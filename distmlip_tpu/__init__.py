@@ -19,18 +19,6 @@ import numpy as np
 
 __version__ = "0.1.0"
 
-# Latency-hiding XLA flags for the overlap-aware halo pipeline must be in
-# the environment BEFORE the XLA backend initializes — and nearly every
-# entry point (DistPotential.__init__, bench.py) touches jax.devices()
-# long before the first graph_mesh() call. Importing distmlip_tpu is the
-# one hook that reliably precedes backend init, so apply them here
-# (no-op unless a TPU platform is requested / DISTMLIP_LATENCY_HIDING=1 —
-# see parallel/mesh.py).
-from .parallel.mesh import ensure_latency_hiding_flags as _lh
-
-_lh()
-del _lh
-
 # ---------------------------------------------------------------------------
 # Global dtype registry.
 #

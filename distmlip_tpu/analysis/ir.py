@@ -24,7 +24,7 @@ from typing import Any, Iterator
 # collective primitives the graph runtime can emit (names as they appear
 # in jaxprs across the jax versions this repo supports)
 COLLECTIVE_PRIMS = frozenset({
-    "ppermute", "psum", "psum2", "all_gather", "all_to_all",
+    "ppermute", "psum", "psum_invariant", "all_gather", "all_to_all",
     "reduce_scatter", "pmax", "pmin", "pgather", "collective_permute",
 })
 
@@ -100,18 +100,15 @@ def scope_of(eqn) -> str:
 
 
 def source_location(eqn):
-    """(file, line) the eqn was traced from, or None. Uses jax's private
-    source_info_util (stable across the 0.4.x builds this repo supports);
-    any API drift degrades to no-location, never to a crash."""
-    try:
-        from jax._src import source_info_util
+    """(file, line) of the user frame the eqn was traced from, or None
+    when the traceback holds no user frame (jax's private
+    source_info_util: the only place that knows which frames are jax's)."""
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return None
-        return (frame.file_name, int(frame.start_line))
-    except Exception:  # noqa: BLE001 - introspection is best effort
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return None
+    return (frame.file_name, int(frame.start_line))
 
 
 def iter_sites(closed_jaxpr) -> Iterator[EqnSite]:

@@ -263,18 +263,17 @@ class _Flat:
         return self.n_regions - 1
 
 
-def _shard_factor(names: dict, mesh) -> int:
+def _shard_factor(spec, mesh) -> int:
     """How many ways one shard_map operand is split: product of the mesh
-    axis sizes named by its in_names entry ({dim: (axis, ...)})."""
+    axis sizes named by its ``in_specs`` PartitionSpec (one entry per
+    dim: None, an axis name, or a tuple of names)."""
     factor = 1
-    try:
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        for axes in names.values():
-            for ax in (axes if isinstance(axes, (tuple, list)) else (axes,)):
-                factor *= int(sizes.get(ax, 1))
-    except Exception:  # noqa: BLE001 - unknown mesh shape: no scaling
-        return 1
-    return max(factor, 1)
+    for axes in spec:
+        if axes is None:
+            continue
+        for ax in (axes if isinstance(axes, tuple) else (axes,)):
+            factor *= int(mesh.shape[ax])
+    return factor
 
 
 def _arg_shard_factors(jaxpr) -> dict:
@@ -289,14 +288,13 @@ def _arg_shard_factors(jaxpr) -> dict:
             name = eqn.primitive.name
             subs = ir.sub_jaxprs(eqn.params)
             if name == "shard_map":
-                mesh = eqn.params.get("mesh")
-                in_names = eqn.params.get("in_names", ())
-                for v, names in zip(eqn.invars, in_names):
+                mesh = eqn.params["mesh"]
+                for v, spec in zip(eqn.invars, eqn.params["in_specs"]):
                     if _is_literal(v):
                         continue
                     root = outer_ids.get(id(v))
-                    if root is not None and isinstance(names, dict):
-                        f = _shard_factor(names, mesh)
+                    if root is not None:
+                        f = _shard_factor(spec, mesh)
                         factors[root] = max(factors.get(root, 1), f)
             elif subs and name in ("pjit", "closed_call", "core_call",
                                    "remat2", "custom_jvp_call",
@@ -660,15 +658,14 @@ def oracle_peak_bytes(closed_jaxpr) -> int | None:
     (tests and ``tools/memory_audit.py --oracle`` only)."""
     try:
         import jax
-        from jax.core import jaxpr_as_fun
-        from jax.experimental import enable_x64
+        from jax.extend.core import jaxpr_as_fun
 
         fn = jaxpr_as_fun(closed_jaxpr)
         shapes = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
                   for v in closed_jaxpr.jaxpr.invars]
         # replay in the same x64 regime the program was traced under, so
         # every literal and weak scalar lowers at its traced width
-        with enable_x64(_traced_with_x64(closed_jaxpr)):
+        with jax.enable_x64(_traced_with_x64(closed_jaxpr)):
             ma = jax.jit(fn).lower(*shapes).compile().memory_analysis()
         total = (int(ma.argument_size_in_bytes)
                  + int(ma.output_size_in_bytes)

@@ -142,6 +142,7 @@ class BatchedPotential:
         # every subsequent hit runs
         self._kernel_mode = ""
         self._kernel_coverage = 0.0
+        self._kernel_ops: dict = {}   # op -> [pallas, xla] call sites
         self._cache = None  # (graph, host, [(numbers, cell, pbc)])
         self.rebuild_count = 0
         # device-resident packed refresh (partition.device_refresh_packed);
@@ -451,6 +452,7 @@ class BatchedPotential:
             if kc.total:  # a fresh trace happened (new shape bucket)
                 self._kernel_mode = kc.mode
                 self._kernel_coverage = kc.coverage
+                self._kernel_ops = kc.ops
                 # new shape bucket: calibrate the bytes model with the
                 # static planner's per-device peak for THIS program
                 # (host-side abstract trace; once per bucket)
@@ -498,6 +500,7 @@ class BatchedPotential:
         self.last_stats["batch_size"] = len(structures)
         self.last_stats["kernel_mode"] = self._kernel_mode
         self.last_stats["kernel_coverage"] = self._kernel_coverage
+        self.last_stats["kernel_ops"] = self._kernel_ops
         self.last_stats["rebuild_count"] = int(not reused)
         self.last_stats["rebuild_on_device"] = int(refreshed)
         self.last_stats["rebuild_overflow_count"] = self.rebuild_overflow_count

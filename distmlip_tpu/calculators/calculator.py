@@ -200,6 +200,7 @@ class DistPotential:
         # a warm pjit cache)
         self._kernel_mode = ""
         self._kernel_coverage = 0.0
+        self._kernel_ops: dict = {}   # op -> [pallas, xla] call sites
         # collective_count telemetry: one extra ABSTRACT trace (make_jaxpr,
         # no compile) per runtime build, on the first record emit — a small
         # fraction of that build's compile cost, but disable for
@@ -765,6 +766,7 @@ class DistPotential:
             if kc.total:  # a fresh jit trace happened (new shape bucket)
                 self._kernel_mode = kc.mode
                 self._kernel_coverage = kc.coverage
+                self._kernel_ops = kc.ops
             energy = float(out["energy"])
         forces = host.gather_owned(np.asarray(out["forces"]), len(atoms))
         stress = np.asarray(out["stress"])
@@ -795,6 +797,7 @@ class DistPotential:
             rebuild_overflow_count=self.rebuild_overflow_count,
             kernel_mode=self._kernel_mode,
             kernel_coverage=self._kernel_coverage,
+            kernel_ops=self._kernel_ops,
         )
         self._emit_record("calculate", host,
                           total_s=time.perf_counter() - t_start)
@@ -863,20 +866,16 @@ class DistPotential:
             for k, v in stats.items():
                 setattr(rec, k, v)
         # analytic cost model: per-step FLOPs + model FLOP utilization
-        # (utils/flops.py; mfu stays 0 where peak FLOPs are unknown — CPU)
-        try:
-            from ..utils.flops import mfu as _mfu
-            from ..utils.flops import model_flop_estimate
+        # (utils/flops.py; mfu is None where the device has no published
+        # peak — CPU)
+        from ..utils.flops import mfu as _mfu
+        from ..utils.flops import model_flop_estimate
 
-            n_edges = sum(rec.n_edges_per_part) or 0
-            n_lines = stats.get("n_lines", 0) if stats else 0
-            rec.flops_per_step = model_flop_estimate(
-                self.model, rec.n_atoms, n_edges, n_lines)
-            rec.mfu = _mfu(rec.flops_per_step,
-                           timings.get("device_s", 0.0),
-                           max(self.num_partitions or 1, 1))
-        except Exception:  # noqa: BLE001 - telemetry must never fail a step
-            pass
+        n_lines = stats.get("n_lines", 0) if stats else 0
+        rec.flops_per_step = model_flop_estimate(
+            self.model, rec.n_atoms, sum(rec.n_edges_per_part), n_lines)
+        rec.mfu = _mfu(rec.flops_per_step, timings.get("device_s", 0.0),
+                       max(self.num_partitions or 1, 1))
         (rec.collective_count, rec.contract_error_count,
          rec.contract_warning_count, rec.kernel_mode,
          rec.kernel_coverage, rec.est_peak_bytes) = self._contract_audit()

@@ -2,7 +2,7 @@
 
 The production serving layer over :mod:`distmlip_tpu.serve`: N
 ``ServeEngine`` replicas (in-process for tests and single-host serving;
-one process + chip grant each in real deployments) behind a
+one process holding one chip each in real deployments) behind a
 :class:`FleetRouter` with per-tenant admission quotas and weighted
 fairness, a content-addressed :class:`ResultCache` so duplicate
 screening traffic never touches a chip, wedge-detecting health monitoring

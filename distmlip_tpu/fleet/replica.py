@@ -2,16 +2,16 @@
 
 A :class:`Replica` wraps one :class:`~distmlip_tpu.serve.ServeEngine`
 (its own ``BatchedPotential``, its own compile cache, in real
-deployments its own process + chip grant) with the fleet-facing state
-the router needs: an id, an alive flag, and the dispatch bookkeeping for
-least-loaded routing.
+deployments its own process holding its own chip) with the fleet-facing
+state the router needs: an id, an alive flag, and the dispatch
+bookkeeping for least-loaded routing.
 
-:class:`ReplicaHealth` watches every replica with the same suspicion
-discipline bench.py uses on wedged chip grants
+:class:`ReplicaHealth` watches every replica with a bounded
+suspect-then-confirm policy
 (:class:`~distmlip_tpu.utils.health.ReprobePolicy`): a replica whose
 scheduler thread died, or which holds queued/in-flight work without
-making dispatch progress for ``stall_budget_s`` (the BENCH_r03–r05
-signature — a grant that neither serves nor fails), is marked SUSPECT;
+making dispatch progress for ``stall_budget_s`` (a replica that neither
+serves nor fails), is marked SUSPECT;
 bounded re-probes with backoff either observe recovery or confirm the
 wedge, at which point the monitor fails the replica over through the
 router — reclaiming its queued requests and re-dispatching them on

@@ -1,9 +1,9 @@
 """FleetRouter: N serving replicas behind one fair, cached, failover door.
 
-The production serving shape the ROADMAP names: one ``ServeEngine`` on
-one chip grant is a single point of failure (a wedged grant took
-BENCH_r03–r05 down for ~28 min) and a single queue is a single victim
-for any firehose tenant. The router fronts N replicas with:
+The production serving shape the ROADMAP names: one ``ServeEngine`` is
+one process holding one chip, so a wedged replica is a single point of
+failure and a single queue is a single victim for any firehose tenant.
+The router fronts N replicas with:
 
 - **submit(atoms, tenant=, priority=, deadline=, properties=)** — the
   ServeEngine surface plus tenancy. Returns a Future that ALWAYS
@@ -765,7 +765,7 @@ def make_fleet(n_replicas: int, potential_factory, *, engine_kwargs=None,
     """Convenience constructor for an IN-PROCESS fleet (tests, demos,
     single-host serving): ``potential_factory(i)`` builds replica ``i``'s
     ``BatchedPotential`` (each replica needs its OWN — independent
-    compile caches model independent chip grants), an optional shared
+    compile caches model independent processes), an optional shared
     AOT cache directory rehydrates every replica's bucket ladder, and
     ``engine_kwargs`` feed each ``ServeEngine``."""
     from ..serve import ServeEngine

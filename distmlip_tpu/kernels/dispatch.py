@@ -1,16 +1,18 @@
-"""Kernel dispatch: Pallas on TPU, pure XLA everywhere else.
+"""Kernel dispatch: Pallas where it was proven on the chip, XLA elsewhere.
 
 Every fused-kernel call site in the codebase goes through this module,
 never through :mod:`segment`/:mod:`so3` directly. The dispatcher owns
 
 - **routing**: trace-time selection of the Pallas kernel vs the
-  pure-XLA ops (``ops/segment.py`` semantics). Pallas runs on TPU
-  backends, under ``DISTMLIP_KERNELS=interpret`` (interpreter-mode
-  kernels — the chip-free test lane), or inside a
-  :func:`force_kernel_mode` context; the ``DISTMLIP_KERNELS=0`` kill
-  switch and per-object ``kernels=False`` force XLA. The decision is
-  static per trace — both paths ship from ONE code path with no model
-  forks.
+  pure-XLA ops (``ops/segment.py`` semantics). On a TPU backend each op
+  takes the mode :data:`TPU_DEFAULT_MODE` records for it; Pallas also
+  runs when asked for by name (``kernels="pallas"``), under
+  ``DISTMLIP_KERNELS=interpret`` (interpreter-mode kernels — the
+  chip-free test lane), or inside a :func:`force_kernel_mode` context;
+  the ``DISTMLIP_KERNELS=0`` kill switch and per-object
+  ``kernels=False`` force XLA. The decision is static per trace — both
+  paths ship from ONE code path with no model forks, and a kernel that
+  fails to lower fails the trace: nothing here catches a lowering error.
 - **autodiff**: ``pallas_call`` has no transpose rule, so each fused op
   carries a custom VJP. ``fused_segment_sum``'s backward is the sorted
   gather ``g[segment_ids] * mask``; ``fused_edge_aggregate``'s backward
@@ -51,13 +53,41 @@ DEFAULT_BWD_CHUNK = int(os.environ.get("DISTMLIP_KERNELS_BWD_CHUNK", "32768"))
 _MODES = ("pallas", "interpret", "xla")
 _local = threading.local()
 
+# What each fused op runs by default on a TPU backend. An op reads
+# "pallas" only if it compiled with interpret=False on the chip at a
+# published-width shape AND agreed with the XLA path there; the
+# measurement is chip_smoke.py's KERNELS phase, which re-takes it on every
+# run and fails when it disagrees with this table. An op that Mosaic
+# refuses stays "xla" with the compiler's message beside it;
+# ``kernels="pallas"`` remains the explicit way to reach it.
+TPU_DEFAULT_MODE = {
+    # compiled on a TPU v5 lite at MACE's (E_c=32768, nQ=40, 128) scan
+    # chunk; max rel err vs XLA float32/highest 1.9e-7 (float32), 3.1e-3
+    # (bfloat16: the output's own rounding) — chip run, PR 21
+    "segment_sum": "pallas",
+    # Mosaic refuses CHGNet's atom-conv message at units = 64 (chip run,
+    # PR 21). float32: "Mosaic failed to compile TPU kernel: Slice shape
+    # along dimension 2 must be aligned to tiling (128), but is 64" — a
+    # 64-wide streamed block is half a lane tile. bfloat16: "'tpu.matmul'
+    # op Expected matmul acc to be 32-bit" — the model's `x @ w` inside
+    # edge_fn keeps a bf16 result, Mosaic wants preferred_element_type
+    # float32. ROADMAP S3 carries both as a perf_opt item.
+    "edge_aggregate": "xla",
+    # compiled on a TPU v5 lite at eSCN's 128-channel, l_max = 2 SO(2)
+    # block over a 32768-edge chunk; max rel err 2.3e-7 (float32), 2.0e-3
+    # (bfloat16) — chip run, PR 21
+    "so2_conv": "pallas",
+}
+
 
 @dataclass
 class KernelCounter:
-    """Trace-time tally of dispatch decisions (edge aggregations only)."""
+    """Trace-time tally of dispatch decisions, in total and per fused op
+    (``ops[op] = [pallas, xla]`` call sites)."""
 
     pallas: int = 0
     xla: int = 0
+    ops: dict = field(default_factory=dict)
 
     @property
     def total(self) -> int:
@@ -123,24 +153,27 @@ def counting():
         _local.counter = old
 
 
-def _count(used_pallas: bool) -> None:
+def _count(op: str, used_pallas: bool) -> None:
     c = getattr(_local, "counter", None)
     if c is not None:
         if used_pallas:
             c.pallas += 1
         else:
             c.xla += 1
+        c.ops.setdefault(op, [0, 0])[0 if used_pallas else 1] += 1
 
 
-def resolve_kernel_mode(kernels=None) -> str:
-    """Static (trace-time) routing decision.
+def resolve_kernel_mode(kernels=None, *, op: str) -> str:
+    """Static (trace-time) routing decision for fused op ``op`` (a key of
+    :data:`TPU_DEFAULT_MODE`).
 
     Priority: :func:`force_kernel_mode` context > per-object ``kernels``
     (``False`` -> xla, ``"interpret"``/``"pallas"``/``"xla"`` verbatim)
     > ``DISTMLIP_KERNELS`` env (``0``/``off`` kill switch, ``interpret``,
-    ``1``/``on``) > backend default (pallas iff the default backend is
-    TPU). ``kernels=None``/``True`` both mean "backend default" — True
-    cannot force a compiled Pallas kernel onto a CPU host.
+    ``1``/``on``) > backend default (``TPU_DEFAULT_MODE[op]`` on a TPU
+    backend, xla on any other). ``kernels=None``/``True`` both mean
+    "backend default" — True cannot force a compiled Pallas kernel onto a
+    CPU host.
     """
     forced = getattr(_local, "forced", None)
     if forced is not None:
@@ -159,11 +192,7 @@ def resolve_kernel_mode(kernels=None) -> str:
         return "interpret"
     if env in ("1", "on", "force", "pallas"):
         return "pallas"
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 - no backend yet: fall back to XLA
-        backend = "cpu"
-    return "pallas" if backend == "tpu" else "xla"
+    return TPU_DEFAULT_MODE[op] if jax.default_backend() == "tpu" else "xla"
 
 
 def _mask_mul(rows, mask):
@@ -193,7 +222,7 @@ def fused_segment_sum(data, segment_ids, num_segments: int, mask=None,
     and the mode resolves to Pallas; identical masking/padding semantics
     on both paths, custom VJP on the kernel path.
     """
-    mode = resolve_kernel_mode(kernels)
+    mode = resolve_kernel_mode(kernels, op="segment_sum")
     # float (inexact) masks would need a real mask cotangent (the bwd
     # returns float0) — all repo masks are boolean; float masks take the
     # XLA path where plain AD handles them
@@ -201,7 +230,7 @@ def fused_segment_sum(data, segment_ids, num_segments: int, mask=None,
                   and jnp.issubdtype(jnp.result_type(mask), jnp.inexact))
     use = (mode != "xla" and indices_are_sorted and not float_mask
            and data.shape[0] > 0 and num_segments > 0)
-    _count(use)
+    _count("segment_sum", use)
     if not use:
         return masked_segment_sum(data, segment_ids, num_segments, mask,
                                   indices_are_sorted=indices_are_sorted)
@@ -328,7 +357,7 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
     mesh on every force call that plain XLA AD never ships).
     """
     inputs = list(inputs)
-    mode = resolve_kernel_mode(kernels)
+    mode = resolve_kernel_mode(kernels, op="edge_aggregate")
     e = int(segment_ids.shape[0])
     # float (inexact) masks would need a mask cotangent the chunked
     # backward doesn't produce — every mask in this repo is boolean; a
@@ -337,7 +366,7 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
                   and jnp.issubdtype(jnp.result_type(mask), jnp.inexact))
     use = (mode != "xla" and indices_are_sorted and e > 0
            and num_segments > 0 and not float_mask)
-    _count(use)
+    _count("edge_aggregate", use)
     if not use:
         msg = edge_fn(*[_rows_of(i) for i in inputs])
         return masked_segment_sum(msg, segment_ids, num_segments, mask,
@@ -630,9 +659,9 @@ def fused_so2_conv(h, weights, m_idx: dict, channels: int, kernels=None,
         return so2_conv_reference(h_[:, perm, :], list(ws), segments,
                                   channels)[:, inv, :]
 
-    mode = resolve_kernel_mode(kernels)
+    mode = resolve_kernel_mode(kernels, op="so2_conv")
     use = mode != "xla" and h.shape[0] > 0
-    _count(use)
+    _count("so2_conv", use)
     if not use:
         return ref(h, *weights)
     interpret = mode == "interpret"

@@ -8,25 +8,29 @@ possible: the edges landing in dst rows ``[t*TILE_N, (t+1)*TILE_N)`` form a
 CONTIGUOUS slice of the edge array whose bounds come from one on-device
 ``searchsorted`` over the tile boundaries (:func:`dst_tile_offsets`).
 
-Each grid step then owns one dst tile: it streams that tile's edge slice
-from HBM in fixed-size blocks (async DMA into VMEM scratch), optionally
+Each grid step then owns one dst tile: it streams the edge BLOCKS that
+overlap the tile's slice from HBM (async DMA into VMEM scratch), optionally
 gathers per-edge rows from VMEM-resident node arrays, applies a
 caller-supplied per-edge compute, and accumulates into the tile's
 ``(TILE_N, W)`` VMEM accumulator with a one-hot MXU matmul — the classic
 TPU segment-sum idiom. The ``(E, width)`` message tensor never exists:
 messages live one ``(BLK, width)`` block at a time in VMEM.
 
+Mosaic layout rules shape the streaming. A DMA may start at any index of
+an untiled (leading) dimension but only at tile-aligned offsets of the
+last two, and a tile's first edge is wherever ``searchsorted`` says. So
+every streamed array is reshaped to ``(n_blocks, BLK, W)`` outside the
+kernel and the kernel copies whole blocks ``[e0 // BLK, cdiv(e1, BLK))``
+by leading index. A boundary block also carries edges of the neighbouring
+tile; their local row index falls outside ``[0, TILE_N)`` and matches no
+one-hot row. The validity mask and the guard padding are folded into the
+ids the same way (``-1`` matches nothing), so the ids are the only
+screening the kernel needs — :func:`_prepare_edges` is the one place that
+establishes it.
+
 Everything here is the raw kernel layer: no routing, no autodiff. Call
 sites go through :mod:`distmlip_tpu.kernels.dispatch`, which adds the
-XLA fallback and the custom VJPs.
-
-Shapes are NOT required to be multiples of the tile sizes — inputs are
-guard-padded with ZERO-filled rows (:func:`_prepare_edges`) so in-kernel
-block slices never hit ``dynamic_slice``'s end-clamp, and outputs are
-sliced back. The guard rows' content is never read as real data: tile
-offsets come from the UNPADDED ids, and the in-kernel ``pos < tile_end``
-test screens every guard row before it can reach the accumulator — do
-not drop that test in favor of trusting the pad values.
+XLA path and the custom VJPs.
 """
 
 from __future__ import annotations
@@ -43,6 +47,10 @@ from jax.experimental.pallas import tpu as pltpu
 # layer may shrink them for tiny problems so guard padding stays bounded.
 TILE_N = 128
 EDGE_BLK = 256
+# scoped-VMEM ceiling handed to Mosaic: the default (16 MiB on v5e) is
+# below the working set of MACE's 5120-wide scan chunk; physical VMEM is
+# 128 MiB on v5e/v6e and 64 MiB per core on v7x, so stay under the latter
+VMEM_LIMIT_CAP = 48 * 1024 * 1024
 
 
 def dst_tile_offsets(segment_ids, num_segments: int, tile_n: int):
@@ -63,13 +71,6 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _pad_rows(x, rows: int, fill=0):
-    if rows == 0:
-        return x
-    widths = [(0, rows)] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(x, widths, constant_values=fill)
-
-
 def _flatten_width(x):
     """(E, ...) -> (E, W) with W >= 1 (scalars get a singleton lane)."""
     if x.ndim == 1:
@@ -86,53 +87,68 @@ def _pick_tiles(n_edges: int, num_segments: int, tile_n: int | None,
     return int(tn), int(eb)
 
 
-def _prepare_edges(arrays, n_edges: int, edge_blk: int):
-    """Guard-pad every (E, ...) array to ``round_up(E, blk) + blk`` rows so
-    in-kernel block slices never hit ``dynamic_slice``'s end-clamp (which
-    would silently re-read earlier rows)."""
-    e_pad = _round_up(max(n_edges, 1), edge_blk) + edge_blk
-    return [_pad_rows(a, e_pad - n_edges) for a in arrays], e_pad
+def _prepare_edges(segment_ids, mask, arrays, edge_blk: int):
+    """Block every per-edge array for leading-index DMA.
 
-
-def _block_copy(src_ref, dst_ref, sem, start, rows: int):
-    """DMA ``rows`` rows of ``src_ref`` starting at ``start`` into VMEM."""
-    cp = pltpu.make_async_copy(src_ref.at[pl.ds(start, rows)], dst_ref, sem)
-    cp.start()
-    cp.wait()
-
-
-def _onehot_accumulate(acc, msg, local_dst, valid, tile_n: int):
-    """acc += onehot(local_dst)^T @ (msg * valid): the per-block dst
-    scatter as ONE MXU matmul against a (BLK, TILE_N) one-hot."""
-    blk = msg.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (blk, tile_n), 1)
-    onehot = jnp.where((local_dst[:, None] == cols) & valid[:, None], 1.0, 0.0
-                       ).astype(jnp.float32)
-    return acc + jax.lax.dot_general(
-        onehot, msg.astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-def _gather_rows(node_ref, idx, width: int):
-    """(BLK,) indexed rows of a VMEM-resident (N, W) node ref.
-
-    Row-looped dynamic slices — the node array is VMEM-resident (the
-    dispatch layer only routes arrays under its VMEM budget here; larger
-    arrays are pre-gathered by XLA), so each read is an on-chip dynamic
-    slice, not an HBM round trip.
+    Returns ``(ids_b, arrays_b)``: ids as ``(n_blocks, 1, BLK)`` int32 row
+    vectors with masked and guard rows set to ``-1`` (no dst tile owns row
+    ``-1``, so they never reach an accumulator), each ``(E, W)`` array as
+    ``(n_blocks, BLK, W)`` with zero-filled guard rows.
     """
-    blk = idx.shape[0]
+    e = segment_ids.shape[0]
+    nb = -(-e // edge_blk)
+    pad = nb * edge_blk - e
+    ids = segment_ids.astype(jnp.int32)
+    if mask is not None:
+        ids = jnp.where(mask, ids, -1)
+    ids_b = jnp.pad(ids, (0, pad), constant_values=-1).reshape(
+        nb, 1, edge_blk)
+    arrays_b = [jnp.pad(a, ((0, pad), (0, 0))).reshape(nb, edge_blk,
+                                                        a.shape[1])
+                for a in arrays]
+    return ids_b, arrays_b
 
-    zero = jnp.zeros((), dtype=idx.dtype)  # match idx dtype under x64 tracing
 
-    def body(j, acc):
-        row = jax.lax.dynamic_slice(node_ref[:], (idx[j], zero), (1, width))
-        return jax.lax.dynamic_update_slice(acc, row,
-                                            (j.astype(idx.dtype), zero))
+def _vmem_limit(nbytes: int) -> int:
+    """Scoped-VMEM request for a kernel whose named buffers total
+    ``nbytes``: twice that (Mosaic's own temporaries track the operand
+    sizes), at least the 16 MiB default, at most :data:`VMEM_LIMIT_CAP`."""
+    return int(min(VMEM_LIMIT_CAP, max(16 * 1024 * 1024, 2 * nbytes)))
 
-    init = jnp.zeros((blk, width), dtype=node_ref.dtype)
-    return jax.lax.fori_loop(0, blk, body, init)
+
+def _block_range(offs_ref, i, edge_blk: int):
+    """Blocks ``[b0, b1)`` overlapping dst tile ``i``'s edge slice (int32
+    arithmetic spelled out: the contract checker traces under x64, where
+    a bare Python int would promote)."""
+    blk = jnp.int32(edge_blk)
+    return offs_ref[i] // blk, (offs_ref[i + 1] + (blk - 1)) // blk
+
+
+def _copy_blocks(b, srcs, dsts, sems):
+    """DMA block ``b`` of every blocked HBM ref into its scratch buffer."""
+    copies = [pltpu.make_async_copy(src.at[b], dst, sems.at[k])
+              for k, (src, dst) in enumerate(zip(srcs, dsts))]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+
+
+def _onehot_accumulate(acc_ref, msg, ids_row, tile_start, tile_n: int):
+    """acc += onehot(ids - tile_start) @ msg: the per-block dst scatter as
+    ONE MXU matmul against a (TILE_N, BLK) one-hot. ``ids_row`` is the
+    (1, BLK) id row; ids outside the tile (neighbouring tiles, ``-1``)
+    match no row. The one-hot is exact in any float dtype, so the product
+    runs in the message dtype (bf16 stays one MXU pass) with fp32
+    accumulation; fp32 messages ask for a true fp32 contraction."""
+    blk = msg.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_n, blk), 0)
+    onehot = (rows == ids_row - tile_start).astype(msg.dtype)
+    precision = (jax.lax.Precision.HIGHEST if msg.dtype == jnp.float32
+                 else None)
+    acc_ref[...] += jax.lax.dot_general(
+        onehot, msg, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -159,62 +175,53 @@ def pallas_segment_sum(data, segment_ids, num_segments: int, mask=None, *,
     tn, eb = _pick_tiles(e, num_segments, tile_n, edge_blk)
     ntile = -(-num_segments // tn)
     offs = dst_tile_offsets(segment_ids, num_segments, tn)
+    ids_b, (data_b,) = _prepare_edges(segment_ids, mask, [flat], eb)
 
-    m = (jnp.ones((e,), jnp.int32) if mask is None
-         else mask.astype(jnp.int32))
-    (flat_p, ids_p, m_p), _ = _prepare_edges(
-        [flat, segment_ids.astype(jnp.int32), m], e, eb)
-
-    kernel = functools.partial(_segment_sum_kernel, tile_n=tn, edge_blk=eb,
-                               width=w)
+    item = flat.dtype.itemsize
+    # data block + fp32 accumulator and dot result + double-buffered output
+    # block + one-hot
+    vmem = eb * w * item + tn * w * (8 + 2 * item) + tn * eb * item
+    kernel = functools.partial(_segment_sum_kernel, tile_n=tn, edge_blk=eb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(ntile,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),   # ids
-            pl.BlockSpec(memory_space=pltpu.ANY),   # mask
-            pl.BlockSpec(memory_space=pltpu.ANY),   # data
+            pl.BlockSpec(memory_space=pl.ANY),   # ids
+            pl.BlockSpec(memory_space=pl.ANY),   # data
         ],
         out_specs=pl.BlockSpec((tn, w), lambda i, offs: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((eb,), jnp.int32),
-            pltpu.VMEM((eb,), jnp.int32),
+            pltpu.VMEM((1, eb), jnp.int32),
             pltpu.VMEM((eb, w), flat.dtype),
             pltpu.VMEM((tn, w), jnp.float32),
-            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ntile * tn, w), data.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
-    )(offs, ids_p, m_p, flat_p)
+    )(offs, ids_b, data_b)
     return out[:num_segments].reshape(out_shape)
 
 
-def _segment_sum_kernel(offs_ref, ids_ref, mask_ref, data_ref, out_ref,
-                        ids_s, mask_s, data_s, acc_s, sems, *,
-                        tile_n: int, edge_blk: int, width: int):
+def _segment_sum_kernel(offs_ref, ids_ref, data_ref, out_ref,
+                        ids_s, data_s, acc_s, sems, *,
+                        tile_n: int, edge_blk: int):
     i = pl.program_id(0)
-    e0 = offs_ref[i]
-    e1 = offs_ref[i + 1]
-    acc_s[:] = jnp.zeros_like(acc_s)
-    nblk = pl.cdiv(e1 - e0, edge_blk)
+    b0, b1 = _block_range(offs_ref, i, edge_blk)
+    acc_s[...] = jnp.zeros_like(acc_s)
 
-    def body(b, _):
-        s = e0 + b * edge_blk
-        _block_copy(ids_ref, ids_s, sems.at[0], s, edge_blk)
-        _block_copy(mask_ref, mask_s, sems.at[1], s, edge_blk)
-        _block_copy(data_ref, data_s, sems.at[2], s, edge_blk)
-        pos = s + jax.lax.broadcasted_iota(jnp.int32, (edge_blk, 1), 0)[:, 0]
-        valid = (pos < e1) & (mask_s[:] != 0)
-        local = ids_s[:] - i * tile_n
-        acc_s[:] = _onehot_accumulate(acc_s[:], data_s[:], local, valid,
-                                      tile_n)
-        return _
+    def body(b, carry):
+        _copy_blocks(b, (ids_ref, data_ref), (ids_s, data_s), sems)
+        _onehot_accumulate(acc_s, data_s[...], ids_s[...], i * tile_n,
+                           tile_n)
+        return carry
 
-    jax.lax.fori_loop(0, nblk, body, None)
-    out_ref[:] = acc_s[:].astype(out_ref.dtype)
+    jax.lax.fori_loop(b0, b1, body, None)
+    out_ref[...] = acc_s[...].astype(out_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -259,27 +266,23 @@ def pallas_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
     # every input contributes exactly ONE streamed array (its data, or the
     # gather's idx column), so input position == streamed-array position
     edge_arrays = []                    # flattened (E, Wi), one per input
-    node_arrays, node_widths = [], []
+    node_arrays = []
     kinds = []                          # ("edge", trailing)|("gather", k, tr)
     for item in inputs:
         if isinstance(item, tuple) and len(item) == 3 and item[0] == "gather":
             _, node, idx = item
-            node2 = _flatten_width(node)
             kinds.append(("gather", len(node_arrays), node.shape[1:]))
-            node_arrays.append(node2)
-            node_widths.append(node2.shape[1])
+            node_arrays.append(_flatten_width(node))
             edge_arrays.append(idx.astype(jnp.int32)[:, None])
         else:
             arr = jnp.asarray(item)
             kinds.append(("edge", None, arr.shape[1:]))
             edge_arrays.append(_flatten_width(arr))
-
-    m = (jnp.ones((e,), jnp.int32) if mask is None
-         else mask.astype(jnp.int32))
-    padded, _ = _prepare_edges(
-        [segment_ids.astype(jnp.int32), m] + edge_arrays, e, eb)
-    ids_p, m_p = padded[0], padded[1]
-    edge_p = padded[2:]
+    ids_b, edge_b = _prepare_edges(segment_ids, mask, edge_arrays, eb)
+    # gather idx columns ride SMEM as (1, BLK) rows: the in-kernel gather
+    # reads them as scalars
+    edge_b = [a.reshape(a.shape[0], 1, eb) if k[0] == "gather" else a
+              for a, k in zip(edge_b, kinds)]
 
     # whole-array consts: 0/1-d arrays ride as (1, n) (TPU wants >= 2-d
     # tiles); the kernel restores the original shapes before edge_fn
@@ -290,83 +293,92 @@ def pallas_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
 
     kernel = functools.partial(
         _edge_aggregate_kernel, edge_fn=edge_fn, kinds=kinds,
-        node_widths=node_widths, const_shapes=const_shapes, tile_n=tn,
-        edge_blk=eb, w_out=w_out, out_shape=tuple(out_shape))
-    n_stream = 2 + len(edge_p)  # ids + mask + per-edge arrays
+        n_node=len(node_arrays), const_shapes=const_shapes, tile_n=tn,
+        edge_blk=eb, w_out=w_out)
+    n_stream = 1 + len(edge_b)  # ids + per-edge arrays
+    stream_scratch = [
+        pltpu.SMEM((1, eb), jnp.int32) if k[0] == "gather"
+        else pltpu.VMEM((eb, a.shape[2]), a.dtype)
+        for a, k in zip(edge_b, kinds)]
+    gather_scratch = [pltpu.VMEM((eb, n.shape[1]), n.dtype)
+                      for n in node_arrays]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(ntile,),
         in_specs=(
-            [pl.BlockSpec(memory_space=pltpu.ANY)] * n_stream
+            [pl.BlockSpec(memory_space=pl.ANY)] * n_stream
             + [pl.BlockSpec(memory_space=pltpu.VMEM)]
             * (len(node_arrays) + len(const_in))
         ),
         out_specs=pl.BlockSpec((tn, w_out), lambda i, offs: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((eb,), jnp.int32),
-            pltpu.VMEM((eb,), jnp.int32),
-        ] + [
-            pltpu.VMEM((eb, a.shape[1]), a.dtype) for a in edge_p
-        ] + [
-            pltpu.VMEM((tn, w_out), jnp.float32),
-            pltpu.SemaphoreType.DMA((n_stream,)),
-        ],
+        scratch_shapes=(
+            [pltpu.VMEM((1, eb), jnp.int32)] + stream_scratch
+            + gather_scratch
+            + [pltpu.VMEM((tn, w_out), jnp.float32),
+               pltpu.SemaphoreType.DMA((n_stream,))]),
     )
+    # whole-array residents + one block of every stream and gather + the
+    # message, accumulator and output tiles at up to 12 bytes per element
+    vmem = sum(int(a.size) * a.dtype.itemsize
+               for a in node_arrays + const_in)
+    vmem += sum(eb * a.shape[2] * a.dtype.itemsize for a in edge_b)
+    vmem += sum(eb * n.shape[1] * n.dtype.itemsize for n in node_arrays)
+    vmem += (tn + eb) * w_out * 12
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ntile * tn, w_out), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
-    )(offs, ids_p, m_p, *edge_p, *node_arrays, *const_in)
+    )(offs, ids_b, *edge_b, *node_arrays, *const_in)
     return out[:num_segments].reshape(full_out)
 
 
-def _edge_aggregate_kernel(offs_ref, ids_ref, mask_ref, *refs, edge_fn,
-                           kinds, node_widths, const_shapes, tile_n: int,
-                           edge_blk: int, w_out: int, out_shape):
+def _gather_rows(node_ref, idx_ref, rows_ref):
+    """rows_ref[j] = node_ref[idx_ref[0, j]] for the block's edges: a
+    row-looped VMEM-to-VMEM copy driven by SMEM scalars. The node array is
+    VMEM-resident (the dispatch layer only routes arrays under its VMEM
+    budget here; larger arrays are pre-gathered by XLA), so each read is
+    an on-chip dynamic-row load, not an HBM round trip."""
+
+    def body(j, carry):
+        rows_ref[pl.ds(j, 1), :] = node_ref[pl.ds(idx_ref[0, j], 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0], body, None)
+
+
+def _edge_aggregate_kernel(offs_ref, ids_ref, *refs, edge_fn, kinds,
+                           n_node: int, const_shapes, tile_n: int,
+                           edge_blk: int, w_out: int):
     n_edge = len(kinds)
-    n_node = len(node_widths)
     n_const = len(const_shapes)
-    edge_refs = refs[:n_edge]
-    node_refs = refs[n_edge:n_edge + n_node]
-    const_refs = refs[n_edge + n_node:n_edge + n_node + n_const]
-    out_ref = refs[n_edge + n_node + n_const]
-    ids_s = refs[n_edge + n_node + n_const + 1]
-    mask_s = refs[n_edge + n_node + n_const + 2]
-    edge_s = refs[n_edge + n_node + n_const + 3:
-                  n_edge + n_node + n_const + 3 + n_edge]
-    acc_s = refs[-2]
-    sems = refs[-1]
-    const_vals = [r[:].reshape(shp) for r, shp in
+    it = iter(refs)
+    take = lambda n: [next(it) for _ in range(n)]
+    edge_refs, node_refs, const_refs = (take(n_edge), take(n_node),
+                                        take(n_const))
+    (out_ref, ids_s), edge_s, rows_s = take(2), take(n_edge), take(n_node)
+    acc_s, sems = take(2)
+    const_vals = [r[...].reshape(shp) for r, shp in
                   zip(const_refs, const_shapes)]
 
     i = pl.program_id(0)
-    e0 = offs_ref[i]
-    e1 = offs_ref[i + 1]
-    acc_s[:] = jnp.zeros_like(acc_s)
-    nblk = pl.cdiv(e1 - e0, edge_blk)
+    b0, b1 = _block_range(offs_ref, i, edge_blk)
+    acc_s[...] = jnp.zeros_like(acc_s)
 
-    def body(b, _):
-        s = e0 + b * edge_blk
-        _block_copy(ids_ref, ids_s, sems.at[0], s, edge_blk)
-        _block_copy(mask_ref, mask_s, sems.at[1], s, edge_blk)
-        for k, (eref, sref) in enumerate(zip(edge_refs, edge_s)):
-            _block_copy(eref, sref, sems.at[2 + k], s, edge_blk)
+    def body(b, carry):
+        _copy_blocks(b, [ids_ref] + edge_refs, [ids_s] + edge_s, sems)
         args = []
         for p, (tag, node_k, trailing) in enumerate(kinds):
             if tag == "gather":
-                idx = edge_s[p][:][:, 0]
-                rows = _gather_rows(node_refs[node_k], idx,
-                                    node_widths[node_k])
-                args.append(rows.reshape((edge_blk,) + tuple(trailing)))
+                _gather_rows(node_refs[node_k], edge_s[p], rows_s[node_k])
+                rows = rows_s[node_k][...]
             else:
-                args.append(edge_s[p][:].reshape(
-                    (edge_blk,) + tuple(trailing)))
+                rows = edge_s[p][...]
+            args.append(rows.reshape((edge_blk,) + tuple(trailing)))
         msg = edge_fn(*args, *const_vals).reshape(edge_blk, w_out)
-        pos = s + jax.lax.broadcasted_iota(jnp.int32, (edge_blk, 1), 0)[:, 0]
-        valid = (pos < e1) & (mask_s[:] != 0)
-        local = ids_s[:] - i * tile_n
-        acc_s[:] = _onehot_accumulate(acc_s[:], msg, local, valid, tile_n)
-        return _
+        _onehot_accumulate(acc_s, msg, ids_s[...], i * tile_n, tile_n)
+        return carry
 
-    jax.lax.fori_loop(0, nblk, body, None)
-    out_ref[:] = acc_s[:].astype(out_ref.dtype)
+    jax.lax.fori_loop(b0, b1, body, None)
+    out_ref[...] = acc_s[...].astype(out_ref.dtype)

@@ -94,12 +94,19 @@ def so2_conv_pallas(h_packed, weights, segments, channels: int, *,
     :func:`so2_conv_reference` (they ride VMEM whole — SO(2) stacks are
     O(l_max * (l_max * C)^2) bytes, far under the VMEM budget for every
     model config this repo ships).
+
+    The kernel sees the coefficients as ``(E, S * C)`` rows: each per-m
+    operand ``(BLK, nl * C)`` is then a static LANE slice (128-aligned
+    when ``C`` is a multiple of 128, the published eSCN/UMA width) that
+    feeds the MXU as loaded — Mosaic has no in-kernel reshape that folds
+    the ``nl`` sublane axis into lanes.
     """
     e, s, c = h_packed.shape
     blk = min(edge_blk or EDGE_BLK, max(8, e))
     e_pad = -(-e // blk) * blk
-    h_in = (jnp.pad(h_packed, ((0, e_pad - e), (0, 0), (0, 0)))
-            if e_pad != e else h_packed)
+    h_in = h_packed.reshape(e, s * c)
+    if e_pad != e:
+        h_in = jnp.pad(h_in, ((0, e_pad - e), (0, 0)))
 
     kernel = functools.partial(_so2_kernel, segments=segments, channels=c,
                                n_weights=len(weights))
@@ -107,44 +114,42 @@ def so2_conv_pallas(h_packed, weights, segments, channels: int, *,
         kernel,
         grid=(e_pad // blk,),
         in_specs=(
-            [pl.BlockSpec((blk, s, c), lambda i: (i, 0, 0))]
+            [pl.BlockSpec((blk, s * c), lambda i: (i, 0))]
             + [pl.BlockSpec(w.shape, lambda i: (0,) * w.ndim)
                for w in weights]
         ),
-        out_specs=pl.BlockSpec((blk, s, c), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((e_pad, s, c), h_packed.dtype),
+        out_specs=pl.BlockSpec((blk, s * c), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((e_pad, s * c), h_packed.dtype),
         interpret=interpret,
     )(h_in, *weights)
-    return out[:e]
+    return out[:e].reshape(e, s, c)
 
 
 def _so2_kernel(h_ref, *refs, segments, channels: int, n_weights: int):
     w_refs = refs[:n_weights]
     out_ref = refs[n_weights]
     c = channels
-    blk = h_ref.shape[0]
-    h = h_ref[:]
+    # fp32 coefficients ask for a true fp32 contraction, stated so the
+    # result does not hang on Mosaic's default; bf16 runs native
+    dot = functools.partial(
+        jnp.dot, preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if h_ref.dtype == jnp.float32 else None))
     wi = 0
     for m, start, nl in segments:
         d = nl * c
+        lo = start * c
         if m == 0:
-            f = h[:, start:start + nl, :].reshape(blk, d)
-            y = jnp.dot(f, w_refs[wi][:],
-                        preferred_element_type=jnp.float32)
-            out_ref[:, start:start + nl, :] = y.reshape(blk, nl, c).astype(
-                out_ref.dtype)
+            y = dot(h_ref[:, lo:lo + d], w_refs[wi][...])
+            out_ref[:, lo:lo + d] = y.astype(out_ref.dtype)
             wi += 1
         else:
-            fp = h[:, start:start + nl, :].reshape(blk, d)
-            fm = h[:, start + nl:start + 2 * nl, :].reshape(blk, d)
-            wr = w_refs[wi][:]
-            wim = w_refs[wi + 1][:]
+            fp = h_ref[:, lo:lo + d]
+            fm = h_ref[:, lo + d:lo + 2 * d]
+            wr = w_refs[wi][...]
+            wim = w_refs[wi + 1][...]
             wi += 2
-            yp = (jnp.dot(fp, wr, preferred_element_type=jnp.float32)
-                  - jnp.dot(fm, wim, preferred_element_type=jnp.float32))
-            ym = (jnp.dot(fp, wim, preferred_element_type=jnp.float32)
-                  + jnp.dot(fm, wr, preferred_element_type=jnp.float32))
-            out_ref[:, start:start + nl, :] = yp.reshape(blk, nl, c).astype(
+            out_ref[:, lo:lo + d] = (dot(fp, wr) - dot(fm, wim)).astype(
                 out_ref.dtype)
-            out_ref[:, start + nl:start + 2 * nl, :] = ym.reshape(
-                blk, nl, c).astype(out_ref.dtype)
+            out_ref[:, lo + d:lo + 2 * d] = (dot(fp, wim) + dot(fm, wr)
+                                             ).astype(out_ref.dtype)

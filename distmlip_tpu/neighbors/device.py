@@ -56,6 +56,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .. import geometry
+from ..geometry import COORD_PRECISION
 from .python_ref import NUMERICAL_TOL, _image_ranges
 
 
@@ -193,7 +194,7 @@ def _wrap_device(positions, inv_lattice, pbc_mask):
     the in-jit analogue of ``geometry.wrap_positions``."""
     import jax.numpy as jnp
 
-    frac = positions @ inv_lattice
+    frac = jnp.matmul(positions, inv_lattice, precision=COORD_PRECISION)
     shift = jnp.where(pbc_mask, jnp.floor(frac), 0.0)
     return frac, shift.astype(jnp.int32), frac - shift
 
@@ -270,8 +271,9 @@ def cell_list_neighbors(static: CellListStatic, arrays, positions):
     jc = jnp.minimum(cand, st.n_cap - 1)
 
     # --- distance filter against the center's wrapped position ---
-    wpos = w @ lat                                        # (n_cap, 3)
-    img_cart = wrap.astype(dtype) @ lat                   # (n_cap, S, 3)
+    wpos = jnp.matmul(w, lat, precision=COORD_PRECISION)  # (n_cap, 3)
+    img_cart = jnp.matmul(wrap.astype(dtype), lat,        # (n_cap, S, 3)
+                          precision=COORD_PRECISION)
     diff = wpos[jc] + img_cart[:, :, None, :] - wpos[:, None, None, :]
     d2 = jnp.sum(diff * diff, axis=-1)                    # (n_cap, S, cap)
     r2 = jnp.asarray((st.r + NUMERICAL_TOL) ** 2, dtype=dtype)
@@ -407,12 +409,14 @@ def packed_neighbors(static: PackedStatic, arrays, positions):
     img_mask = jnp.asarray(arrays["img_mask"])
 
     p = positions[gi]                                     # (B, n_max, 3)
-    frac = jnp.einsum("bki,bij->bkj", p, invs)
+    frac = jnp.einsum("bki,bij->bkj", p, invs, precision=COORD_PRECISION)
     shift = jnp.where(pbc[:, None, :], jnp.floor(frac), 0.0)
     w = frac - shift
     shift = shift.astype(jnp.int32)
-    wc = jnp.einsum("bki,bij->bkj", w, cells)             # wrapped cartesian
-    imgc = jnp.einsum("bmi,bij->bmj", imgs.astype(dtype), cells)
+    wc = jnp.einsum("bki,bij->bkj", w, cells,             # wrapped cartesian
+                    precision=COORD_PRECISION)
+    imgc = jnp.einsum("bmi,bij->bmj", imgs.astype(dtype), cells,
+                      precision=COORD_PRECISION)
 
     # diff[b, k(center), j(neighbor), m] = wc[b,j] + imgc[b,m] - wc[b,k]
     diff = (wc[:, None, :, None, :] + imgc[:, None, None, :, :]
@@ -426,7 +430,8 @@ def packed_neighbors(static: PackedStatic, arrays, positions):
     off_int = (-imgs[:, None, None, :, :]
                + shift[:, None, :, None, :]
                - shift[:, :, None, None, :])              # (B, k, j, m, 3)
-    off_cart = jnp.einsum("bkjmi,bin->bkjmn", off_int.astype(dtype), cells)
+    off_cart = jnp.einsum("bkjmi,bin->bkjmn", off_int.astype(dtype), cells,
+                          precision=COORD_PRECISION)
     src = jnp.broadcast_to(gi[:, None, :, None], valid.shape)
     dst = jnp.broadcast_to(gi[:, :, None, None], valid.shape)
     return _compact_edges(

@@ -1,8 +1,10 @@
 """ctypes bindings for the native (C++/OpenMP) neighbor search + partitioner.
 
 The shared library is built on demand from ``src/`` with ``make`` (g++,
--O3 -march=native -fopenmp). If the build or load fails, callers fall back
-to the numpy implementations — same results, slower host path.
+-O3 -fopenmp; no ``-march=native``, so a library built on one machine of
+an installation loads on another). A failed build or load raises with the
+compiler's output: the numpy implementations are the tests' oracle, not a
+silent second path.
 
 No pybind11 in this image, so the ABI is a plain C handle API consumed via
 ctypes (see src/neighbor.cpp).
@@ -19,6 +21,11 @@ import numpy as np
 
 from .python_ref import NeighborList, neighbor_list_numpy
 
+
+class NativeBuildError(RuntimeError):
+    """``make`` or ``dlopen`` of the native library failed."""
+
+
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
 # DISTMLIP_TPU_NATIVE_LIB points the loader at an alternate build — the
 # sanitizer lane (make asan / make tsan in src/, see the Makefile) loads
@@ -28,62 +35,67 @@ _LIB_PATH = os.environ.get(
     os.path.join(os.path.dirname(__file__), "_native.so"))
 _lock = threading.Lock()
 _lib = None
-_load_failed = False
 
 
 def _build_and_load():
-    global _lib, _load_failed
+    """The loaded library, built first if it is missing or older than its
+    sources. Raises :class:`NativeBuildError` when it cannot be had."""
+    global _lib
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None:
             return _lib
-        try:
-            srcs = [os.path.join(_SRC_DIR, f) for f in os.listdir(_SRC_DIR) if f.endswith(".cpp")]
-            if "DISTMLIP_TPU_NATIVE_LIB" not in os.environ and (
-                not os.path.exists(_LIB_PATH) or any(
-                    os.path.getmtime(s) > os.path.getmtime(_LIB_PATH)
-                    for s in srcs)
-            ):
+        srcs = [os.path.join(_SRC_DIR, f) for f in os.listdir(_SRC_DIR)
+                if f.endswith((".cpp", "Makefile"))]
+        if "DISTMLIP_TPU_NATIVE_LIB" not in os.environ and (
+            not os.path.exists(_LIB_PATH) or any(
+                os.path.getmtime(s) > os.path.getmtime(_LIB_PATH)
+                for s in srcs)
+        ):
+            try:
                 subprocess.run(
                     ["make", "-s", "-C", _SRC_DIR],
                     check=True,
                     capture_output=True,
                     text=True,
                 )
+            except FileNotFoundError as e:
+                raise NativeBuildError(
+                    f"cannot build {_LIB_PATH}: {e}") from e
+            except subprocess.CalledProcessError as e:
+                raise NativeBuildError(
+                    f"building {_LIB_PATH} failed (make exit "
+                    f"{e.returncode}):\n{e.stderr}") from e
+        try:
             lib = ctypes.CDLL(_LIB_PATH)
-            lib.dm_neighbor_build.restype = ctypes.c_void_p
-            lib.dm_neighbor_build.argtypes = [
-                ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_double),
-                ctypes.POINTER(ctypes.c_double),
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_double,
-                ctypes.c_double,
-                ctypes.c_double,
-                ctypes.c_int,
-            ]
-            lib.dm_neighbor_num_edges.restype = ctypes.c_int64
-            lib.dm_neighbor_num_edges.argtypes = [ctypes.c_void_p]
-            lib.dm_neighbor_copy.restype = None
-            lib.dm_neighbor_copy.argtypes = [ctypes.c_void_p] + [
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_double),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_double),
-                ctypes.POINTER(ctypes.c_int64),
-            ]
-            lib.dm_neighbor_free.restype = None
-            lib.dm_neighbor_free.argtypes = [ctypes.c_void_p]
-            _lib = lib
-        except Exception:
-            _load_failed = True
-            _lib = None
+        except OSError as e:
+            raise NativeBuildError(f"cannot load {_LIB_PATH}: {e}") from e
+        lib.dm_neighbor_build.restype = ctypes.c_void_p
+        lib.dm_neighbor_build.argtypes = [
+            ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_double,
+            ctypes.c_double,
+            ctypes.c_double,
+            ctypes.c_int,
+        ]
+        lib.dm_neighbor_num_edges.restype = ctypes.c_int64
+        lib.dm_neighbor_num_edges.argtypes = [ctypes.c_void_p]
+        lib.dm_neighbor_copy.restype = None
+        lib.dm_neighbor_copy.argtypes = [ctypes.c_void_p] + [
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_uint8),
+            ctypes.POINTER(ctypes.c_double),
+            ctypes.POINTER(ctypes.c_int64),
+        ]
+        lib.dm_neighbor_free.restype = None
+        lib.dm_neighbor_free.argtypes = [ctypes.c_void_p]
+        _lib = lib
         return _lib
-
-
-def native_available() -> bool:
-    return _build_and_load() is not None
 
 
 def resolve_num_threads() -> int:
@@ -100,15 +112,16 @@ def neighbor_list(
     cart, lattice, pbc, r: float, bond_r: float = 0.0, tol: float = 1e-8,
     num_threads: int | None = None,
 ) -> NeighborList:
-    """Periodic neighbor search — native fast path with numpy fallback.
+    """Periodic neighbor search through the native library.
 
     Threads resolve as: explicit arg > DISTMLIP_TPU_NUM_THREADS >
     DISTMLIP_NUM_THREADS > 0 (= OpenMP default, all cores). The env-var knob
     mirrors the reference (pes.py:65-66).
     """
-    lib = _build_and_load()
-    if lib is None or np.asarray(cart).shape[0] == 0:
+    if np.asarray(cart).shape[0] == 0:
+        # the native handle API rejects an empty system
         return neighbor_list_numpy(cart, lattice, pbc, r, bond_r, tol)
+    lib = _build_and_load()
     if num_threads is None:
         num_threads = resolve_num_threads()
     cart = np.ascontiguousarray(cart, dtype=np.float64)
@@ -175,14 +188,11 @@ def native_partition(src, dst, frac_axis, walls, num_partitions, bond_mask,
                      use_bond_graph, num_threads=None):
     """Run the native partitioner; returns per-partition dict arrays.
 
-    Returns None if the native library is unavailable. Raises RuntimeError
-    with the offending node on a multi-destination border node (same
-    condition the numpy oracle raises PartitionError for).
+    Raises RuntimeError with the offending node on a multi-destination
+    border node (same condition the numpy oracle raises PartitionError
+    for).
     """
-    lib = _build_and_load()
-    if lib is None:
-        return None
-    _partition_symbols(lib)
+    lib = _partition_symbols(_build_and_load())
     if num_threads is None:
         num_threads = resolve_num_threads()
     src = np.ascontiguousarray(src, dtype=np.int64)

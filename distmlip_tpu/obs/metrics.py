@@ -32,7 +32,7 @@ import json
 import threading
 
 # fixed log-scale latency ladder: 100 µs doubling up to ~104 s. 21 rungs
-# cover everything from a cache hit to a wedged-grant stall.
+# cover everything from a cache hit to a wedged-replica stall.
 LATENCY_BUCKETS = tuple(1e-4 * 2 ** i for i in range(21))
 
 _KINDS = ("counter", "gauge", "histogram")

@@ -13,9 +13,10 @@ per-program :class:`RooflineRow` entries:
   the ridge.
 - **achieved** = flops / (time_s * n_devices) when a measured step time
   exists (bench JSONL, telemetry records); 0.0 otherwise.
-- **mfu** = achieved / peak, with peak from
-  :func:`~distmlip_tpu.utils.flops.peak_flops_per_device` (0.0 on CPU
-  runs — rows still render, utilization just reads n/a).
+- **mfu** = achieved / peak and **ridge** = peak FLOP/s / peak bytes/s,
+  both peaks from :func:`~distmlip_tpu.utils.flops.device_peaks` (no
+  entry on CPU runs — rows still render, utilization and bound read
+  n/a).
 
 Consumed by ``tools/roofline.py`` (CLI over the 28 contract-check
 programs) and ``telemetry_report`` (roofline section when records carry
@@ -111,7 +112,8 @@ class RooflineRow:
     flops: float = 0.0            # analytic FLOPs per step
     bytes: float = 0.0            # minimum HBM bytes per step
     time_s: float = 0.0           # measured step device time (0 = none)
-    peak_flops: float = 0.0       # per-device peak x n_devices (0 = unknown)
+    peak_flops: float = 0.0       # per-device peak FLOP/s (0 = unknown)
+    peak_bytes_per_s: float = 0.0  # per-device HBM bandwidth (0 = unknown)
     n_devices: int = 1
     source: str = "cost_model"    # "measured" when time_s came from a run
 
@@ -133,15 +135,15 @@ class RooflineRow:
 
     @property
     def ridge_bound(self) -> str:
-        """Which roof limits this program at ``peak_flops`` — "compute"
-        when its intensity clears the ridge point assuming the canonical
-        ~1 TB/s-class HBM per peak-PFLOP ratio is unknown; "" when peak
-        is unknown (no basis to place the ridge)."""
-        if self.peak_flops <= 0 or self.intensity <= 0:
+        """Which roof limits this program: "compute" when its intensity
+        clears the ridge ``peak_flops / peak_bytes_per_s`` (about 240
+        FLOP/byte on a v5e), "memory" below it; "" when either peak is
+        unknown (no basis to place the ridge)."""
+        if (self.peak_flops <= 0 or self.peak_bytes_per_s <= 0
+                or self.intensity <= 0):
             return ""
-        # ridge = peak_flops / hbm_bw; without a per-chip BW table use
-        # the conservative 100 FLOP/byte watershed typical of TPU gens
-        return "compute" if self.intensity >= 100.0 else "memory"
+        ridge = self.peak_flops / self.peak_bytes_per_s
+        return "compute" if self.intensity >= ridge else "memory"
 
     def as_dict(self) -> dict:
         return {
@@ -152,6 +154,7 @@ class RooflineRow:
             "time_s": self.time_s,
             "achieved_flops": self.achieved_flops,
             "peak_flops": self.peak_flops,
+            "peak_bytes_per_s": self.peak_bytes_per_s,
             "n_devices": self.n_devices,
             "mfu": round(self.mfu, 6),
             "ridge_bound": self.ridge_bound,
@@ -194,6 +197,9 @@ def rows_from_records(records) -> list:
     rounds where only some records carry the fields degrade to fewer
     rows, never to a KeyError.
     """
+    from ..utils.flops import device_peaks
+
+    peak_flops, peak_bw = device_peaks() or (0.0, 0.0)
     groups: dict[tuple, list] = {}
     for r in records:
         key = (getattr(r, "kind", ""), getattr(r, "bucket_key", ""))
@@ -220,12 +226,10 @@ def rows_from_records(records) -> list:
             continue
         times.sort()
         t_med = times[len(times) // 2] if times else 0.0
-        from ..utils.flops import peak_flops_per_device
-
         name = kind + (f"[{bucket}]" if bucket else "")
         rows.append(RooflineRow(
             program=name, flops=flops, bytes=nbytes, time_s=t_med,
-            peak_flops=peak_flops_per_device(), n_devices=n_dev,
+            peak_flops=peak_flops, peak_bytes_per_s=peak_bw, n_devices=n_dev,
             source="measured" if t_med > 0 else "cost_model"))
     return rows
 

@@ -1,6 +1,5 @@
 from .mesh import (BATCH_AXIS, GRAPH_AXIS, SPATIAL_AXIS, device_mesh,
-                   ensure_latency_hiding_flags, graph_mesh,
-                   latency_hiding_flags, mesh_shape)
+                   graph_mesh, mesh_shape)
 from .halo import HALO_MODES, LocalGraph, local_graph_from_stacked
 from .runtime import (make_total_energy, make_potential_fn,
                       make_batched_potential_fn, make_packed_energy_fn,
@@ -15,8 +14,6 @@ __all__ = [
     "device_mesh",
     "mesh_shape",
     "graph_mesh",
-    "latency_hiding_flags",
-    "ensure_latency_hiding_flags",
     "HALO_MODES",
     "LocalGraph",
     "local_graph_from_stacked",

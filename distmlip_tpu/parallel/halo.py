@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..geometry import COORD_PRECISION
 from ..kernels.dispatch import (Gather, fused_edge_aggregate,
                                 fused_segment_sum)
 from ..telemetry import scope
@@ -52,20 +53,13 @@ def validate_halo_mode(halo_mode: str) -> str:
             f"halo_mode={halo_mode!r}: expected one of {HALO_MODES}")
     return halo_mode
 
-if hasattr(lax, "axis_size"):  # jax >= 0.6
-    _axis_size = lax.axis_size
-else:  # 0.4.x: axis_frame(name) resolves to the (static) size
-    def _axis_size(axis_name):
-        frame = jax.core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)
-
 
 def _exchange(feats, send_idx, send_mask, recv_idx, shifts, axis_name):
     """Legacy round: one gather->ppermute->scatter per shift (S collectives
     per array)."""
     if not shifts or axis_name is None:
         return feats
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     for si, shift in enumerate(shifts):
         with scope(f"halo/shift{shift}"):
             idx = send_idx[si]
@@ -97,7 +91,7 @@ def _coalesced_round(groups, shifts, axis_name):
     """
     if not shifts or axis_name is None:
         return [g[0] for g in groups]
-    n_dev = _axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     S = len(shifts)
     dtype = jnp.result_type(*[g[0].dtype for g in groups])
     flats, shapes = [], []
@@ -278,7 +272,8 @@ class LocalGraph:
         """(E_cap, 3) displacement vectors dst - src + offsets @ lattice."""
         lat = self.lattice if lattice is None else lattice
         disp = positions[self.edge_dst] - positions[self.edge_src]
-        return disp + self.edge_offset.astype(positions.dtype) @ lat
+        return disp + jnp.matmul(self.edge_offset.astype(positions.dtype),
+                                 lat, precision=COORD_PRECISION)
 
     # ---- edge aggregation (interior/frontier aware) ----
     def aggregate_edges(self, data, mask=None):

@@ -19,17 +19,16 @@ and B x S (each packed structure itself spatially partitioned).
 unchanged. Multi-host meshes work as before: ``jax.devices()`` spans hosts
 and slab adjacency maps onto ICI/DCN neighbor links.
 
-This module also owns the XLA scheduler configuration for the
-overlap-aware halo pipeline: the coalesced exchange (parallel/halo.py)
-and the interior/frontier edge split (partition/graph.py) only pay off
-when XLA (a) lowers ``ppermute`` to an async collective-permute pair and
-(b) schedules independent compute between the start/done ops. Both are
-driven by XLA flags that must be set BEFORE the backend initializes.
+The overlap-aware halo pipeline — the coalesced exchange
+(parallel/halo.py) and the interior/frontier edge split
+(partition/graph.py) — pays off when XLA lowers ``ppermute`` to an async
+collective-permute start/done pair and schedules independent compute
+between the two. The TPU compiler does both by default (chip_smoke.py's
+MD-4 phase counts the pairs in the optimised HLO), so this package sets no
+XLA flags.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import numpy as np
@@ -42,73 +41,6 @@ SPATIAL_AXIS = "spatial"
 # addressing the ring by name
 GRAPH_AXIS = SPATIAL_AXIS
 
-# Latency-hiding configuration for the TPU backend: async collective
-# permutes (the halo ppermute becomes a start/done pair) + the
-# latency-hiding scheduler that moves interior edge compute between them.
-# TPU-only flags — the CPU backend (tests) rejects unknown xla_tpu_* flags,
-# so they are applied conditionally by ensure_latency_hiding_flags().
-LATENCY_HIDING_XLA_FLAGS = (
-    "--xla_tpu_enable_async_collective_permute=true",
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-)
-
-
-def latency_hiding_flags() -> tuple[str, ...]:
-    """The XLA flags the overlap pipeline wants on TPU (documentation /
-    tooling surface; see ensure_latency_hiding_flags for the setter)."""
-    return LATENCY_HIDING_XLA_FLAGS
-
-
-def _backend_initialized() -> bool:
-    """True once an XLA backend exists (flag changes no longer take)."""
-    try:
-        from jax._src import xla_bridge
-
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:  # noqa: BLE001 - private API; assume live if unsure
-        return True
-
-
-def ensure_latency_hiding_flags(force: bool | None = None) -> bool:
-    """Append the latency-hiding flags to ``XLA_FLAGS`` when they can still
-    take effect. Returns True when the flags are (already) present.
-
-    Applied only when a TPU platform is explicitly requested
-    (``JAX_PLATFORMS`` mentions tpu) or ``DISTMLIP_LATENCY_HIDING=1``
-    forces it, because other clients reject unknown ``xla_tpu_*`` flags —
-    a CPU test run on a TPU-capable image must not poison its own
-    ``XLA_FLAGS``. ``DISTMLIP_LATENCY_HIDING=0`` disables; the ``force``
-    argument overrides both. Callers on the hot path (graph_mesh) invoke
-    this best-effort: once the backend is live the environment is left
-    untouched.
-    """
-    existing = os.environ.get("XLA_FLAGS", "")
-    if all(f.split("=")[0] in existing for f in LATENCY_HIDING_XLA_FLAGS):
-        return True
-    env = os.environ.get("DISTMLIP_LATENCY_HIDING")
-    if force is None:
-        if env == "0":
-            return False
-        if env == "1":
-            force = True
-    if not force:
-        platforms = os.environ.get("JAX_PLATFORMS", "").lower()
-        if "tpu" not in platforms:
-            return False
-    if _backend_initialized():
-        import warnings
-
-        warnings.warn(
-            "latency-hiding XLA flags requested but the XLA backend is "
-            "already initialized — they cannot take effect this process. "
-            "Import distmlip_tpu (or call ensure_latency_hiding_flags) "
-            "before anything touches jax.devices().", stacklevel=2)
-        return False
-    missing = [f for f in LATENCY_HIDING_XLA_FLAGS
-               if f.split("=")[0] not in existing]
-    os.environ["XLA_FLAGS"] = (existing + " " + " ".join(missing)).strip()
-    return True
-
 
 def device_mesh(batch: int = 1, spatial: int = 1, devices=None) -> Mesh:
     """The named 2-D ``Mesh(("batch", "spatial"))`` of ``batch * spatial``
@@ -119,7 +51,6 @@ def device_mesh(batch: int = 1, spatial: int = 1, devices=None) -> Mesh:
     rides ICI neighbor links within each batch row; batch rows never talk
     to each other (no batch-axis collectives by construction).
     """
-    ensure_latency_hiding_flags()
     devices = list(devices if devices is not None else jax.devices())
     batch, spatial = int(batch), int(spatial)
     if batch < 1 or spatial < 1:

@@ -29,25 +29,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..geometry import apply_strain
+from ..geometry import COORD_PRECISION, apply_strain
 from ..partition.graph import PartitionedGraph
 from ..telemetry import scope
 from .halo import local_graph_from_stacked
 from .mesh import (BATCH_AXIS, GRAPH_AXIS, SPATIAL_AXIS, mesh_row_axes,
                    mesh_shape)
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# the "don't require replication-invariance checks" kwarg was renamed
-# check_rep -> check_vma across jax versions; detect which one this build has
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(shard_map).parameters else "check_rep")
-_NO_CHECK = {_CHECK_KW: False}
 
 
 def graph_row_axes(graph: PartitionedGraph):
@@ -174,12 +161,12 @@ def make_total_energy(model_energy_fn, mesh: Mesh | None,
     def total_energy(params, graph, positions, strain):
         axes = mesh_row_axes(mesh)
         out_specs = (P(), P(axes)) if aux else P()
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_energy,
             mesh=mesh,
             in_specs=(P(), P(), graph_in_specs(graph, axes), P(axes)),
             out_specs=out_specs,
-            **_NO_CHECK,
+            check_vma=False,
         )
         return sharded(params, strain, graph, positions)
 
@@ -229,12 +216,12 @@ def make_site_fn(model_site_fn, mesh: Mesh | None,
     @jax.jit
     def site_fn(params, graph, positions):
         axes = mesh_row_axes(mesh)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_site,
             mesh=mesh,
             in_specs=(P(), graph_in_specs(graph, axes), P(axes)),
             out_specs=P(axes),
-            **_NO_CHECK,
+            check_vma=False,
         )
         return sharded(params, graph, positions)
 
@@ -350,10 +337,10 @@ def make_packed_energy_fn(model_energy_fn, mesh: Mesh | None = None,
         def local_e(params, strain, graph_local, positions):
             return local_energy(params, strain, graph_local, positions)[0]
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_e, mesh=mesh,
             in_specs=(P(), P(BATCH_AXIS), graph_in_specs(graph, axes), row),
-            out_specs=P(BATCH_AXIS), **_NO_CHECK)
+            out_specs=P(BATCH_AXIS), check_vma=False)
         return sharded(params, strain, graph, positions)
 
     return packed_energy
@@ -399,10 +386,12 @@ def _local_batched_energy(model_energy_fn, aux, halo_mode="coalesced",
             # their owner's strained coordinates).
             sym = 0.5 * (strain + jnp.swapaxes(strain, -1, -2)).astype(dtype)
             defm = jnp.eye(3, dtype=dtype)[None, :, :] + sym      # (B, 3, 3)
-            pos = jnp.einsum("ni,nij->nj", pos, defm[sid])
+            pos = jnp.einsum("ni,nij->nj", pos, defm[sid],
+                             precision=COORD_PRECISION)
             esid = sid[lg.edge_dst]  # edge's structure (dst rows are real)
             lg.edge_offset = jnp.einsum(
-                "ei,eij->ej", lg.edge_offset.astype(dtype), defm[esid])
+                "ei,eij->ej", lg.edge_offset.astype(dtype), defm[esid],
+                precision=COORD_PRECISION)
         # spatially partitioned structures refresh their halo rows from the
         # owning slab (strained above); a no-op on S=1 placements
         pos = lg.halo_exchange(pos)
@@ -504,18 +493,18 @@ def make_batched_potential_fn(model_energy_fn, compute_stress: bool = True,
                     # back to the packed (P, N_cap, ...) layout
                     return energies, jax.tree.map(lambda a: a[None], aux_out)
 
-                sharded = shard_map(
+                sharded = jax.shard_map(
                     local_aux, mesh=mesh, in_specs=in_specs,
-                    out_specs=(P(BATCH_AXIS), row), **_NO_CHECK)
+                    out_specs=(P(BATCH_AXIS), row), check_vma=False)
                 energies, aux_out = sharded(params, strain, graph, positions)
             else:
                 def local_e(params, strain, graph_local, positions):
                     return local_energy(params, strain, graph_local,
                                         positions)[0]
 
-                sharded = shard_map(
+                sharded = jax.shard_map(
                     local_e, mesh=mesh, in_specs=in_specs,
-                    out_specs=P(BATCH_AXIS), **_NO_CHECK)
+                    out_specs=P(BATCH_AXIS), check_vma=False)
                 energies = sharded(params, strain, graph, positions)
                 aux_out = None
             return jnp.sum(energies), (energies, aux_out)

@@ -116,9 +116,9 @@ def build_plan(
 ) -> PartitionPlan:
     """Partition a neighbor graph into ``num_partitions`` slabs with halos.
 
-    impl: "auto" prefers the native C++/OpenMP partitioner and falls back to
-    numpy; "native"/"numpy" force one implementation (tests compare the two
-    for exact equality).
+    impl: "auto"/"native" run the native C++/OpenMP partitioner; "numpy"
+    is the oracle implementation below (tests compare the two for exact
+    equality).
 
     grid: optional (gx, gy, gz) block decomposition (prod == num_partitions)
     — delegates to :func:`build_block_plan`, which drops the slab path's
@@ -147,11 +147,8 @@ def build_plan(
     walls = make_walls(frac[:, axis], P)
 
     if impl in ("auto", "native"):
-        plan = _build_plan_native(nl, frac[:, axis], axis, walls, P, use_bond_graph)
-        if plan is not None:
-            return plan
-        if impl == "native":
-            raise PartitionError("native partitioner unavailable")
+        return _build_plan_native(nl, frac[:, axis], axis, walls, P,
+                                  use_bond_graph)
 
     node_part = which_partition(walls, frac[:, axis])
 
@@ -221,7 +218,7 @@ def build_plan(
     return plan
 
 
-def _build_plan_native(nl, frac_axis, axis, walls, P, use_bond_graph) -> PartitionPlan | None:
+def _build_plan_native(nl, frac_axis, axis, walls, P, use_bond_graph) -> PartitionPlan:
     """Native C++ partitioner path; output layout identical to the numpy
     oracle (verified exactly in tests/test_partition.py)."""
     from ..neighbors import native as _native
@@ -233,8 +230,6 @@ def _build_plan_native(nl, frac_axis, axis, walls, P, use_bond_graph) -> Partiti
         )
     except RuntimeError as e:
         raise PartitionError(str(e)) from e
-    if parts is None:
-        return None
     if use_bond_graph:
         W = np.nonzero(nl.bond_mask)[0]
         if np.any(nl.src[W] == nl.dst[W]):

@@ -328,8 +328,8 @@ class ServeEngine:
         scheduler last made dispatch progress (on the engine clock). A
         wedged engine shows ``queue_depth > 0`` (or in-flight work) with
         an ever-growing ``last_progress_age_s`` while
-        ``scheduler_alive`` stays True — the BENCH_r03 signature, visible
-        without touching the device."""
+        ``scheduler_alive`` stays True — visible without touching the
+        device."""
         with self._cv:
             return {
                 "queue_depth": len(self._pending),

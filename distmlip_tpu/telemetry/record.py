@@ -112,7 +112,9 @@ class StepRecord:
     # path in the traced program (1.0 = fully fused, 0.0 = pure XLA)
     kernel_coverage: float = 0.0
     flops_per_step: float = 0.0      # analytic estimate (utils/flops.py)
-    mfu: float = 0.0                 # flops / (device_s * devices * peak)
+    # flops / (device_s * devices * peak); None = not computed (no
+    # published peak for this device: a CPU run)
+    mfu: float | None = None
 
     # --- halo volumes (rows exchanged per partition, summed over shifts) ---
     halo_send_per_part: list[int] = field(default_factory=list)

@@ -386,7 +386,7 @@ def aggregate(
     fr = [r.frontier_edge_frac for r in records if r.frontier_edge_frac > 0]
     if fr:
         c["mean_frontier_edge_frac"] = sum(fr) / len(fr)
-    mfus = [r.mfu for r in records if r.mfu > 0]
+    mfus = [r.mfu for r in records if r.mfu]
     if mfus:
         c["mean_mfu"] = sum(mfus) / len(mfus)
         c["max_mfu"] = max(mfus)

@@ -54,7 +54,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import BATCH_AXIS, mesh_shape
-from ..parallel.runtime import _NO_CHECK, make_packed_energy_fn, shard_map
+from ..parallel.runtime import make_packed_energy_fn
 
 
 @dataclass(frozen=True)
@@ -302,10 +302,10 @@ def _zero1_apply(optimizer, mesh, grads, opt_state, params):
         full = jax.lax.all_gather(p_new[0], BATCH_AXIS, axis=0, tiled=False)
         return full, o2
 
-    full_p, new_opt = shard_map(
+    full_p, new_opt = jax.shard_map(
         shard_update, mesh=mesh,
         in_specs=(P(BATCH_AXIS), opt_specs, P(BATCH_AXIS)),
-        out_specs=(P(), opt_specs), **_NO_CHECK)(g2, opt_state, p2)
+        out_specs=(P(), opt_specs), check_vma=False)(g2, opt_state, p2)
     new_params = unravel(full_p.reshape(-1)[:n])
     return new_params, new_opt
 

@@ -1,0 +1,33 @@
+"""Persistent XLA compile cache, placed from outside or at a fixed path.
+
+A MACE step at published width compiles for minutes and every fresh
+machine starts with no compiled code, so each entry point that compiles on
+the chip (``chip_smoke.py``, ``bench.py``, ``tools/load_test.py``) calls
+:func:`enable_compile_cache` before its first jit.
+"""
+
+from __future__ import annotations
+
+import os
+
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    this sets no path in code. Otherwise the cache lives in
+    ``<checkout>/.jax_cache`` — a FIXED path (git-ignored): the directory
+    is part of the cache key, so one derived from a temp name, a pid or
+    the time would never hit.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

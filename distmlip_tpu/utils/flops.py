@@ -19,21 +19,18 @@ Conventions:
 
 from __future__ import annotations
 
-import os
-
 FWD_BWD_FACTOR = 3.0  # forward + ~2x forward for the reverse pass
 
-# peak dense FLOP/s per device by device_kind substring (bf16 MXU numbers
-# for TPUs; fp32 tensor numbers would be ~half). Extend as chips appear.
-_PEAK_TABLE = (
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 394e12),
-    ("v5litepod", 394e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# Published peaks of ONE chip, keyed by the exact ``device_kind`` jax
+# reports for it: (dense bf16 FLOP/s, HBM bytes/s). A chip enters this
+# table with the string read off the chip and the page the numbers came
+# from; a TPU that is not here is an error, never a default.
+DEVICE_PEAKS = {
+    # v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 (393
+    # TOP/s is the int8 figure), 16 GB HBM2e at 819 GB/s. device_kind read
+    # on the chip through chip_smoke.py (PR 21).
+    "TPU v5 lite": (197e12, 819e9),
+}
 
 
 def _mlp_flops(dims, rows: float) -> float:
@@ -153,7 +150,7 @@ def model_flop_estimate(model, n_atoms: float, n_edges: float,
                         n_lines: float = 0.0) -> float:
     """One potential step's estimated FLOPs (energy + forces [+ stress])
     for ``model`` on a graph of the given shape; 0.0 when the model family
-    is unknown (mfu then reads 0 rather than lying)."""
+    is unknown (mfu is then not computed)."""
     cfg = getattr(model, "cfg", None)
     if cfg is None:
         return 0.0
@@ -176,33 +173,38 @@ def model_flop_estimate(model, n_atoms: float, n_edges: float,
     return factor * fwd
 
 
-def peak_flops_per_device(default: float = 0.0) -> float:
-    """Peak dense FLOP/s of one local device. ``DISTMLIP_PEAK_FLOPS``
-    overrides; otherwise the device_kind lookup table; 0.0 when unknown
-    (CPU test runs) so downstream mfu stays 0 instead of fabricated."""
-    env = os.environ.get("DISTMLIP_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
+def device_peaks(device=None) -> tuple[float, float] | None:
+    """``(peak FLOP/s, peak HBM bytes/s)`` of ``device`` (default: the
+    first local device) from :data:`DEVICE_PEAKS`. None off-TPU — a CPU
+    has no entry and utilisation is then not computed; a TPU whose
+    ``device_kind`` is not in the table raises."""
+    if device is None:
         import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 - no backend, no peak
-        return default
-    for key, peak in _PEAK_TABLE:
-        if key in kind:
-            return peak
-    return default
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peak for TPU device_kind {device.device_kind!r}; "
+            f"add it to utils/flops.DEVICE_PEAKS with its source "
+            f"(known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device.device_kind]
+
+
+def peak_flops_per_device(device=None) -> float | None:
+    """Peak dense bf16 FLOP/s of one device; None off-TPU."""
+    peaks = device_peaks(device)
+    return None if peaks is None else peaks[0]
 
 
 def mfu(flops_per_step: float, device_s: float, n_devices: int,
-        peak: float | None = None) -> float:
-    """Model FLOP utilization in [0, 1]; 0.0 whenever any input is unknown."""
+        peak: float | None = None) -> float | None:
+    """Model FLOP utilization in [0, 1]. ``peak`` defaults to this
+    device's table entry; None (not computed) when there is no peak (CPU)
+    or nothing was measured."""
     if peak is None:
         peak = peak_flops_per_device()
-    if flops_per_step <= 0 or device_s <= 0 or peak <= 0 or n_devices <= 0:
-        return 0.0
+    if peak is None or flops_per_step <= 0 or device_s <= 0:
+        return None
     return flops_per_step / (device_s * n_devices * peak)

@@ -7,11 +7,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-# default: 8-virtual-device CPU mesh so the example runs anywhere;
-# set DISTMLIP_REAL_DEVICES=1 to use the machine's real accelerators
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_num_cpu_devices", 8)
+# Runs on the backend jax finds. For the 8-virtual-device CPU mesh:
+#   JAX_PLATFORMS=cpu python examples/02_relax_chgnet.py
+jax.config.update("jax_num_cpu_devices", 8)  # read by the CPU backend only
 
 import numpy as np
 

@@ -23,8 +23,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_num_cpu_devices", 8)
+# Runs on the backend jax finds. For the 8-virtual-device CPU mesh:
+#   JAX_PLATFORMS=cpu python examples/04_pretrained_and_uma.py
+jax.config.update("jax_num_cpu_devices", 8)  # read by the CPU backend only
 
 import numpy as np
 

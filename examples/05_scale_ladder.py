@@ -10,9 +10,9 @@ Run: python examples/05_scale_ladder.py [--config 2|3|4|5]
   4: eSCN/UMA ~101k atoms, 8-way (csd + MOLE + chunked Wigner/SO(2))
   5: MACE ~1M atoms, 16-way over a virtual 2-host x 8-chip topology
      (BASELINE config 5 proxy; DISTMLIP_C5_REPS shrinks the box)
-Set DISTMLIP_REAL_DEVICES=1 to run configs 3/4 single-chip on real
-hardware (bf16, production model shapes) instead of the CPU-mesh
-correctness compare.
+Runs on the backend jax finds. On a TPU, configs 3/4/5 run single-chip at
+production model shapes in bf16; under ``JAX_PLATFORMS=cpu`` every config
+is the virtual-CPU-mesh correctness compare.
 """
 
 import os
@@ -22,25 +22,22 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-# default: virtual CPU mesh (set DISTMLIP_REAL_DEVICES=1 to use real chips;
-# probing jax.devices() first would initialize the backend and pin us to it).
-# config 5 (the multi-host proxy) needs 16 virtual devices — decided BEFORE
-# the backend initializes.
+# config 5 (the multi-host proxy) needs 16 virtual CPU devices — decided
+# BEFORE the backend initializes; the option is read by the CPU backend only
 _N_VIRT = 16 if ("--config" in sys.argv
                  and sys.argv[sys.argv.index("--config") + 1] == "5") else 8
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
+jax.config.update("jax_num_cpu_devices", _N_VIRT)
+if os.environ.get("JAX_PLATFORMS") == "cpu":
     # XLA-CPU in-process collectives hard-terminate if all shards don't
     # reach a rendezvous within 40 s. 16 serialized virtual shards at 1M
     # atoms ALWAYS trip it, and even 4-way 48k-atom shards do on a loaded
-    # host (observed round 5). Raise the deadline for every CPU-mesh run:
-    # these are correctness proxies, not perf runs (real TPU collectives
-    # have no in-process rendezvous).
+    # host. Raise the deadline for every CPU-mesh run: these are
+    # correctness proxies, not perf runs (real TPU collectives have no
+    # in-process rendezvous).
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_cpu_collective_call_terminate_timeout_seconds=100000"
         + " --xla_cpu_collective_call_warn_stuck_timeout_seconds=3600")
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_num_cpu_devices", _N_VIRT)
 
 import time
 
@@ -102,13 +99,13 @@ def config3():
 
     On the CPU mesh the model is shrunk (channels=32, l_max=2, 1 interaction
     — the partition/halo/capacity machinery still sees the full 200k-atom
-    graph); with DISTMLIP_REAL_DEVICES=1 and a TPU visible it runs the
+    graph); on a TPU it runs the
     MP-0-faithful shape (128ch, l_max=a_lmax=3, correlation 3) in bfloat16
     single-chip — BASELINE.md config 3's memory proof.
     """
     from distmlip_tpu.models import MACE, MACEConfig
 
-    real = bool(os.environ.get("DISTMLIP_REAL_DEVICES"))
+    real = jax.default_backend() == "tpu"
     rng = np.random.default_rng(0)
     # beta-cristobalite-ish SiO2: 24-atom cubic cell ~7.16 A, perturbed hard
     unit = np.array([
@@ -166,12 +163,12 @@ def config4():
     expert gating (psum-consistent across partitions), the edge-degree
     embedding, and the edge-chunked Wigner/SO(2) scan (ops/chunk.py) that
     bounds per-edge memory — at this size the unchunked rotated features
-    alone would be ~37 GB. With DISTMLIP_REAL_DEVICES=1 a single real chip
-    runs the same system in bfloat16 at l_max=4.
+    alone would be ~37 GB. On a TPU a single chip runs the same system in
+    bfloat16 at l_max=4.
     """
     from distmlip_tpu.models import ESCN, ESCNConfig
 
-    real = bool(os.environ.get("DISTMLIP_REAL_DEVICES"))
+    real = jax.default_backend() == "tpu"
     rng = np.random.default_rng(0)
     unit = np.array([[0, 0, 0], [0.5, 0.5, 0], [0.5, 0, 0.5], [0, 0.5, 0.5]])
     frac, lattice = geometry.make_supercell(unit, np.eye(3) * 4.2, (30, 30, 28))
@@ -215,15 +212,14 @@ def config5():
     slice). Validates 16-way == 4-way at the north-star atom count; model
     is CPU-mesh-sized (the real-chip shape is bench.py's).
 
-    With DISTMLIP_REAL_DEVICES=1 this becomes the north-star TIMING run
-    instead: the full 1,000,188-atom box through the MP-0-faithful MACE
+    On a TPU this becomes the north-star TIMING run instead: the full 1,000,188-atom box through the MP-0-faithful MACE
     (128ch, l_max=a_lmax=3, correlation 3) in bfloat16 on ONE chip, edge-
     chunked per the ROADMAP.md HBM budget, MD-style perturbed warm steps
     (skin reuse), peak HBM printed. DISTMLIP_C5_EDGE_CHUNK /
     DISTMLIP_C5_NODE_CHUNK trim the chunk sizes if the first attempt OOMs."""
     from distmlip_tpu.models import MACE, MACEConfig
 
-    real = bool(os.environ.get("DISTMLIP_REAL_DEVICES"))
+    real = jax.default_backend() == "tpu"
     rng = np.random.default_rng(0)
     reps = int(os.environ.get("DISTMLIP_C5_REPS", "63"))
     unit = np.array([[0, 0, 0], [0.5, 0.5, 0], [0.5, 0, 0.5], [0, 0.5, 0.5]])

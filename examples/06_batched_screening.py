@@ -14,11 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-# single CPU device is fine: the batched engine is single-partition by
-# design (it scales DOWNWARD to many small graphs; DistPotential scales
-# one large graph across devices)
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    jax.config.update("jax_platforms", "cpu")
+# Runs on the backend jax finds; pass JAX_PLATFORMS=cpu to stay off a chip.
 
 import numpy as np
 

@@ -14,10 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-# single CPU device is fine: serving scales many small graphs onto one
-# chip (use DistPotential for one large halo-partitioned structure)
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    jax.config.update("jax_platforms", "cpu")
+# Runs on the backend jax finds; pass JAX_PLATFORMS=cpu to stay off a chip.
 
 import threading
 

@@ -8,7 +8,9 @@ communication contract — the batch axis NEVER carries a collective, the
 spatial axis pays exactly the 1-D ring's ppermutes — is auditable at the
 jaxpr level, shown below.
 
-Run: python examples/08_mesh_placement.py  (8 virtual CPU devices)
+Run: JAX_PLATFORMS=cpu python examples/08_mesh_placement.py
+(8 virtual CPU devices; without the variable it runs on the devices jax
+finds and needs eight of them)
 """
 
 import os
@@ -16,20 +18,11 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# 8 virtual CPU devices so every placement of a 2-D mesh runs for real
-# (set DISTMLIP_REAL_DEVICES=1 to use real chips instead). Must be decided
-# before the XLA CPU client initializes.
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    _flag = "--xla_force_host_platform_device_count=8"
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") + " " + _flag).strip()
-
 import jax
 
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    jax.config.update("jax_platforms", "cpu")
+# 8 virtual CPU devices so every placement of a 2-D mesh runs for real;
+# decided before the backend initializes, read by the CPU backend only
+jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np
 

@@ -19,8 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    jax.config.update("jax_platforms", "cpu")
+# Runs on the backend jax finds; pass JAX_PLATFORMS=cpu to stay off a chip.
 
 import numpy as np
 import optax

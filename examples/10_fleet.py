@@ -23,10 +23,9 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
+# Runs on the backend jax finds; pass JAX_PLATFORMS=cpu to stay off a chip.
 
 import numpy as np  # noqa: E402
 

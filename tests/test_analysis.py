@@ -26,10 +26,6 @@ from distmlip_tpu.analysis import (Program, Severity, error_count, exit_code,
 
 pytestmark = pytest.mark.contracts
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def _findings(pass_name, findings):
@@ -55,7 +51,7 @@ def test_seeded_hidden_batch_axis_psum():
         def local(v):
             return jax.lax.psum(v, BATCH_AXIS)
 
-        return shard_map(local, mesh=mesh,
+        return jax.shard_map(local, mesh=mesh,
                          in_specs=P(BATCH_AXIS), out_specs=P())(x)
 
     jaxpr = jax.make_jaxpr(bad)(jnp.ones((4, 3), jnp.float32))
@@ -121,14 +117,13 @@ def test_seeded_callback_device_resident_program():
 def test_seeded_f64_leak():
     """An un-cast np.float64 closure array promotes the device path to f64
     under x64 tracing: both the aval walk and the const scan must fire."""
-    from jax.experimental import enable_x64
 
     leak = np.random.default_rng(0).normal(size=(8, 3))  # float64 host array
 
     def bad(x):
         return jnp.sum(x * leak)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(bad)(jnp.ones((8, 3), jnp.float32))
     findings = run_passes(
         Program(name="seeded_f64", jaxpr=jaxpr,
@@ -221,7 +216,7 @@ def test_seeded_dead_collective():
             dead = jax.lax.psum(v, SPATIAL_AXIS)  # noqa: F841 - seeded
             return v * 2.0
 
-        return shard_map(local, mesh=mesh,
+        return jax.shard_map(local, mesh=mesh,
                          in_specs=P(SPATIAL_AXIS), out_specs=P(SPATIAL_AXIS))(x)
 
     jaxpr = jax.make_jaxpr(bad)(jnp.ones((4, 3), jnp.float32))
@@ -346,7 +341,7 @@ def test_total_gates_count_eqns_like_count_collectives():
         def local(v):
             return jax.lax.psum(v, (BATCH_AXIS, SPATIAL_AXIS))
 
-        return shard_map(local, mesh=mesh,
+        return jax.shard_map(local, mesh=mesh,
                          in_specs=P(BATCH_AXIS, SPATIAL_AXIS),
                          out_specs=P())(x)
 
@@ -454,11 +449,10 @@ def _clean_model_programs(name):
     import tools.contract_check as cc
     from distmlip_tpu.parallel import make_potential_fn, make_total_energy
 
-    from jax.experimental import enable_x64
 
     model, params, use_bg, bond_r = cc.make_model(name)
     g1 = cc._graph_for(model, use_bg, bond_r, 1)
-    with enable_x64():
+    with jax.enable_x64(True):
         efn = make_total_energy(model.energy_fn, None)
         jx_e = jax.make_jaxpr(efn)(params, g1, g1.positions,
                                    jnp.zeros((3, 3), np.float32))

@@ -12,7 +12,6 @@ from distmlip_tpu.parallel.halo import local_graph_from_stacked
 from distmlip_tpu.partition import build_plan, build_partitioned_graph
 from tests.conftest import random_cell
 
-from distmlip_tpu.parallel.runtime import _NO_CHECK, shard_map
 
 R = 3.0
 
@@ -45,9 +44,9 @@ def test_halo_exchange_delivers_owner_rows(rng, nparts):
         lg, _ = local_graph_from_stacked(graph_l, GRAPH_AXIS)
         return lg.halo_exchange(feats[0])[None]
 
-    out = shard_map(
+    out = jax.shard_map(
         f, mesh=mesh, in_specs=(graph_in_specs(graph), P(GRAPH_AXIS)),
-        out_specs=P(GRAPH_AXIS), **_NO_CHECK,
+        out_specs=P(GRAPH_AXIS), check_vma=False,
     )(graph, jnp.asarray(local))
     out = np.asarray(out)
     for p in range(nparts):
@@ -69,9 +68,9 @@ def test_halo_exchange_gradients_flow_to_owner(rng, nparts):
         return jax.lax.psum(jnp.sum(full * halo_mask[:, None]), GRAPH_AXIS)
 
     def total(feats):
-        return shard_map(
+        return jax.shard_map(
             loss, mesh=mesh, in_specs=(graph_in_specs(graph), P(GRAPH_AXIS)),
-            out_specs=P(), **_NO_CHECK,
+            out_specs=P(), check_vma=False,
         )(graph, feats)
 
     local = jnp.asarray(host.scatter_global(np.zeros((n, 2), np.float32), graph.n_cap))
@@ -104,9 +103,9 @@ def test_bond_halo_exchange(rng, nparts):
         return lg.bond_halo_exchange(feats[0])[None]
 
     out = np.asarray(
-        shard_map(
+        jax.shard_map(
             f, mesh=mesh, in_specs=(graph_in_specs(graph), P(GRAPH_AXIS)),
-            out_specs=P(GRAPH_AXIS), **_NO_CHECK,
+            out_specs=P(GRAPH_AXIS), check_vma=False,
         )(graph, local)
     )
     for p in range(nparts):

@@ -28,7 +28,6 @@ from distmlip_tpu.parallel import (GRAPH_AXIS, graph_in_specs, graph_mesh,
 from distmlip_tpu.parallel.audit import (count_collectives,
                                          ppermutes_by_scope)
 from distmlip_tpu.parallel.halo import local_graph_from_stacked
-from distmlip_tpu.parallel.runtime import _NO_CHECK, shard_map
 from distmlip_tpu.partition import (CapacityPolicy, build_partitioned_graph,
                                     build_plan)
 from tests.utils import make_crystal
@@ -86,10 +85,10 @@ def test_coalesced_one_ppermute_per_exchange_round(rng, params):
             lg, _ = local_graph_from_stacked(g, GRAPH_AXIS, "coalesced")
             return MODEL.energy_fn(params, lg, pos[0])[None]
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(graph_in_specs(graph), P(GRAPH_AXIS)),
-            out_specs=P(GRAPH_AXIS), **_NO_CHECK,
+            out_specs=P(GRAPH_AXIS), check_vma=False,
         )(graph, positions)
 
     n = _ppermute_count(forward, params, graph, graph.positions)
@@ -236,9 +235,9 @@ def test_gradients_flow_to_owner_both_modes(rng, mode):
         return jax.lax.psum(jnp.sum(full * halo_mask[:, None]), GRAPH_AXIS)
 
     def total(feats):
-        return shard_map(
+        return jax.shard_map(
             loss, mesh=mesh, in_specs=(graph_in_specs(graph), P(GRAPH_AXIS)),
-            out_specs=P(), **_NO_CHECK,
+            out_specs=P(), check_vma=False,
         )(graph, feats)
 
     local = jnp.asarray(host.scatter_global(
@@ -275,10 +274,10 @@ def test_exchange_all_matches_sequential(rng):
                 (xa[0], xb[0].astype(jnp.bfloat16)), ())
             return a[None], b.astype(jnp.float32)[None]
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh,
             in_specs=(graph_in_specs(graph), P(GRAPH_AXIS), P(GRAPH_AXIS)),
-            out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)), **_NO_CHECK,
+            out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)), check_vma=False,
         )(graph, jnp.asarray(la), jnp.asarray(lb))
 
     a_c, b_c = run("coalesced")

@@ -199,26 +199,26 @@ def test_so2_conv_parity_and_grads(rng):
 @pytest.mark.tier1
 def test_resolve_kernel_mode_routing(monkeypatch):
     monkeypatch.delenv("DISTMLIP_KERNELS", raising=False)
-    assert resolve_kernel_mode(False) == "xla"
-    assert resolve_kernel_mode("interpret") == "interpret"
+    assert resolve_kernel_mode(False, op="segment_sum") == "xla"
+    assert resolve_kernel_mode("interpret", op="segment_sum") == "interpret"
     # backend default on this CPU host is the XLA fallback
-    assert resolve_kernel_mode(None) == "xla"
+    assert resolve_kernel_mode(None, op="segment_sum") == "xla"
     # env kill switch beats everything except the explicit per-object flag
     monkeypatch.setenv("DISTMLIP_KERNELS", "0")
-    assert resolve_kernel_mode(None) == "xla"
+    assert resolve_kernel_mode(None, op="segment_sum") == "xla"
     monkeypatch.setenv("DISTMLIP_KERNELS", "interpret")
-    assert resolve_kernel_mode(None) == "interpret"
-    assert resolve_kernel_mode(False) == "xla"
+    assert resolve_kernel_mode(None, op="segment_sum") == "interpret"
+    assert resolve_kernel_mode(False, op="segment_sum") == "xla"
     monkeypatch.setenv("DISTMLIP_KERNELS", "on")
-    assert resolve_kernel_mode(None) == "pallas"
+    assert resolve_kernel_mode(None, op="segment_sum") == "pallas"
     # the force context wins over env + object flags (contract checker)
     with force_kernel_mode("xla"):
-        assert resolve_kernel_mode("interpret") == "xla"
+        assert resolve_kernel_mode("interpret", op="segment_sum") == "xla"
     with pytest.raises(ValueError, match="expected"):
         with force_kernel_mode("bogus"):
             pass
     with pytest.raises(ValueError, match="expected"):
-        resolve_kernel_mode("bogus")
+        resolve_kernel_mode("bogus", op="segment_sum")
 
 
 @pytest.mark.tier1

@@ -125,7 +125,6 @@ def test_shard_map_args_scale_per_device():
     from jax.sharding import PartitionSpec as P
 
     from distmlip_tpu.parallel import SPATIAL_AXIS, graph_mesh
-    from distmlip_tpu.parallel.runtime import _NO_CHECK, shard_map
 
     mesh = graph_mesh(4)
     x = jnp.ones((4, 1024, 64), jnp.float32)     # 1 MiB global
@@ -133,8 +132,8 @@ def test_shard_map_args_scale_per_device():
     def local(xs):
         return jax.lax.psum((xs * 2.0).sum(), SPATIAL_AXIS)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P(SPATIAL_AXIS),),
-                   out_specs=P(), **_NO_CHECK)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(SPATIAL_AXIS),),
+                       out_specs=P(), check_vma=False)
     jaxpr = jax.make_jaxpr(fn)(x)
     plan = analyze_memory(jaxpr)
     nbytes = 4 * 1024 * 64 * 4

@@ -8,7 +8,6 @@ from distmlip_tpu.neighbors import (
     neighbor_list_brute,
     neighbor_list_numpy,
 )
-from distmlip_tpu.neighbors.native import native_available
 from tests.conftest import random_cell
 
 
@@ -27,8 +26,6 @@ def _assert_same(a, b):
     "n_atoms,box,r", [(20, 6.0, 2.5), (60, 9.0, 3.5), (12, 3.0, 2.9)]
 )
 def test_vs_brute_force(rng, impl, n_atoms, box, r):
-    if impl == "native" and not native_available():
-        pytest.skip("native lib unavailable")
     cart, lattice, _, pbc = random_cell(rng, n_atoms=n_atoms, box=box, jitter=1.0)
     fn = neighbor_list_numpy if impl == "numpy" else neighbor_list
     got = fn(cart, lattice, pbc, r, bond_r=r * 0.6)
@@ -39,8 +36,6 @@ def test_vs_brute_force(rng, impl, n_atoms, box, r):
 @pytest.mark.parametrize("impl", ["numpy", "native"])
 def test_unwrapped_inputs(rng, impl):
     """Offsets must be reported relative to the unwrapped input coordinates."""
-    if impl == "native" and not native_available():
-        pytest.skip("native lib unavailable")
     cart, lattice, _, pbc = random_cell(rng, n_atoms=30, box=7.0)
     shift = rng.integers(-3, 4, (30, 3)) @ lattice
     fn = neighbor_list_numpy if impl == "numpy" else neighbor_list
@@ -54,8 +49,6 @@ def test_unwrapped_inputs(rng, impl):
 @pytest.mark.parametrize("impl", ["numpy", "native"])
 def test_self_image_small_cell(rng, impl):
     """Cell smaller than cutoff: atoms must neighbor their own images."""
-    if impl == "native" and not native_available():
-        pytest.skip("native lib unavailable")
     cart = np.array([[0.5, 0.5, 0.5]])
     lattice = np.eye(3) * 2.0
     fn = neighbor_list_numpy if impl == "numpy" else neighbor_list
@@ -68,8 +61,6 @@ def test_self_image_small_cell(rng, impl):
 
 @pytest.mark.parametrize("impl", ["numpy", "native"])
 def test_nonperiodic_axes(rng, impl):
-    if impl == "native" and not native_available():
-        pytest.skip("native lib unavailable")
     cart, lattice, _, _ = random_cell(rng, n_atoms=25, box=6.0)
     pbc = np.array([1, 1, 0])
     fn = neighbor_list_numpy if impl == "numpy" else neighbor_list
@@ -92,8 +83,6 @@ def test_symmetry(rng):
 def test_out_of_cell_on_free_axis(impl):
     """Atoms outside the cell along a non-periodic axis must keep their edges
     (free axes are never wrapped, so such positions are legal input)."""
-    if impl == "native" and not native_available():
-        pytest.skip("native lib unavailable")
     cart = np.array([[3.0, 3.0, 9.5], [3.0, 3.0, 7.5]])
     lattice = np.eye(3) * 6.0
     pbc = [1, 1, 0]

@@ -155,11 +155,8 @@ def test_bond_mapping(rng, P):
 @pytest.mark.parametrize("bond", [False, True])
 def test_native_matches_numpy_oracle(rng, P, bond):
     """The C++ partitioner must reproduce the numpy plan EXACTLY."""
-    from distmlip_tpu.neighbors.native import native_available
     from distmlip_tpu.neighbors import neighbor_list_numpy
 
-    if not native_available():
-        pytest.skip("native lib unavailable")
     box = max(16.0, P * 8.0)
     cart, lattice, _, pbc = random_cell(rng, n_atoms=int(0.02 * box**3), box=box)
     nl = neighbor_list_numpy(cart, lattice, pbc, R, bond_r=BOND_R)
@@ -187,11 +184,8 @@ def test_native_matches_numpy_oracle(rng, P, bond):
 
 
 def test_native_partitioner_rejects_multidest(rng):
-    from distmlip_tpu.neighbors.native import native_available
     from distmlip_tpu.neighbors import neighbor_list_numpy
 
-    if not native_available():
-        pytest.skip("native lib unavailable")
     cart, lattice, _, pbc = random_cell(rng, n_atoms=200, box=16.0)
     nl = neighbor_list_numpy(cart, lattice, pbc, R)
     # P=4 on a 16 A box: slab 4 A > R so check_partition_size passes, but

@@ -14,7 +14,7 @@ the single-partition packed-batch program — and runs every registered
 :class:`distmlip_tpu.analysis.ContractPass` over each jaxpr. No chip, no
 compile: the whole check is abstract tracing on CPU.
 
-Model programs are traced under ``jax.experimental.enable_x64`` so f64
+Model programs are traced under ``jax.enable_x64`` so f64
 leaks stay visible instead of being silently canonicalized to f32 (the
 ``dtype_discipline`` pass ignores weak-typed python scalars, so a clean
 fp32 program stays clean under x64).
@@ -176,7 +176,6 @@ def _trace_model_programs(name, programs_out, want=_want_all):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import enable_x64
 
     from distmlip_tpu.analysis import Program
     from distmlip_tpu.parallel import (BATCH_AXIS, device_mesh, graph_mesh,
@@ -208,7 +207,7 @@ def _trace_model_programs(name, programs_out, want=_want_all):
         placements.append(
             ("2x1", graph_mesh(2), _graph_for(model, use_bg, bond_r, 2),
              {"forbidden_axes": [BATCH_AXIS]}, strain_cotangent))
-    with enable_x64():
+    with jax.enable_x64(True):
         for tag, mesh, graph, coll_cfg, grad_cfg in placements:
             mesh_tag = {"mesh"} if mesh is not None else set()
             if want(f"energy[{name}][{tag}]"):
@@ -244,7 +243,6 @@ def _trace_packed_batch(programs_out):
     """Single-partition packed-batch program (B=4): communication-free by
     construction — batching adds structures, not collectives."""
     import jax
-    from jax.experimental import enable_x64
 
     from distmlip_tpu.analysis import Program
     from distmlip_tpu.parallel import make_batched_potential_fn
@@ -252,7 +250,7 @@ def _trace_packed_batch(programs_out):
     model, params, use_bg, bond_r = make_model("tensornet")
     graph = _packed_graph(model, use_bg, bond_r, batch=4)
     bfn = make_batched_potential_fn(model.energy_fn)
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(bfn)(params, graph, graph.positions)
     programs_out.append(Program(
         name="packed_batch[tensornet][B=4]", jaxpr=jx,
@@ -276,7 +274,6 @@ def _trace_ensemble(programs_out, want=_want_all):
         return
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from distmlip_tpu.analysis import Program
     from distmlip_tpu.parallel import (BATCH_AXIS, graph_mesh,
@@ -286,7 +283,7 @@ def _trace_ensemble(programs_out, want=_want_all):
 
     model, params, use_bg, bond_r = make_model("tensornet")
     stacked = jax.tree.map(lambda p: jnp.stack([p, p]), params)
-    with enable_x64():
+    with jax.enable_x64(True):
         if names[0] in wanted:
             graph = _graph_for(model, use_bg, bond_r, 2)
             pfn = make_potential_fn(model.energy_fn, graph_mesh(2))
@@ -343,6 +340,19 @@ def _trace_device_md(programs_out):
         config={"max_total_collectives": 0}))
 
 
+def _adam_f32():
+    """Adam whose hyperparameters are float32 at the host boundary. The
+    programs below are traced under x64, where a Python-float decay makes
+    optax's ``1 - decay**count`` a strong float64 — an artifact of the
+    tracing regime (production runs with x64 off), which the dtype pass
+    would report at optax's own source line."""
+    import numpy as np
+    import optax
+
+    return optax.adam(np.float32(1e-3), b1=np.float32(0.9),
+                      b2=np.float32(0.999))
+
+
 def _trace_train_step(programs_out, want=_want_all):
     """The accumulated bf16 train-step programs (distmlip_tpu.train):
     lax.scan over 2 micro-batches, fp32 master weights, dynamic loss
@@ -360,7 +370,6 @@ def _trace_train_step(programs_out, want=_want_all):
     import jax
     import numpy as np
     import optax
-    from jax.experimental import enable_x64
 
     from distmlip_tpu.analysis import Program
     from distmlip_tpu.calculators import Atoms
@@ -388,7 +397,7 @@ def _trace_train_step(programs_out, want=_want_all):
             Atoms(numbers=species + 1, positions=pos, cell=lattice),
             float(rng.normal()),
             rng.normal(0, 0.1, cart.shape).astype(np.float32)))
-    optimizer = optax.adam(1e-3)
+    optimizer = _adam_f32()
     n_leaves = len(jax.tree.leaves(params))
     zero1_budget = {BATCH_AXIS: {
         "psum": 2 * n_leaves * accum,   # audited grad-reduction allowance
@@ -412,7 +421,7 @@ def _trace_train_step(programs_out, want=_want_all):
         step = make_accum_train_step(model.energy_fn, optimizer, mesh, cfg)
         batch = loader.next_batch()
         loader.close()
-        with enable_x64():
+        with jax.enable_x64(True):
             jx = jax.make_jaxpr(step)(state, batch.graphs, batch.targets)
         tags = {"grad", "x64", "train"} | ({"mesh"} if mesh else set())
         programs_out.append(Program(
@@ -435,7 +444,6 @@ def _trace_train_step_tiers(programs_out, want=_want_all):
     import jax
     import numpy as np
     import optax
-    from jax.experimental import enable_x64
 
     from distmlip_tpu.analysis import Program
     from distmlip_tpu.calculators import Atoms
@@ -460,7 +468,7 @@ def _trace_train_step_tiers(programs_out, want=_want_all):
                 float(rng.normal()),
                 rng.normal(0, 0.1, cart.shape).astype(np.float32)))
     cfg = TrainConfig(accum_steps=accum, precision="bf16")
-    optimizer = optax.adam(1e-3)
+    optimizer = _adam_f32()
     loader = PackedBatchLoader(
         samples, model.cfg.cutoff, micro_batch_size=2, accum_steps=accum,
         species_fn=lambda z: (z - 1).astype("int32"), prefetch=0,
@@ -473,7 +481,7 @@ def _trace_train_step_tiers(programs_out, want=_want_all):
         if name not in wanted:
             continue
         batch = loader._build(0, first)
-        with enable_x64():
+        with jax.enable_x64(True):
             jx = jax.make_jaxpr(step)(state, batch.graphs, batch.targets)
         programs_out.append(Program(
             name=name, jaxpr=jx,

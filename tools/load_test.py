@@ -48,10 +48,11 @@ per-replica compile counts are UNCHANGED across the swap burst (the
 pytree swap reuses every executable), the router's cache model-id
 rolled forward, and escalations were actually evaluated.
 
-Smoke (verify flow): ``python tools/load_test.py --requests 12 --check``
-(~seconds on CPU with the default pair model) and
-``python tools/load_test.py --fleet 2 --chaos kill-replica --requests 48
---check``.
+Runs on the backend jax finds; pass ``JAX_PLATFORMS=cpu`` from outside
+for the CPU lane (the tests do). Smoke (verify flow):
+``JAX_PLATFORMS=cpu python tools/load_test.py --requests 12 --check``
+(~seconds with the default pair model) and ``JAX_PLATFORMS=cpu python
+tools/load_test.py --fleet 2 --chaos kill-replica --requests 48 --check``.
 """
 
 import argparse
@@ -60,13 +61,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# the serving engine is single-partition by design; CPU is fine unless the
-# caller explicitly wants the real accelerator
-if not os.environ.get("DISTMLIP_REAL_DEVICES"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 
@@ -843,6 +837,9 @@ def main(argv=None) -> int:
         print("usage error: --active requires fleet mode (--fleet N)",
               file=sys.stderr)
         return 2
+    from distmlip_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     try:
         if args.fleet > 0:
             return run_fleet(args)

@@ -18,8 +18,8 @@ the train steps and tier family) and places each on the roofline:
   program: ``--times times.json`` maps program-name substrings to
   seconds, ``--jsonl run.jsonl`` pulls warm-step device medians from a
   telemetry round by bucket/kind. Peak FLOP/s comes from
-  :func:`utils.flops.peak_flops_per_device` (``DISTMLIP_PEAK_FLOPS``
-  overrides; 0 on CPU -> mfu renders n/a). No chip is needed for the
+  :func:`utils.flops.device_peaks` (one table keyed by ``device_kind``;
+  no entry on CPU -> mfu renders n/a). No chip is needed for the
   flops/bytes/intensity columns — CPU CI exercises the full report path
   (the cost-model fallback of the acceptance gate).
 
@@ -142,9 +142,9 @@ def main(argv=None) -> int:
     from distmlip_tpu.obs.roofline import (RooflineRow, bytes_touched,
                                            format_roofline_table,
                                            jaxpr_flop_estimate)
-    from distmlip_tpu.utils.flops import peak_flops_per_device
+    from distmlip_tpu.utils.flops import device_peaks
 
-    peak = peak_flops_per_device()
+    peak, peak_bw = device_peaks() or (0.0, 0.0)
     programs = trace_programs(models, args.programs)
     rows, breakdowns = [], []
     for prog in programs:
@@ -156,7 +156,8 @@ def main(argv=None) -> int:
             program=prog.name,
             flops=jaxpr_flop_estimate(prog.jaxpr),
             bytes=float(bytes_touched(analyze_memory(prog.jaxpr))),
-            time_s=t, peak_flops=peak, n_devices=n_dev,
+            time_s=t, peak_flops=peak, peak_bytes_per_s=peak_bw,
+            n_devices=n_dev,
             source="measured" if t > 0 else "cost_model"))
         if args.attribution:
             breakdowns.append(attribute_cost_model(
@@ -176,7 +177,8 @@ def main(argv=None) -> int:
     else:
         print(format_roofline_table(
             rows, title=f"roofline: {len(rows)} program(s), "
-            f"peak/device={peak:.3g} FLOP/s"))
+            f"peak/device="
+            + (f"{peak:.3g} FLOP/s" if peak else "n/a (no TPU)")))
         for b in breakdowns:
             print()
             print(b.render())

@@ -62,6 +62,9 @@ class EqnSite:
     path: tuple          # enclosing control-flow primitive names, outer first
     scope: str           # jax.named_scope stack ("" when metadata is absent)
     jaxpr: Any           # the (sub)jaxpr owning this eqn
+    # scope stacks of the enclosing eqns (a sub-jaxpr's eqns carry only
+    # what was entered inside it), outer first, then ``scope``
+    stack: str = ""
 
     @property
     def primitive(self) -> str:
@@ -119,14 +122,17 @@ def iter_sites(closed_jaxpr) -> Iterator[EqnSite]:
     yield from _walk(jaxpr, ())
 
 
-def _walk(jaxpr, path) -> Iterator[EqnSite]:
+def _walk(jaxpr, path, outer: str = "") -> Iterator[EqnSite]:
     for eqn in jaxpr.eqns:
-        yield EqnSite(eqn=eqn, path=path, scope=scope_of(eqn), jaxpr=jaxpr)
+        scope = scope_of(eqn)
+        stack = f"{outer}/{scope}" if outer and scope else outer or scope
+        yield EqnSite(eqn=eqn, path=path, scope=scope, jaxpr=jaxpr,
+                      stack=stack)
         subs = sub_jaxprs(eqn.params)
         if subs:
             sub_path = path + (eqn.primitive.name,)
             for sub in subs:
-                yield from _walk(sub, sub_path)
+                yield from _walk(sub, sub_path, stack)
 
 
 def iter_eqns(jaxpr):

@@ -32,7 +32,7 @@ from ..obs import profiling as _profiling
 from ..obs import runtime as obsrt
 from ..parallel import make_batched_potential_fn
 from ..partition import BucketPolicy, pack_structures
-from ..telemetry import StepRecord, annotate
+from ..telemetry import StepRecord, annotate, note_dispatch
 from ..telemetry.trace import tracing_enabled
 from .atoms import (AMU_A2_FS2_TO_EV, EV_A3_TO_GPA, KB, map_species,
                     max_displacement)
@@ -387,7 +387,7 @@ class BatchedPotential:
         structures = list(structures)
         if not structures:
             return []
-        with self._lock:
+        with self._lock, annotate("distmlip/calculate"):
             return self._calculate_locked(structures)
 
     def _prepare_batch(self, structures):
@@ -432,8 +432,9 @@ class BatchedPotential:
             (t0, t1, t2)
 
     def _calculate_locked(self, structures) -> list:
-        graph, host, positions, reused, refreshed, rebuild_s, \
-            (t0, t1, t2) = self._prepare_batch(structures)
+        with annotate("distmlip/prepare"):
+            graph, host, positions, reused, refreshed, rebuild_s, \
+                (t0, t1, t2) = self._prepare_batch(structures)
         # when an xprof capture is live, fold the ambient obs trace id
         # into the TraceAnnotation name so the device timeline lines up
         # with the host span tree (name built only when tracing is on —
@@ -447,7 +448,8 @@ class BatchedPotential:
         with annotate(ann_name):
             from ..kernels.dispatch import counting
 
-            with counting() as kc:
+            with annotate("distmlip/dispatch"), counting() as kc:
+                note_dispatch(self._potential, self.params, graph, positions)
                 out = self._potential(self.params, graph, positions)
             if kc.total:  # a fresh trace happened (new shape bucket)
                 self._kernel_mode = kc.mode
@@ -458,21 +460,24 @@ class BatchedPotential:
                 # (host-side abstract trace; once per bucket)
                 if self.memory_model:
                     self._calibrate_memory(graph, positions, structures)
-            # flat shard-major slots -> input structure order (identity for
-            # the single-shard pack)
-            slots = host.structure_slots
-            energies = np.asarray(out["energies"],
-                                  dtype=np.float64)[slots]
-        forces = host.gather_per_structure(np.asarray(out["forces"]))
-        strain_grad = np.asarray(out["strain_grad"])[slots]
-        if "aux" in out:
-            m = np.asarray(out["aux"]["magmoms"])
-            # the meshless runtime returns shard-local (N_cap,) aux rows;
-            # the mesh runtime returns the packed (P, N_cap, ...) layout
-            magmoms = host.gather_per_structure(
-                m if self.mesh is not None else m[None])
-        else:
-            magmoms = None
+            with annotate("distmlip/wait"):
+                out["energies"].block_until_ready()
+            with annotate("distmlip/results_to_host"):
+                # flat shard-major slots -> input structure order (identity
+                # for the single-shard pack)
+                slots = host.structure_slots
+                energies = np.asarray(out["energies"],
+                                      dtype=np.float64)[slots]
+                forces = host.gather_per_structure(np.asarray(out["forces"]))
+                strain_grad = np.asarray(out["strain_grad"])[slots]
+                if "aux" in out:
+                    m = np.asarray(out["aux"]["magmoms"])
+                    # the meshless runtime returns shard-local (N_cap,) aux
+                    # rows; the mesh runtime the packed (P, N_cap, ...) layout
+                    magmoms = host.gather_per_structure(
+                        m if self.mesh is not None else m[None])
+                else:
+                    magmoms = None
         results = []
         for b in range(len(structures)):
             stress = strain_grad[b] / max(host.volumes[b], 1e-30)

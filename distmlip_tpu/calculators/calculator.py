@@ -41,7 +41,7 @@ import numpy as np
 from ..neighbors import neighbor_list
 from ..parallel import graph_mesh, make_potential_fn, make_site_fn
 from ..partition import CapacityPolicy, build_partitioned_graph, build_plan
-from ..telemetry import StepRecord, annotate
+from ..telemetry import StepRecord, annotate, note_dispatch
 from .atoms import EV_A3_TO_GPA, Atoms, map_species, max_displacement
 
 
@@ -751,43 +751,50 @@ class DistPotential:
         Thread-safe: callers sharing one potential (a ServeEngine lane plus
         a direct caller) serialize here, and ``last_stats``/``last_timings``
         always describe the caller's own step while the lock is held."""
-        with self._lock:
+        with self._lock, annotate("distmlip/calculate"):
             return self._calculate_locked(atoms)
 
     def _calculate_locked(self, atoms: Atoms) -> dict:
         t_start = time.perf_counter()
-        graph, host, positions = self._prepare(atoms)
+        with annotate("distmlip/prepare"):
+            graph, host, positions = self._prepare(atoms)
         t2 = time.perf_counter()
         with annotate("distmlip/potential"):
             from ..kernels.dispatch import counting
 
-            with counting() as kc:
+            with annotate("distmlip/dispatch"), counting() as kc:
+                note_dispatch(self._potential, self.params, graph, positions)
                 out = self._potential(self.params, graph, positions)
             if kc.total:  # a fresh jit trace happened (new shape bucket)
                 self._kernel_mode = kc.mode
                 self._kernel_coverage = kc.coverage
                 self._kernel_ops = kc.ops
-            energy = float(out["energy"])
-        forces = host.gather_owned(np.asarray(out["forces"]), len(atoms))
-        stress = np.asarray(out["stress"])
-        result = {
-            "energy": energy,
-            "free_energy": energy,
-            "forces": forces,
-            "stress": stress,
-            "stress_GPa": stress * EV_A3_TO_GPA,
-        }
-        if "aux" in out:
-            # fused site readout: magmoms rode the energy forward as an aux
-            # output — no second forward pass
-            m = np.asarray(out["aux"]["magmoms"])
-            result["magmoms"] = host.gather_owned(m, len(atoms))
-        elif self._site_fn is not None:
-            # legacy separate-forward readout (CHGNet magmoms; reference
-            # ase.py magmoms surface) over the SAME cached graph/positions
-            with annotate("distmlip/site_readout"):
-                m = np.asarray(self._site_fn(self.params, graph, positions))
-            result["magmoms"] = host.gather_owned(m, len(atoms))
+            with annotate("distmlip/wait"):
+                out["energy"].block_until_ready()
+            with annotate("distmlip/results_to_host"):
+                energy = float(out["energy"])
+                forces = host.gather_owned(np.asarray(out["forces"]),
+                                           len(atoms))
+                stress = np.asarray(out["stress"])
+                result = {
+                    "energy": energy,
+                    "free_energy": energy,
+                    "forces": forces,
+                    "stress": stress,
+                    "stress_GPa": stress * EV_A3_TO_GPA,
+                }
+                if "aux" in out:
+                    # fused site readout: magmoms rode the energy forward
+                    # as an aux output — no second forward pass
+                    m = np.asarray(out["aux"]["magmoms"])
+                    result["magmoms"] = host.gather_owned(m, len(atoms))
+            if "aux" not in out and self._site_fn is not None:
+                # legacy separate-forward readout (CHGNet magmoms; reference
+                # ase.py magmoms surface) over the SAME cached graph/positions
+                with annotate("distmlip/site_readout"):
+                    m = np.asarray(self._site_fn(self.params, graph,
+                                                 positions))
+                    result["magmoms"] = host.gather_owned(m, len(atoms))
         self.last_timings["device_s"] = time.perf_counter() - t2
         self.last_stats = dict(getattr(host, "stats", None) or {})
         self.last_stats.update(

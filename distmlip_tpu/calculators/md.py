@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..telemetry import annotate
 from .atoms import AMU_A2_FS2_TO_EV, EV_A3_TO_GPA, KB, Atoms
 
 ENSEMBLES = (
@@ -169,6 +170,12 @@ class MolecularDynamics:
 
     # ---- ensembles ----
     def step(self):
+        # the potential's spans nest inside; this span's own time is the
+        # host half-steps, thermostat and barostat
+        with annotate("distmlip/integrate"):
+            self._step()
+
+    def _step(self):
         e = self.ensemble
         if e == "nve":
             self._velocity_verlet()

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.segment import masked_segment_sum
+from ..telemetry import scope
 from .segment import pallas_edge_aggregate, pallas_segment_sum
 from .so3 import packed_m_layout, so2_conv_pallas, so2_conv_reference
 
@@ -232,9 +233,9 @@ def fused_segment_sum(data, segment_ids, num_segments: int, mask=None,
            and data.shape[0] > 0 and num_segments > 0)
     _count("segment_sum", use)
     if not use:
-        return masked_segment_sum(data, segment_ids, num_segments, mask,
-                                  indices_are_sorted=indices_are_sorted)
-    interpret = mode == "interpret"
+        with scope("edge_aggregate"):
+            return masked_segment_sum(data, segment_ids, num_segments, mask,
+                                      indices_are_sorted=indices_are_sorted)
     # every traced operand is an EXPLICIT custom_vjp arg (ids/mask may be
     # tracers of an enclosing scan/checkpoint body — closing over them
     # would leak out of that trace when the backward replays); integer
@@ -242,8 +243,9 @@ def fused_segment_sum(data, segment_ids, num_segments: int, mask=None,
     # forward of this call can be fully dead (the bwd needs only ids/mask
     # residuals); XLA DCEs the pure replay, no bytes ship:
     # contract: allow(dead_compute)
-    return _segment_sum_vjp(num_segments, interpret,
-                            jnp.result_type(data))(data, segment_ids, mask)
+    with scope("edge_aggregate"):
+        return _segment_sum_vjp(num_segments, mode == "interpret",
+                                jnp.result_type(data))(data, segment_ids, mask)
 
 
 def _segment_sum_vjp(num_segments: int, interpret: bool, dtype):
@@ -261,9 +263,10 @@ def _segment_sum_vjp(num_segments: int, interpret: bool, dtype):
     def bwd(res, g):
         ids, m = res
         # transpose of a masked segment sum: the sorted per-edge gather
-        gd = jnp.take(g, ids, axis=0)
-        m_ct = None if m is None else _int_zero(m)
-        return (_mask_mul(gd, m).astype(dtype), _int_zero(ids), m_ct)
+        with scope("edge_aggregate"):
+            gd = jnp.take(g, ids, axis=0)
+            m_ct = None if m is None else _int_zero(m)
+            return (_mask_mul(gd, m).astype(dtype), _int_zero(ids), m_ct)
 
     f.defvjp(fwd, bwd)
     return f
@@ -368,9 +371,15 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
            and num_segments > 0 and not float_mask)
     _count("edge_aggregate", use)
     if not use:
-        msg = edge_fn(*[_rows_of(i) for i in inputs])
-        return masked_segment_sum(msg, segment_ids, num_segments, mask,
-                                  indices_are_sorted=indices_are_sorted)
+        # stages (telemetry/stages.py): the src-row gathers and the
+        # per-edge compute are the message (edge_fn's own scopes, e.g. a
+        # radial MLP inside it, are innermost and win), the sum is the
+        # aggregate; the fused kernel below is one operation: aggregate
+        with scope("edge_message"):
+            msg = edge_fn(*[_rows_of(i) for i in inputs])
+        with scope("edge_aggregate"):
+            return masked_segment_sum(msg, segment_ids, num_segments, mask,
+                                      indices_are_sorted=indices_are_sorted)
 
     interpret = mode == "interpret"
     budget = DEFAULT_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)
@@ -488,9 +497,10 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
 
             return rowwise
 
-        in_cts, const_cts = _edge_aggregate_bwd(
-            make_rowwise, prep, arrs, dconsts, idxs_,
-            ids_, m_, g, chunk, diff_params)
+        with scope("edge_aggregate"):
+            in_cts, const_cts = _edge_aggregate_bwd(
+                make_rowwise, prep, arrs, dconsts, idxs_,
+                ids_, m_, g, chunk, diff_params)
         out = in_cts + tuple(_int_zero(i) for i in idxs_)
         out = out + (_int_zero(ids_),)
         if has_mask:
@@ -505,7 +515,8 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
     # enclosing transpose needs only some, the rest (including their
     # scatter-adds) are dead and XLA DCEs them:
     # contract: allow(dead_compute)
-    return f(*diff)
+    with scope("edge_aggregate"):
+        return f(*diff)
 
 
 def _edge_aggregate_bwd(make_rowwise, prep, arrs, dconsts, idxs,

@@ -50,6 +50,7 @@ from ..ops.so3 import (
     spherical_harmonics,
     symmetric_coupling_basis,
 )
+from ..telemetry import scope
 
 
 @dataclass(frozen=True)
@@ -328,36 +329,45 @@ class MACE:
         )
         acc_dtype = positions.dtype
 
-        vec = lg.edge_vectors(positions)
-        d = jnp.linalg.norm(jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
-        rhat = vec / jnp.maximum(d, 1e-9)[:, None]
-        env = (
-            radial.polynomial_cutoff(d, cfg.cutoff, p=cfg.cutoff_p) * lg.edge_mask
-        ).astype(dtype)
-        # envelope multiplies the bessel features BEFORE the radial MLP
-        # (upstream's RadialEmbeddingBlock); the bias-free MLP maps 0 -> 0,
-        # so messages still vanish smoothly at the cutoff
-        bessel = (
-            radial.spherical_bessel_basis(d, cfg.cutoff, cfg.num_bessel)
-            * env[:, None]
-        ).astype(dtype)
-        Y = {l: spherical_harmonics(l, rhat) for l in range(cfg.l_max + 1)}
+        # every stage below sits in its telemetry scope (telemetry/stages.py)
+        with scope("edge_geometry"):
+            vec = lg.edge_vectors(positions)
+            d = jnp.linalg.norm(
+                jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
+            rhat = vec / jnp.maximum(d, 1e-9)[:, None]
+            env = (
+                radial.polynomial_cutoff(d, cfg.cutoff, p=cfg.cutoff_p)
+                * lg.edge_mask
+            ).astype(dtype)
+            # envelope multiplies the bessel features BEFORE the radial MLP
+            # (upstream's RadialEmbeddingBlock); the bias-free MLP maps
+            # 0 -> 0, so messages still vanish smoothly at the cutoff
+            bessel = (
+                radial.spherical_bessel_basis(d, cfg.cutoff, cfg.num_bessel)
+                * env[:, None]
+            ).astype(dtype)
+            Y = {l: spherical_harmonics(l, rhat)
+                 for l in range(cfg.l_max + 1)}
 
         z = lg.species
-        h = {0: params["species_emb"]["w"][z][:, None, :].astype(dtype)}
-        h = self._unpack(lg.halo_exchange(self._pack(h)), [0], C)
+        with scope("node_linear"):
+            h = {0: params["species_emb"]["w"][z][:, None, :].astype(dtype)}
+        with scope("halo"):
+            h = self._unpack(lg.halo_exchange(self._pack(h)), [0], C)
 
         head = cfg.head
         # site/readout energies accumulate in the positions dtype: bf16 has
         # too few mantissa bits for per-atom energy sums
-        e_site = params["species_ref"]["w"][head][z].astype(acc_dtype)
+        with scope("readout"):
+            e_site = params["species_ref"]["w"][head][z].astype(acc_dtype)
         # ZBL joins the *interaction* energies: upstream ScaleShiftMACE puts
         # pair_node_energy into node_es_list and scale-shifts the sum
         # (reference mace/models.py:131,174-175), so it must sit inside
         # scale*(...)+shift, not alongside the unscaled E0 reference
         acc = jnp.zeros(positions.shape[0], dtype=acc_dtype)
         if cfg.zbl:
-            acc = acc + self._zbl_site(params, lg, d, acc_dtype)
+            with scope("pair_repulsion"):
+                acc = acc + self._zbl_site(params, lg, d, acc_dtype)
 
         for t, inter in enumerate(params["interactions"]):
             body = partial(self._interaction, lg=lg, Y=Y, bessel=bessel,
@@ -367,20 +377,25 @@ class MACE:
                 # scans carry the policy themselves and double-wrapping
                 # would discard their saved dots
                 body = jax.checkpoint(body)
-            h = body(inter, h)
-            h = self._unpack(lg.halo_exchange(self._pack(h)), self.h_ls_out[t], C)
+            with scope(f"interaction{t}"):
+                h = body(inter, h)
+            with scope("halo"):
+                h = self._unpack(lg.halo_exchange(self._pack(h)),
+                                 self.h_ls_out[t], C)
 
             # invariant readout (head column selected)
-            scalars = h[0][:, 0, :]
-            if t == cfg.num_interactions - 1:
-                r_out = mlp(inter["readout"], scalars)[:, head]
-            else:
-                r_out = linear(inter["readout"][0], scalars)[:, head]
-            acc = acc + r_out.astype(acc_dtype)
+            with scope("readout"):
+                scalars = h[0][:, 0, :]
+                if t == cfg.num_interactions - 1:
+                    r_out = mlp(inter["readout"], scalars)[:, head]
+                else:
+                    r_out = linear(inter["readout"][0], scalars)[:, head]
+                acc = acc + r_out.astype(acc_dtype)
 
-        scale = params["scale"][head].astype(acc_dtype)
-        shift = params["shift"][head].astype(acc_dtype)
-        return e_site + scale * acc + shift
+        with scope("readout"):
+            scale = params["scale"][head].astype(acc_dtype)
+            shift = params["shift"][head].astype(acc_dtype)
+            return e_site + scale * acc + shift
 
     def _zbl_site(self, params, lg, d, dtype):
         """Per-atom ZBL pair repulsion (half per directed edge), added under
@@ -433,16 +448,15 @@ class MACE:
         nQ = proj["W"].shape[1]
 
         # sender features, channel-mixed per l, packed (N, S_h, C)
-        hu = jnp.concatenate(
-            [
-                jnp.einsum("nmc,cd->nmd", h[l], inter["lin_up"][str(l)]["w"])
-                for l in h_ls
-            ],
-            axis=1,
-        )
-        Y_full = jnp.concatenate(
-            [Y[l] for l in range(cfg.l_max + 1)], axis=-1
-        ).astype(dtype)                                   # (E, S_Y)
+        with scope("node_linear"):
+            hu = jnp.concatenate(
+                [
+                    jnp.einsum("nmc,cd->nmd", h[l],
+                               inter["lin_up"][str(l)]["w"])
+                    for l in h_ls
+                ],
+                axis=1,
+            )
 
         # density projection A, accumulated over edge chunks (memory-bounded):
         # per chunk, outer(h_src, Y) -> one GEMM over every CG path -> radial
@@ -456,110 +470,126 @@ class MACE:
             e_cap, cfg.edge_chunk,
             lg.e_split if lg.has_frontier_split else None)
         take = lambda x: chunked(jnp.asarray(x)[row_idx], K, chunk)
-        src_ch = take(lg.edge_src)
-        dst_ch = take(lg.edge_dst)
-        mask_ch = chunked(
-            jnp.asarray(lg.edge_mask)[row_idx] & jnp.asarray(row_valid),
-            K, chunk)
-        bes_ch = take(bessel)
-        Y_ch = take(Y_full)
+        with scope("edge_gather"):
+            Y_full = jnp.concatenate(
+                [Y[l] for l in range(cfg.l_max + 1)], axis=-1
+            ).astype(dtype)                               # (E, S_Y)
+            src_ch = take(lg.edge_src)
+            dst_ch = take(lg.edge_dst)
+            mask_ch = chunked(
+                jnp.asarray(lg.edge_mask)[row_idx] & jnp.asarray(row_valid),
+                K, chunk)
+            bes_ch = take(bessel)
+            Y_ch = take(Y_full)
 
         Wp3 = Wp.reshape(proj["S_h"], proj["S_Y"], nQ)
 
         def chunk_body(A_acc, xs):
             srcc, dstc, maskc, Yc, besc = xs
-            Rc = mlp(inter["radial"], besc).reshape(chunk, len(paths), C)
+            with scope("radial_mlp"):
+                Rc = mlp(inter["radial"], besc).reshape(chunk, len(paths), C)
             # factor the CG contraction: T[e,m,q] = sum_n Y[e,n] W[(m,n),q]
             # is channel-free and tiny (E_c, S_h, Q); contracting it with
             # h_src over m (<= S_h) then costs S_h fused multiply-adds per
             # (q, c) — no (E_c, S_h*S_Y, C) outer product ever materializes
             # (the outer was ~0.5 GB/chunk and 16x the FLOPs)
-            T = jnp.einsum("en,mnq->emq", Yc, Wp3)
-            M = jnp.einsum("emq,emc->eqc", T, hu[srcc])   # (E_c, Q, C)
-            M = M * Rc[:, q_path, :]                      # per-path radial
-            return (
-                A_acc
-                + fused_segment_sum(
-                    # sorted within every chunk by chunk_layout
-                    # construction; dispatches to the dst-tiled Pallas
-                    # scatter kernel on TPU (kernels/dispatch)
-                    M, dstc, n_nodes, maskc, indices_are_sorted=True,
-                    kernels=lg.kernels,
-                ),
-                None,
-            )
+            with scope("edge_message"):
+                T = jnp.einsum("en,mnq->emq", Yc, Wp3)
+                M = jnp.einsum("emq,emc->eqc", T, hu[srcc])  # (E_c, Q, C)
+                M = M * Rc[:, q_path, :]                     # per-path radial
+            with scope("edge_aggregate"):
+                return (
+                    A_acc
+                    + fused_segment_sum(
+                        # sorted within every chunk by chunk_layout
+                        # construction; dispatches to the dst-tiled Pallas
+                        # scatter kernel on TPU (kernels/dispatch)
+                        M, dstc, n_nodes, maskc, indices_are_sorted=True,
+                        kernels=lg.kernels,
+                    ),
+                    None,
+                )
 
-        A0 = jnp.zeros((n_nodes, nQ, C), dtype=dtype)
-        A_all = scan_accumulate(
-            chunk_body, A0, (src_ch, dst_ch, mask_ch, Y_ch, bes_ch),
-            remat=cfg.remat,
-        )
+        # the scan's own slicing of the chunked rows (and, transposed, the
+        # stacking of their cotangents) continues the gather's data path
+        with scope("edge_gather"):
+            A0 = jnp.zeros((n_nodes, nQ, C), dtype=dtype)
+            A_all = scan_accumulate(
+                chunk_body, A0, (src_ch, dst_ch, mask_ch, Y_ch, bes_ch),
+                remat=cfg.remat,
+            )
         # per-path output mixing on nodes (upstream's post-conv_tp linear):
         # A[l] = sum_paths A_all[:, :, cols(path)] @ W_path — (P_l*C) GEMMs
-        inv_avg = jnp.asarray(1.0 / cfg.avg_num_neighbors, dtype=dtype)
-        A = {
-            l: jnp.einsum(
-                "npmc,pcd->nmd",
-                A_all[:, proj["lo_cols"][l]] * inv_avg,
-                inter["lin_A"][str(l)].astype(dtype),
-            )
-            for l in self.a_ls
-        }
+        with scope("node_linear"):
+            inv_avg = jnp.asarray(1.0 / cfg.avg_num_neighbors, dtype=dtype)
+            A = {
+                l: jnp.einsum(
+                    "npmc,pcd->nmd",
+                    A_all[:, proj["lo_cols"][l]] * inv_avg,
+                    inter["lin_A"][str(l)].astype(dtype),
+                )
+                for l in self.a_ls
+            }
 
         # ---- symmetric contraction (ACE product basis, U-matrix form) ----
         # node-chunked: the Horner intermediates are (n, d, S, S, C)
-        A_flat = jnp.concatenate([A[l] for l in self.a_ls], axis=1)  # (N,S_A,C)
-        h_in_ls = [l for l in h_ls if l in h]
-        h_flat = jnp.concatenate([h[l] for l in h_in_ls], axis=1)
-        nchunk = cfg.node_chunk if cfg.node_chunk > 0 else n_nodes
-        nchunk = min(nchunk, n_nodes)
-        Kn = -(-n_nodes // nchunk)
-        padn = Kn * nchunk - n_nodes
+        with scope("node_tensor"):
+            # (N, S_A, C)
+            A_flat = jnp.concatenate([A[l] for l in self.a_ls], axis=1)
+            h_in_ls = [l for l in h_ls if l in h]
+            h_flat = jnp.concatenate([h[l] for l in h_in_ls], axis=1)
+            nchunk = cfg.node_chunk if cfg.node_chunk > 0 else n_nodes
+            nchunk = min(nchunk, n_nodes)
+            Kn = -(-n_nodes // nchunk)
+            padn = Kn * nchunk - n_nodes
 
-        def padn_c(x):
-            if padn == 0:
-                return x
-            widths = [(0, padn)] + [(0, 0)] * (x.ndim - 1)
-            return jnp.pad(x, widths)
+            def padn_c(x):
+                if padn == 0:
+                    return x
+                widths = [(0, padn)] + [(0, 0)] * (x.ndim - 1)
+                return jnp.pad(x, widths)
 
-        A_ch = padn_c(A_flat).reshape(Kn, nchunk, -1, C)
-        z_ch = padn_c(z).reshape(Kn, nchunk)
-        h_ch = padn_c(h_flat).reshape(Kn, nchunk, -1, C)
+            A_ch = padn_c(A_flat).reshape(Kn, nchunk, -1, C)
+            z_ch = padn_c(z).reshape(Kn, nchunk)
+            h_ch = padn_c(h_flat).reshape(Kn, nchunk, -1, C)
 
-        def node_body(_, xs):
-            Ac, zc, hc = xs
-            outs = []
+            def node_body(_, xs):
+                Ac, zc, hc = xs
+                outs = []
+                for l in out_ls:
+                    B = self._sym_contract(
+                        inter["product"][str(l)], self.prod_U[l], Ac, zc, dtype
+                    )
+                    with scope("node_linear"):
+                        m = jnp.einsum("nmc,cd->nmd", B,
+                                       inter["lin_msg"][str(l)]["w"])
+                        if l in h_in_ls and str(l) in inter["lin_res"]:
+                            off = sum(2 * ll + 1 for ll in h_in_ls if ll < l)
+                            hl = hc[:, off : off + 2 * l + 1, :]
+                            Wr = inter["lin_res"][str(l)][zc].astype(
+                                dtype)                          # (n, C, C)
+                            m = m + jnp.einsum("nmc,ncd->nmd", hl, Wr)
+                    outs.append(m)
+                return None, jnp.concatenate(outs, axis=1)
+
+            from ..ops.chunk import remat_wrap
+
+            body = remat_wrap(node_body, cfg.remat)
+            if Kn == 1:
+                # single-chunk path keeps the remat mode too (same contract
+                # as scan_accumulate: a system just under one node chunk must
+                # have the same backward memory bound as one just over)
+                _, out_flat = body(None, (A_ch[0], z_ch[0], h_ch[0]))
+            else:
+                _, out_flat = jax.lax.scan(body, None, (A_ch, z_ch, h_ch))
+                out_flat = out_flat.reshape(Kn * nchunk, -1, C)[:n_nodes]
+
+            h_new = {}
+            o = 0
             for l in out_ls:
-                B = self._sym_contract(
-                    inter["product"][str(l)], self.prod_U[l], Ac, zc, dtype
-                )
-                m = jnp.einsum("nmc,cd->nmd", B, inter["lin_msg"][str(l)]["w"])
-                if l in h_in_ls and str(l) in inter["lin_res"]:
-                    off = sum(2 * ll + 1 for ll in h_in_ls if ll < l)
-                    hl = hc[:, off : off + 2 * l + 1, :]
-                    Wr = inter["lin_res"][str(l)][zc].astype(dtype)  # (n,C,C)
-                    m = m + jnp.einsum("nmc,ncd->nmd", hl, Wr)
-                outs.append(m)
-            return None, jnp.concatenate(outs, axis=1)
-
-        from ..ops.chunk import remat_wrap
-
-        body = remat_wrap(node_body, cfg.remat)
-        if Kn == 1:
-            # single-chunk path keeps the remat mode too (same contract as
-            # scan_accumulate: a system just under one node chunk must have
-            # the same backward memory bound as one just over)
-            _, out_flat = body(None, (A_ch[0], z_ch[0], h_ch[0]))
-        else:
-            _, out_flat = jax.lax.scan(body, None, (A_ch, z_ch, h_ch))
-            out_flat = out_flat.reshape(Kn * nchunk, -1, C)[:n_nodes]
-
-        h_new = {}
-        o = 0
-        for l in out_ls:
-            d = 2 * l + 1
-            h_new[l] = out_flat[:, o : o + d, :]
-            o += d
+                d = 2 * l + 1
+                h_new[l] = out_flat[:, o : o + d, :]
+                o += d
         return h_new
 
     def _sym_contract(self, wts, Us, Ac, zc, dtype):

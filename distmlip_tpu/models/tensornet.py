@@ -34,6 +34,7 @@ from ..ops import radial
 from ..ops.nn import (cast_params_subtrees, embedding, gather_rows,
                       layernorm, layernorm_init, linear, linear_init, mlp,
                       mlp_init)
+from ..telemetry import scope
 
 
 @dataclass(frozen=True)
@@ -54,20 +55,22 @@ class TensorNetConfig:
 def decompose(X):
     """Split (..., 3, 3, C) into (trace-part I, antisymmetric A,
     sym-traceless S); the matrix lives in axes (-3, -2)."""
-    trace = (X[..., 0, 0, :] + X[..., 1, 1, :] + X[..., 2, 2, :])[
-        ..., None, None, :
-    ]
-    eye = jnp.eye(3, dtype=X.dtype)[:, :, None]
-    I = trace / 3.0 * eye
-    Xt = jnp.swapaxes(X, -3, -2)
-    A = 0.5 * (X - Xt)
-    S = 0.5 * (X + Xt) - I
-    return I, A, S
+    with scope("node_tensor"):
+        trace = (X[..., 0, 0, :] + X[..., 1, 1, :] + X[..., 2, 2, :])[
+            ..., None, None, :
+        ]
+        eye = jnp.eye(3, dtype=X.dtype)[:, :, None]
+        I = trace / 3.0 * eye
+        Xt = jnp.swapaxes(X, -3, -2)
+        A = 0.5 * (X - Xt)
+        S = 0.5 * (X + Xt) - I
+        return I, A, S
 
 
 def tensor_norm(X):
     """Per-channel squared Frobenius norm: (..., 3, 3, C) -> (..., C)."""
-    return jnp.sum(X * X, axis=(-3, -2))
+    with scope("node_tensor"):
+        return jnp.sum(X * X, axis=(-3, -2))
 
 
 def _vector_to_skew(v):
@@ -86,7 +89,8 @@ def _mix(lin, comp):
     """torchmd-net channel mix: Linear over the channel axis of a
     (..., 3, 3, C) component (torch permutes around nn.Linear; here the
     channel axis is already last, so it is one lane-resident GEMM)."""
-    return jnp.einsum("...ijc,cd->...ijd", comp, lin["w"])
+    with scope("node_linear"):
+        return jnp.einsum("...ijc,cd->...ijd", comp, lin["w"])
 
 
 class TensorNet:
@@ -142,26 +146,35 @@ class TensorNet:
                 keep_fp32=("species_ref", "out_norm", "linear", "final",
                            "data_std"))
 
-        vec = lg.edge_vectors(positions)
-        d = jnp.linalg.norm(jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
-        rhat = (vec / jnp.maximum(d, 1e-9)[:, None]).astype(dtype)
-        env = (radial.cosine_cutoff(d, cfg.cutoff) * lg.edge_mask).astype(dtype)
-        rbf = radial.spherical_bessel_basis(d, cfg.cutoff, cfg.num_rbf).astype(dtype)
+        # every stage below sits in its telemetry scope (telemetry/stages.py)
+        with scope("edge_geometry"):
+            vec = lg.edge_vectors(positions)
+            d = jnp.linalg.norm(
+                jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
+            rhat = (vec / jnp.maximum(d, 1e-9)[:, None]).astype(dtype)
+            env = (radial.cosine_cutoff(d, cfg.cutoff)
+                   * lg.edge_mask).astype(dtype)
+            rbf = radial.spherical_bessel_basis(
+                d, cfg.cutoff, cfg.num_rbf).astype(dtype)
 
-        # --- tensor embedding (torchmd-net TensorEmbedding) ---
-        eye = jnp.eye(3, dtype=dtype)[:, :, None]                # (3, 3, 1)
-        A_e = _vector_to_skew(rhat)[..., None]                   # (E, 3, 3, 1)
-        S_e = (rhat[:, :, None] * rhat[:, None, :])[..., None] - eye / 3.0
+            # --- tensor embedding (torchmd-net TensorEmbedding) ---
+            eye = jnp.eye(3, dtype=dtype)[:, :, None]            # (3, 3, 1)
+            A_e = _vector_to_skew(rhat)[..., None]               # (E, 3, 3, 1)
+            S_e = (rhat[:, :, None] * rhat[:, None, :])[..., None] - eye / 3.0
 
-        z = embedding(params["species_emb"], lg.species)         # (N, C)
+        with scope("node_linear"):
+            z = embedding(params["species_emb"], lg.species)     # (N, C)
         # gather_rows: on the bf16 path the backward accumulates per-node
         # feature grads from every referencing edge in fp32, not bf16
-        Zij = linear(params["emb2"],
-                     jnp.concatenate([gather_rows(z, lg.edge_src),
-                                      gather_rows(z, lg.edge_dst)], axis=-1))
-        W1 = linear(params["dist_proj"][0], rbf) * env[:, None]  # (E, C)
-        W2 = linear(params["dist_proj"][1], rbf) * env[:, None]
-        W3 = linear(params["dist_proj"][2], rbf) * env[:, None]
+        with scope("edge_message"):
+            Zij = linear(params["emb2"],
+                         jnp.concatenate([gather_rows(z, lg.edge_src),
+                                          gather_rows(z, lg.edge_dst)],
+                                         axis=-1))
+        with scope("radial_mlp"):
+            W1 = linear(params["dist_proj"][0], rbf) * env[:, None]  # (E, C)
+            W2 = linear(params["dist_proj"][1], rbf) * env[:, None]
+            W3 = linear(params["dist_proj"][2], rbf) * env[:, None]
 
         # the (E, 3, 3, C) edge tensor is 9C wide vs the ~4C of its inputs
         # — built INSIDE the fused dst-tile kernel on the Pallas path, so
@@ -178,49 +191,57 @@ class TensorNet:
         X = lg.aggregate_edge_messages(
             embed_msg, (Zij, W1, W2, W3, A_e, S_e), mask=lg.edge_mask)
 
-        norm = layernorm(params["init_norm"], tensor_norm(X))
-        for lin in params["emb_lin_scalar"]:
-            norm = jax.nn.silu(linear(lin, norm))
-        norm = norm.reshape(-1, C, 3)  # torchmd-net's (C, 3) unflatten order
-        I, A, S = decompose(X)
-        I = _mix(params["emb_lin_tensor"][0], I)
-        A = _mix(params["emb_lin_tensor"][1], A)
-        S = _mix(params["emb_lin_tensor"][2], S)
-        X = (I * norm[:, None, None, :, 0] + A * norm[:, None, None, :, 1]
-             + S * norm[:, None, None, :, 2])
+        with scope("node_linear"):
+            norm = layernorm(params["init_norm"], tensor_norm(X))
+            for lin in params["emb_lin_scalar"]:
+                norm = jax.nn.silu(linear(lin, norm))
+            # torchmd-net's (C, 3) unflatten order
+            norm = norm.reshape(-1, C, 3)
+        with scope("node_tensor"):
+            I, A, S = decompose(X)
+            I = _mix(params["emb_lin_tensor"][0], I)
+            A = _mix(params["emb_lin_tensor"][1], A)
+            S = _mix(params["emb_lin_tensor"][2], S)
+            X = (I * norm[:, None, None, :, 0] + A * norm[:, None, None, :, 1]
+                 + S * norm[:, None, None, :, 2])
         X = lg.halo_exchange(X)
 
         # --- interaction layers ---
-        for lp in params["layers"]:
-            X = self._interaction(lp, lg, X, rbf, env)
+        for t, lp in enumerate(params["layers"]):
+            with scope(f"interaction{t}"):
+                X = self._interaction(lp, lg, X, rbf, env)
             X = lg.halo_exchange(X)
 
         # --- invariant readout (reference dist_forward :131-151) ---
-        I, A, S = decompose(X)
-        inv = jnp.concatenate(
-            [tensor_norm(I), tensor_norm(A), tensor_norm(S)], axis=-1
-        ).astype(positions.dtype)
-        x = linear(fp["linear"], layernorm(fp["out_norm"], inv))
-        e_atom = mlp(fp["final"], x)[:, 0]
-        e_ref = fp["species_ref"]["w"][lg.species, 0]
-        return fp["data_std"] * e_atom + e_ref
+        with scope("readout"):
+            I, A, S = decompose(X)
+            inv = jnp.concatenate(
+                [tensor_norm(I), tensor_norm(A), tensor_norm(S)], axis=-1
+            ).astype(positions.dtype)
+            x = linear(fp["linear"], layernorm(fp["out_norm"], inv))
+            e_atom = mlp(fp["final"], x)[:, 0]
+            e_ref = fp["species_ref"]["w"][lg.species, 0]
+            return fp["data_std"] * e_atom + e_ref
 
     def _interaction(self, lp, lg, X, rbf, env):
         """torchmd-net TensorNetInteraction (O(3) group): radial edge gates,
         per-channel normalization X/(||X||+1), channel mixes, neighbor
         message M, B = YM + MY, normalized remix, X + dX + dX^2."""
         C = self.cfg.units
-        f = rbf
-        for lin in lp["lin_scalar"]:
-            f = jax.nn.silu(linear(lin, f))
-        f = (f * env[:, None]).reshape(-1, C, 3)  # torchmd-net (C, 3) order
+        with scope("radial_mlp"):
+            f = rbf
+            for lin in lp["lin_scalar"]:
+                f = jax.nn.silu(linear(lin, f))
+            # torchmd-net (C, 3) order
+            f = (f * env[:, None]).reshape(-1, C, 3)
 
-        X = X / (tensor_norm(X) + 1.0)[..., None, None, :]
-        I, A, S = decompose(X)
-        I = _mix(lp["lin_tensor"][0], I)
-        A = _mix(lp["lin_tensor"][1], A)
-        S = _mix(lp["lin_tensor"][2], S)
-        Y = I + A + S
+        with scope("node_tensor"):
+            X = X / (tensor_norm(X) + 1.0)[..., None, None, :]
+            I, A, S = decompose(X)
+            I = _mix(lp["lin_tensor"][0], I)
+            A = _mix(lp["lin_tensor"][1], A)
+            S = _mix(lp["lin_tensor"][2], S)
+            Y = I + A + S
 
         # 27C of gathered src components fold into a 9C message inside the
         # fused kernel (in-kernel src gather on the Pallas path)
@@ -238,11 +259,12 @@ class TensorNet:
         # batched 3x3 matmuls over (node, channel); the matrix axes are
         # (-3, -2), channels ride the lane axis untouched
         matmul = lambda P, Q: jnp.einsum("nijc,njkc->nikc", P, Q)
-        B = matmul(Y, M) + matmul(M, Y)
-        I, A, S = decompose(B)
-        np1 = (tensor_norm(B) + 1.0)[..., None, None, :]
-        I = _mix(lp["lin_tensor"][3], I / np1)
-        A = _mix(lp["lin_tensor"][4], A / np1)
-        S = _mix(lp["lin_tensor"][5], S / np1)
-        dX = I + A + S
-        return X + dX + matmul(dX, dX)
+        with scope("node_tensor"):
+            B = matmul(Y, M) + matmul(M, Y)
+            I, A, S = decompose(B)
+            np1 = (tensor_norm(B) + 1.0)[..., None, None, :]
+            I = _mix(lp["lin_tensor"][3], I / np1)
+            A = _mix(lp["lin_tensor"][4], A / np1)
+            S = _mix(lp["lin_tensor"][5], S / np1)
+            dX = I + A + S
+            return X + dX + matmul(dX, dX)

@@ -20,14 +20,15 @@ untouched):
   active-loop buffer/swaps), served as Prometheus text exposition by
   :class:`MetricsServer` and snapshot-dumpable into bench JSON.
 
-- **Compiler/device** (:mod:`.profiling` / :mod:`.attribution` /
-  :mod:`.roofline`) — compile telemetry at every compile point (fresh
-  vs AOT-rehydrate, wall time, bucket key; ``distmlip_compile_seconds``
-  + ``distmlip_compiles_total{kind=}``), scope-level device-time
-  attribution from a profiler capture or the analytic cost model, and
-  roofline rows (intensity / achieved vs peak / MFU) joined from the
-  FLOP and memory planners. CLIs: ``tools/roofline.py`` and
-  ``tools/perf_gate.py`` (baseline regression gate).
+- **Compiler/device** (:mod:`.profiling` / :mod:`.roofline`) — compile
+  telemetry at every compile point (fresh vs AOT-rehydrate, wall time,
+  bucket key; ``distmlip_compile_seconds`` +
+  ``distmlip_compiles_total{kind=}``) and roofline rows (intensity /
+  achieved vs peak / MFU) joined from the FLOP and memory planners.
+  CLIs: ``tools/roofline.py`` and ``tools/perf_gate.py`` (baseline
+  regression gate). Where device time goes, stage by stage, is read from
+  a profiler trace and the compiled step's own metadata:
+  ``telemetry.set_tracing`` + ``telemetry.stage_tables()``.
 
 Plus the incident plane: :class:`~.slo.SLOMonitor` evaluates per-tenant
 multi-window burn rates and, on breach (or first deadline miss / replica
@@ -52,8 +53,7 @@ jitted code is the DML003 lint violation (``contract_check --lint``).
 
 from __future__ import annotations
 
-from . import attribution, profiling, roofline, runtime
-from .attribution import ScopeBreakdown, attribute
+from . import profiling, roofline, runtime
 from .export import (critical_path_summary, critical_paths,
                      format_critical_path, load_trace, load_trace_dir,
                      request_trace_summary, to_trace_events, write_trace)
@@ -172,15 +172,12 @@ __all__ = [
     "critical_path_summary",
     "format_critical_path",
     "profiling",
-    "attribution",
     "roofline",
     "CompileEvent",
     "record_compile",
     "compile_events",
     "compile_counts",
     "reset_compile_log",
-    "ScopeBreakdown",
-    "attribute",
     "RooflineRow",
     "format_roofline_table",
 ]

@@ -291,14 +291,15 @@ class LocalGraph:
                                      indices_are_sorted=True,
                                      kernels=self.kernels)
         s = self.e_split
-        out = fused_segment_sum(
-            data[:s], self.edge_dst[:s], self.n_cap,
-            None if mask is None else mask[:s], indices_are_sorted=True,
-            kernels=self.kernels)
-        return out + fused_segment_sum(
-            data[s:], self.edge_dst[s:], self.n_cap,
-            None if mask is None else mask[s:], indices_are_sorted=True,
-            kernels=self.kernels)
+        with scope("edge_aggregate"):  # the slices and the add, too
+            out = fused_segment_sum(
+                data[:s], self.edge_dst[:s], self.n_cap,
+                None if mask is None else mask[:s], indices_are_sorted=True,
+                kernels=self.kernels)
+            return out + fused_segment_sum(
+                data[s:], self.edge_dst[s:], self.n_cap,
+                None if mask is None else mask[s:], indices_are_sorted=True,
+                kernels=self.kernels)
 
     def aggregate_edge_messages(self, msg_fn, edge_inputs, mask=None):
         """Fused per-edge compute + dst aggregation ((n_cap, ...)).
@@ -320,14 +321,15 @@ class LocalGraph:
                 diff_params=self.kernels_diff_params)
         out = None
         for sl in (slice(0, self.e_split), slice(self.e_split, None)):
-            sliced = [Gather(i.node, i.idx[sl]) if isinstance(i, Gather)
-                      else i[sl] for i in edge_inputs]
-            part = fused_edge_aggregate(
-                msg_fn, sliced, self.edge_dst[sl], self.n_cap,
-                None if mask is None else mask[sl],
-                indices_are_sorted=True, kernels=self.kernels,
-                diff_params=self.kernels_diff_params)
-            out = part if out is None else out + part
+            with scope("edge_aggregate"):  # the slices and the add, too
+                sliced = [Gather(i.node, i.idx[sl]) if isinstance(i, Gather)
+                          else i[sl] for i in edge_inputs]
+                part = fused_edge_aggregate(
+                    msg_fn, sliced, self.edge_dst[sl], self.n_cap,
+                    None if mask is None else mask[sl],
+                    indices_are_sorted=True, kernels=self.kernels,
+                    diff_params=self.kernels_diff_params)
+                out = part if out is None else out + part
         return out
 
     def chunk_sorted(self, chunk: int) -> bool:

@@ -135,18 +135,19 @@ def make_total_energy(model_energy_fn, mesh: Mesh | None,
             graph_local, axis, halo_mode, kernels=kernels,
             kernels_diff_params=kernels_diff_params)
         dtype = positions.dtype
-        with scope("apply_strain"):
+        with scope("edge_geometry"), scope("apply_strain"):
             pos, lg.lattice = apply_strain(
                 positions[0], lg.lattice.astype(dtype), strain.astype(dtype)
             )
         pos = lg.halo_exchange(pos)
         with scope("model_energy"):
             out = model_energy_fn(params, lg, pos)
-        if aux:
-            e_atoms, aux_out = out
-            aux_out = jax.tree.map(lambda a: a[None], aux_out)
-            return lg.owned_sum(e_atoms.reshape(-1, 1)), aux_out
-        return lg.owned_sum(out.reshape(-1, 1))
+        with scope("readout"):
+            if aux:
+                e_atoms, aux_out = out
+                aux_out = jax.tree.map(lambda a: a[None], aux_out)
+                return lg.owned_sum(e_atoms.reshape(-1, 1)), aux_out
+            return lg.owned_sum(out.reshape(-1, 1))
 
     if mesh is None:
         def total_energy(params, graph, positions, strain):
@@ -377,7 +378,7 @@ def _local_batched_energy(model_energy_fn, aux, halo_mode="coalesced",
         dtype = positions.dtype
         pos = positions[0]
         sid = lg.struct_id
-        with scope("apply_strain"):
+        with scope("edge_geometry"), scope("apply_strain"):
             # per-structure symmetric strain: x_i -> x_i @ (I + eps_{s(i)});
             # Cartesian edge offsets deform with their structure's cell.
             # Padded and halo rows have sid == B — the gather clamps them
@@ -398,7 +399,7 @@ def _local_batched_energy(model_energy_fn, aux, halo_mode="coalesced",
         with scope("model_energy"):
             out = model_energy_fn(params, lg, pos)
         e_atoms, aux_out = out if aux else (out, None)
-        with scope("batched_readout"):
+        with scope("readout"), scope("batched_readout"):
             # segment_sum onto batch slots + psum over the SPATIAL axis
             # only — the batch axis never carries a collective
             energies = lg.structure_sum(e_atoms.reshape(-1).astype(dtype))

@@ -8,7 +8,9 @@ One pipeline replaces the ad-hoc timing that used to live in
 - ``Telemetry`` + sinks (``AggregatingSink``, ``JsonlSink``,
   ``StderrSummarySink``) — where records go;
 - ``annotate``/``scope``/``device_trace`` — xprof timeline names on the
-  host and jit hot paths;
+  host and jit hot paths; ``STAGES`` names the stages the models scope,
+  ``stage_tables()`` says after a tracing session which compiled
+  instruction belongs to which;
 - ``report`` — offline aggregation of a JSONL run
   (``tools/telemetry_report.py``).
 
@@ -26,7 +28,9 @@ Quick start::
 from .record import PHASE_KEYS, StepRecord, TrainRecord
 from .sinks import (AggregatingSink, JsonlSink, StderrSummarySink, Telemetry,
                     TelemetrySink)
-from .trace import annotate, device_trace, scope, set_tracing, tracing_enabled
+from .stages import STAGES
+from .trace import (annotate, device_trace, note_dispatch, scope, set_tracing,
+                    stage_tables, tracing_enabled)
 
 __all__ = [
     "PHASE_KEYS",
@@ -42,4 +46,7 @@ __all__ = [
     "device_trace",
     "set_tracing",
     "tracing_enabled",
+    "note_dispatch",
+    "stage_tables",
+    "STAGES",
 ]

@@ -23,11 +23,18 @@ def enable_compile_cache() -> str:
     is part of the cache key, so one derived from a temp name, a pid or
     the time would never hit.
     """
+    import jax
+
+    # An executable loaded from the cache keeps the metadata of the code
+    # that compiled it, and jax leaves metadata out of the cache's key. The
+    # stage tables of a tracing session (telemetry/trace.py) read each
+    # instruction's scope stack from that metadata: names that older code
+    # wrote would be read as this code's. With metadata in the key, a
+    # change of scopes (or of a traced line's number) compiles anew.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     path = os.path.join(CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -168,6 +168,29 @@ def test_compile_cache_defaults_inside_the_checkout():
     assert path == after == os.path.join(REPO, ".jax_cache")
 
 
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["from-outside", "in-checkout"])
+def test_compile_cache_keys_executables_by_their_metadata(tmp_path, placed):
+    """An executable from the cache carries the scope names of the code
+    that compiled it, and the stage tables read them: with either
+    placement the key holds the metadata, so changed scopes compile anew."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = ("import jax\n"
+            "from distmlip_tpu.utils.compile_cache import "
+            "enable_compile_cache\n"
+            "name = 'jax_compilation_cache_include_metadata_in_key'\n"
+            "before = getattr(jax.config, name)\n"
+            "enable_compile_cache()\n"
+            "print(before, getattr(jax.config, name))\n")
+    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split()[-2:] == ["False", "True"]
+
+
 # ---------------------------------------------------------------------------
 # device peaks
 # ---------------------------------------------------------------------------

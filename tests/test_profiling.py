@@ -1,5 +1,5 @@
-"""Compiler/device observability plane: compile telemetry, device-time
-attribution, roofline accounting, perf-regression baseline gate.
+"""Compiler/device observability plane: compile telemetry, roofline
+accounting, perf-regression baseline gate.
 
 The contracts under test:
 
@@ -7,9 +7,6 @@ The contracts under test:
   the fresh-vs-AOT split: a BatchedPotential bucket compile records
   ``fresh``; a replica restarted onto a warm AOT cache records ``aot``
   rehydrates and keeps ``compile_count == 0`` (the restart gate);
-- trace-based and cost-model attribution bucket identically — a
-  ``named_scope`` beats the op name for both sources, and a synthetic
-  Perfetto capture and a traced jaxpr produce the same category keys;
 - ``jaxpr_flop_estimate`` is dot_general-exact; roofline rows derive
   intensity/achieved/MFU without a chip, and record-derived rows
   tolerate mixed rounds where only some records carry FLOP estimates;
@@ -31,9 +28,6 @@ from distmlip_tpu import geometry
 from distmlip_tpu.calculators import Atoms, BatchedPotential
 from distmlip_tpu.models import PairConfig, PairPotential
 from distmlip_tpu.obs import Observability, profiling, uninstall
-from distmlip_tpu.obs.attribution import (CATEGORIES, ScopeBreakdown,
-                                          attribute, attribute_cost_model,
-                                          attribute_trace, classify)
 from distmlip_tpu.obs.roofline import (RooflineRow, bytes_touched,
                                        format_roofline_table,
                                        jaxpr_flop_estimate,
@@ -175,84 +169,6 @@ def test_metrics_label_cardinality_cap_overflows_to_other():
                     '{metric="x_total"}', 0) == 6.0
     # capped children keep their own identity
     assert vals.get('x_total{k="v0"}') == 1.0
-
-
-# ---------------------------------------------------------------------------
-# device-time attribution: trace + cost-model, one bucketing
-# ---------------------------------------------------------------------------
-
-
-def test_classify_rules_and_scope_priority():
-    assert classify("ppermute") == "halo_exchange"
-    assert classify("fusion.3", "jit(f)/halo_exchange/add") == "halo_exchange"
-    # an author named_scope beats the op name
-    assert classify("dot_general", "jit(f)/halo_exchange") == "halo_exchange"
-    assert classify("pallas_call") == "pallas_kernel"
-    assert classify("scatter-add.1") == "scatter"
-    assert classify("transpose", "jit(f)/backward") == "gradient_transpose"
-    assert classify("dot_general") == "interior_aggregation"
-    assert classify("copy.7") == "other"
-    assert set(CATEGORIES) >= {classify("anything"), "halo_exchange"}
-
-
-def test_attribute_trace_synthetic_capture(tmp_path):
-    trace = {"traceEvents": [
-        {"ph": "X", "name": "ppermute.1", "dur": 300.0, "args": {}},
-        {"ph": "X", "name": "fusion.2", "dur": 500.0,
-         "args": {"op_name": "jit(step)/interior_aggregation/dot_general"}},
-        {"ph": "X", "name": "scatter-add.3", "dur": 200.0, "args": {}},
-        {"ph": "M", "name": "process_name"},          # metadata: skipped
-        {"ph": "X", "name": "thread_sort_index"},     # noise: skipped
-        {"ph": "X", "name": "zero", "dur": 0.0},      # no duration: skipped
-    ]}
-    bd = attribute_trace(trace, program="step")
-    assert bd.source == "trace" and bd.n_events == 3
-    assert bd.total_s == pytest.approx(1e-3)
-    assert bd.by_category["halo_exchange"] == pytest.approx(300e-6)
-    assert bd.by_category["interior_aggregation"] == pytest.approx(500e-6)
-    assert bd.fraction("scatter") == pytest.approx(0.2)
-    # path round-trip (the offline-parser entry point)
-    p = tmp_path / "capture.json"
-    p.write_text(json.dumps(trace))
-    bd2 = attribute_trace(str(p))
-    assert bd2.by_category == bd.by_category
-    assert "halo_exchange" in bd.render()
-
-
-def test_attribute_cost_model_apportions_measured_total():
-    import jax
-    import jax.numpy as jnp
-
-    def step(x, w):
-        with jax.named_scope("halo_exchange"):
-            h = jnp.roll(x, 1, axis=0) + x
-        with jax.named_scope("interior_aggregation"):
-            y = h @ w
-        return y.sum()
-
-    jaxpr = jax.make_jaxpr(step)(jnp.ones((8, 4)), jnp.ones((4, 4)))
-    bd = attribute_cost_model(jaxpr, total_s=2.0, program="step")
-    assert bd.source == "cost_model" and bd.n_events > 0
-    # the split is an estimate; the total is real
-    assert sum(bd.by_category.values()) == pytest.approx(2.0)
-    assert bd.by_category.get("interior_aggregation", 0.0) > 0
-    assert bd.total_s == 2.0
-    d = bd.as_dict()
-    assert d["program"] == "step" and d["by_category"] == bd.by_category
-
-
-def test_attribute_entry_point_prefers_trace_falls_back():
-    trace = {"traceEvents": [
-        {"ph": "X", "name": "ppermute", "dur": 100.0}]}
-    assert attribute(1.0, trace=trace).source == "trace"
-    empty = {"traceEvents": []}
-    import jax
-    import jax.numpy as jnp
-
-    jaxpr = jax.make_jaxpr(lambda x: (x * x).sum())(jnp.ones(4))
-    assert attribute(1.0, trace=empty, jaxpr=jaxpr).source == "cost_model"
-    bd = attribute(1.0)
-    assert isinstance(bd, ScopeBreakdown) and bd.n_events == 0
 
 
 # ---------------------------------------------------------------------------

@@ -375,3 +375,286 @@ def test_disabled_hub_not_invoked(rng):
     pot = _pot(num_partitions=1, telemetry=tel)
     res = pot.calculate(make_atoms(rng))
     assert np.isfinite(res["energy"])
+
+
+# ---------------------------------------------------------------------------
+# stage scopes, stage tables, host spans (PR 26)
+# ---------------------------------------------------------------------------
+
+import jax  # noqa: E402
+
+from distmlip_tpu.analysis.ir import iter_sites  # noqa: E402
+from distmlip_tpu.calculators import MolecularDynamics  # noqa: E402
+from distmlip_tpu.calculators.batched import BatchedPotential  # noqa: E402
+from distmlip_tpu.models import (MACE, MACEConfig, TensorNet,  # noqa: E402
+                                 TensorNetConfig)
+from distmlip_tpu.parallel.audit import (count_collectives,  # noqa: E402
+                                         ppermutes_by_scope)
+from distmlip_tpu.telemetry import STAGES, stage_tables  # noqa: E402
+from distmlip_tpu.telemetry import trace as trace_mod  # noqa: E402
+from distmlip_tpu.telemetry.stages import (pass_of, stage_of,  # noqa: E402
+                                           stage_table)
+
+TOY = {
+    "mace": lambda: MACE(MACEConfig(
+        num_species=95, channels=8, l_max=2, a_lmax=2, hidden_lmax=1,
+        correlation=2, num_interactions=2, num_bessel=4, radial_mlp=8,
+        radial_layers=2, cutoff=3.0, avg_num_neighbors=12.0, edge_chunk=512,
+        zbl=True, remat=True)),
+    "tensornet": lambda: TensorNet(TensorNetConfig(
+        units=8, num_rbf=4, num_layers=2, cutoff=3.0)),
+}
+# what a family has no code for (the table of telemetry/stages.py)
+NOT_IN = {"mace": set(), "tensornet": {"edge_gather", "pair_repulsion"}}
+
+
+def toy_potential(family, rng, nparts=1, **kw):
+    model = TOY[family]()
+    pot = DistPotential(model, model.init(jax.random.PRNGKey(0)),
+                        num_partitions=nparts, skin=0.3, **kw)
+    atoms = make_atoms(rng, reps=(3 * nparts, 2, 2), a=3.9)
+    return pot, atoms
+
+
+def step_jaxpr(pot, atoms):
+    graph, _, positions = pot._prepare(atoms)
+    return jax.make_jaxpr(pot._potential)(pot.params, graph, positions)
+
+
+@pytest.mark.parametrize("kernels", [None, "interpret"])
+@pytest.mark.parametrize("family", ["mace", "tensornet"])
+def test_every_stage_is_scoped_where_the_work_happens(rng, family, kernels):
+    """Each declared stage is on the scope stack of some equation of the
+    energy-and-forces program (two partitions, so the halo has work), and
+    no contraction, scatter-add or kernel call is outside every stage."""
+    pot, atoms = toy_potential(family, rng, nparts=2, kernels=kernels)
+    sites = list(iter_sites(step_jaxpr(pot, atoms)))
+    seen = {stage_of(site.stack) for site in sites}
+    assert set(STAGES) - NOT_IN[family] <= seen
+    heavy = [s for s in sites if s.primitive in (
+        "dot_general", "scatter-add", "scatter_add", "pallas_call")]
+    assert {s.primitive for s in heavy} >= {"dot_general"}
+    if kernels == "interpret":
+        assert any(s.primitive == "pallas_call" for s in heavy)
+    outside = [(s.primitive, s.stack) for s in heavy
+               if stage_of(s.stack) is None]
+    assert not outside
+
+
+def test_stage_and_pass_of_an_op_name():
+    fwd = "jit(potential)/energy_and_grad/jvp(model_energy/interaction0/radial_mlp)/dot_general"
+    bwd = ("jit(potential)/energy_and_grad/transpose(jvp(model_energy))/"
+           "interaction0/checkpoint/edge_gather/while/body/closed_call/"
+           "checkpoint/edge_aggregate/scatter-add")
+    again = bwd.replace("checkpoint/edge_aggregate",
+                        "checkpoint/rematted_computation/edge_message")
+    assert (stage_of(fwd), pass_of(fwd)) == ("radial_mlp", "forward")
+    # the innermost declared name wins
+    assert (stage_of(bwd), pass_of(bwd)) == ("edge_aggregate", "backward")
+    assert (stage_of(again), pass_of(again)) == ("edge_message", "recompute")
+    # parallel/halo.py's own scopes are the halo stage
+    assert stage_of("jit(f)/halo_exchange/halo/shift1/ppermute") == "halo"
+    # a jitted function's name is no scope; an undeclared scope is no stage
+    assert stage_of("jit(readout)/stress/mul") is None
+    assert stage_of("") is None and pass_of("") == "forward"
+
+
+HLO = """HloModule jit_f, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %m = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(f)/jvp(edge_message)/mul"}
+  ROOT %a = f32[8]{0} add(%m, %p), metadata={op_name="jit(f)/jvp(edge_aggregate)/add"}
+}
+
+%fused_computation.1 (q: f32[8]) -> (f32[8], f32[8]) {
+  %q = f32[8]{0} parameter(0)
+  %n = f32[8]{0} negate(%q), metadata={op_name="jit(f)/transpose(jvp(readout))/neg"}
+  ROOT %t = (f32[8]{0}, f32[8]{0}) tuple(%n, %q)
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %fusion = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/jvp(edge_aggregate)/add"}
+  %fusion.1 = (f32[8]{0}, f32[8]{0}) fusion(%fusion), kind=kLoop, calls=%fused_computation.1
+  %gte = f32[8]{0} get-tuple-element(%fusion.1), index=0
+  %cc = f32[8]{0} custom-call(%gte), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/transpose(jvp(edge_aggregate))/pallas_call"}
+  %copy.3 = f32[8]{0} copy(%cc)
+  %copy.4 = f32[8]{0} copy(%x)
+  ROOT %add.9 = f32[8]{0} add(%copy.3, %copy.4), metadata={op_name="jit(f)/add"}
+}
+"""
+
+
+def test_stage_table_reads_fusions_by_their_root():
+    rows = {r["head"]: r for r in stage_table(HLO)}
+    assert set(rows) == {
+        "%fusion = f32[8]{0} fusion",
+        "%fusion.1 = (f32[8]{0}, f32[8]{0}) fusion",
+        '%cc = f32[8]{0} custom-call custom_call_target="tpu_custom_call"',
+        "%copy.3 = f32[8]{0} copy", "%copy.4 = f32[8]{0} copy",
+        "%add.9 = f32[8]{0} add"}
+    mixed = rows["%fusion = f32[8]{0} fusion"]
+    assert mixed["stage"] == "edge_aggregate"      # the root's
+    assert mixed["stages"] == ["edge_aggregate", "edge_message"]
+    # no metadata of its own: the one stage its fused instructions agree on
+    alone = rows["%fusion.1 = (f32[8]{0}, f32[8]{0}) fusion"]
+    assert alone["stage"] == "readout" and alone["stages"] == ["readout"]
+    kernel = rows['%cc = f32[8]{0} custom-call '
+                  'custom_call_target="tpu_custom_call"']
+    assert (kernel["stage"], kernel["pass"]) == ("edge_aggregate", "backward")
+    # the compiler's own copy, without metadata: what it copies decides
+    copied = rows["%copy.3 = f32[8]{0} copy"]
+    assert (copied["stage"], copied["pass"], copied["inherited"]) == (
+        "edge_aggregate", "backward", True)
+    # a copy of a parameter has nothing to inherit
+    assert rows["%copy.4 = f32[8]{0} copy"]["stage"] is None
+
+
+@pytest.fixture
+def fresh_session(monkeypatch):
+    """The module's session state, emptied for one test."""
+    monkeypatch.setattr(trace_mod, "_stage_tables", [])
+    monkeypatch.setattr(trace_mod, "_noted", {})
+    yield
+    set_tracing(False)
+
+
+def test_stage_table_of_a_compiled_step_outlives_the_potential(
+        rng, fresh_session):
+    pot, atoms = toy_potential("mace", rng)
+    md = MolecularDynamics(atoms, pot, ensemble="nve", timestep=0.05)
+    set_tracing(True)
+    md.step()
+    md.step()
+    assert stage_tables() == []          # built when the session closes
+    set_tracing(False)
+    pot.close()
+    del pot, md
+    jax.clear_caches()
+    (table,) = stage_tables()
+    assert table["executable"] == "potential" and "error" not in table
+    rows = table["instructions"]
+    by_stage = {s: [r for r in rows if r["stage"] == s] for s in STAGES}
+    # one partition: the halo has no work
+    assert all(by_stage[s] for s in STAGES if s != "halo")
+    passes = {r["pass"] for r in rows}
+    assert passes == {"forward", "backward", "recompute"}
+    assert any(r["pass"] != "forward" for r in by_stage["edge_aggregate"])
+    # plain data: no executable, no buffer, no text of the module
+    text = json.dumps(stage_tables())
+    assert json.loads(text) == stage_tables() and "HloModule" not in text
+    assert all(set(r) <= {"head", "stage", "pass", "stages", "inherited"}
+               for r in rows)
+
+
+class Counting:
+    """A jitted callable that counts its calls and its lowerings."""
+
+    def __init__(self, fn):
+        self.fn, self.calls, self.lowerings = fn, 0, 0
+
+    def __call__(self, *args):
+        self.calls += 1
+        return self.fn(*args)
+
+    def lower(self, *args):
+        self.lowerings += 1
+        return self.fn.lower(*args)
+
+
+def test_nothing_is_noted_or_lowered_while_tracing_is_off(
+        rng, fresh_session):
+    pot, atoms = toy_potential("tensornet", rng)
+    pot._potential = counted = Counting(pot._potential)
+    pot.calculate(atoms)
+    pot.calculate(atoms)
+    assert (counted.calls, counted.lowerings) == (2, 0)
+    assert trace_mod._noted == {} and stage_tables() == []
+    # one session: the step is noted at each dispatch, lowered once at
+    # the close and not while the session is open
+    set_tracing(True)
+    pot.calculate(atoms)
+    pot.calculate(atoms)
+    assert counted.lowerings == 0 and len(trace_mod._noted) == 1
+    set_tracing(False)
+    assert (counted.calls, counted.lowerings) == (4, 1)
+    assert trace_mod._noted == {} and len(stage_tables()) == 1
+
+
+@pytest.fixture
+def spans(monkeypatch):
+    """Every TraceAnnotation as (name, names of the spans open around
+    it)."""
+    seen, open_ = [], []
+
+    class Span:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append((self.name, tuple(open_)))
+            open_.append(self.name)
+
+        def __exit__(self, *exc):
+            open_.pop()
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
+    return seen
+
+
+PARTS = ["distmlip/dispatch", "distmlip/wait", "distmlip/results_to_host"]
+
+
+def test_host_spans_reach_the_last_copy(rng, spans, fresh_session):
+    pot, atoms = toy_potential("tensornet", rng)
+    md = MolecularDynamics(atoms, pot, ensemble="nve", timestep=0.05)
+    md.step()
+    assert spans == []                   # only while tracing is on
+    set_tracing(True)
+    md.step()
+    set_tracing(False)
+    md.step()
+    call = ("distmlip/integrate", "distmlip/calculate")
+    assert spans == [
+        ("distmlip/integrate", ()),
+        ("distmlip/calculate", call[:1]),
+        ("distmlip/prepare", call),
+        ("distmlip/positions_upload", call + ("distmlip/prepare",)),
+        ("distmlip/potential", call),
+        *[(name, call + ("distmlip/potential",)) for name in PARTS]]
+
+
+def test_batched_potential_has_the_same_spans(rng, spans, fresh_session):
+    model = TOY["tensornet"]()
+    pot = BatchedPotential(model, model.init(jax.random.PRNGKey(0)))
+    batch = [make_atoms(rng, reps=(2, 2, 2), a=3.9),
+             make_atoms(rng, reps=(2, 2, 1), a=3.9)]
+    pot.calculate(batch)
+    assert spans == []
+    set_tracing(True)
+    pot.calculate(batch)
+    set_tracing(False)
+    names = [name for name, _ in spans]
+    assert names[:2] == ["distmlip/calculate", "distmlip/prepare"]
+    assert names[-4:] == ["distmlip/batched_potential", *PARTS]
+    assert all(around == ("distmlip/calculate", "distmlip/batched_potential")
+               for _, around in spans[-3:])
+    assert [t["executable"] for t in stage_tables()] == ["potential"]
+
+
+@pytest.mark.parametrize("family, before", [("mace", 7), ("tensornet", 8)])
+def test_ppermutes_by_scope_are_unchanged(rng, family, before):
+    """The new scopes sit around parallel/halo.py's, not in their place:
+    every ppermute is still counted under ``halo_exchange``, as many as
+    on the commit before the stages (counted there with this very
+    program)."""
+    pot, atoms = toy_potential(family, rng, nparts=2)
+    jaxpr = step_jaxpr(pot, atoms)
+    scopes = ppermutes_by_scope(jaxpr)
+    total = count_collectives(jaxpr).get("ppermute", 0)
+    assert sum(scopes.values()) == total
+    assert all("halo_exchange" in s and stage_of(s) == "halo"
+               for s in scopes)
+    assert total == before

@@ -3,7 +3,7 @@
 
     python tools/roofline.py [--models chgnet,tensornet,mace,escn]
         [--programs SUBSTR] [--json] [--times times.json]
-        [--jsonl run.jsonl] [--mfu-floor F] [--attribution]
+        [--jsonl run.jsonl] [--mfu-floor F]
 
 Traces the SAME programs ``tools/contract_check.py`` gates (every model
 at 1x1 / 2x1 / 2x2, the packed batch, the ensembles, the DeviceMD chunk,
@@ -26,8 +26,6 @@ the train steps and tier family) and places each on the roofline:
 ``--mfu-floor F`` exits 3 when any program WITH a computable MFU (a
 measured time and a known peak) sits below ``F`` — the pinned-floor
 regression gate; programs without measurements never trip it.
-``--attribution`` appends the per-category cost-model device-time split
-(:mod:`obs.attribution`) under each row.
 
 Exit codes: 0 clean, 2 usage error, 3 MFU-floor regression.
 """
@@ -117,9 +115,6 @@ def main(argv=None) -> int:
     ap.add_argument("--mfu-floor", type=float, default=None,
                     help="exit 3 when a measured program's MFU falls "
                          "below this fraction")
-    ap.add_argument("--attribution", action="store_true",
-                    help="append the per-category cost-model split "
-                         "under each program")
     try:
         args = ap.parse_args(argv)
     except SystemExit as e:
@@ -138,7 +133,6 @@ def main(argv=None) -> int:
     models = tuple(m.strip() for m in args.models.split(",") if m.strip())
 
     from distmlip_tpu.analysis.memory import analyze_memory
-    from distmlip_tpu.obs.attribution import attribute_cost_model
     from distmlip_tpu.obs.roofline import (RooflineRow, bytes_touched,
                                            format_roofline_table,
                                            jaxpr_flop_estimate)
@@ -146,7 +140,7 @@ def main(argv=None) -> int:
 
     peak, peak_bw = device_peaks() or (0.0, 0.0)
     programs = trace_programs(models, args.programs)
-    rows, breakdowns = [], []
+    rows = []
     for prog in programs:
         n_dev = 2 if ("2x1" in prog.name or "2x2" in prog.name) else 1
         if "2x2" in prog.name:
@@ -159,9 +153,6 @@ def main(argv=None) -> int:
             time_s=t, peak_flops=peak, peak_bytes_per_s=peak_bw,
             n_devices=n_dev,
             source="measured" if t > 0 else "cost_model"))
-        if args.attribution:
-            breakdowns.append(attribute_cost_model(
-                prog.jaxpr, total_s=t or 1.0, program=prog.name))
 
     below = [r for r in rows
              if args.mfu_floor is not None and r.time_s > 0
@@ -172,16 +163,12 @@ def main(argv=None) -> int:
             "peak_flops_per_device": peak,
             "mfu_floor": args.mfu_floor,
             "below_floor": [r.program for r in below],
-            "attribution": [b.as_dict() for b in breakdowns],
         }, indent=2, sort_keys=True))
     else:
         print(format_roofline_table(
             rows, title=f"roofline: {len(rows)} program(s), "
             f"peak/device="
             + (f"{peak:.3g} FLOP/s" if peak else "n/a (no TPU)")))
-        for b in breakdowns:
-            print()
-            print(b.render())
         if below:
             print()
             for r in below:

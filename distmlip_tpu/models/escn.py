@@ -210,20 +210,19 @@ class ESCN:
         # + 2 constant matmuls per l — noise next to the SO(2) GEMMs), so
         # peak memory is O(chunk), not O(E). Scaffolding shared with MACE
         # (ops/chunk.py).
-        from ..ops.chunk import chunk_layout, chunked, scan_accumulate
+        from ..ops.chunk import (chunk_layout, chunked, scan_accumulate,
+                                 take_rows)
 
         e_cap = lg.edge_src.shape[0]
         # chunk boundaries aligned to the interior/frontier split so every
         # chunk's dst stays sorted (indices_are_sorted survives the layout)
-        row_idx, row_valid, K, chunk = chunk_layout(
-            e_cap, cfg.edge_chunk,
-            lg.e_split if lg.has_frontier_split else None)
-        take = lambda x: chunked(jnp.asarray(x)[row_idx], K, chunk)
+        e_split = lg.e_split if lg.has_frontier_split else None
+        _, row_valid, K, chunk = chunk_layout(e_cap, cfg.edge_chunk, e_split)
+        take = lambda x: chunked(take_rows(x, chunk, e_split), K, chunk)
         edge_xs = (
             take(lg.edge_src),
             take(lg.edge_dst),
-            chunked(jnp.asarray(lg.edge_mask)[row_idx]
-                    & jnp.asarray(row_valid), K, chunk),
+            take(lg.edge_mask) & chunked(jnp.asarray(row_valid), K, chunk),
             take(rhat),
             take(bessel),
             take(env),

@@ -383,18 +383,17 @@ class ESCNMD:
 
         # --- edge-chunked scan scaffolding (shared with models/escn.py);
         # chunk_layout keeps every chunk inside one dst-sorted edge segment
-        from ..ops.chunk import chunk_layout, chunked, scan_accumulate
+        from ..ops.chunk import (chunk_layout, chunked, scan_accumulate,
+                                 take_rows)
 
         e_cap = lg.edge_src.shape[0]
-        row_idx, row_valid, K_ch, chunk = chunk_layout(
-            e_cap, cfg.edge_chunk,
-            lg.e_split if lg.has_frontier_split else None)
-        take = lambda x: chunked(jnp.asarray(x)[row_idx], K_ch, chunk)
+        e_split = lg.e_split if lg.has_frontier_split else None
+        _, row_valid, K_ch, chunk = chunk_layout(e_cap, cfg.edge_chunk, e_split)
+        take = lambda x: chunked(take_rows(x, chunk, e_split), K_ch, chunk)
         edge_xs = (
             take(lg.edge_src),
             take(lg.edge_dst),
-            chunked(jnp.asarray(lg.edge_mask)[row_idx]
-                    & jnp.asarray(row_valid), K_ch, chunk),
+            take(lg.edge_mask) & chunked(jnp.asarray(row_valid), K_ch, chunk),
             take(rhat),
             take(gauss),
             take(env),

@@ -369,8 +369,32 @@ class MACE:
             with scope("pair_repulsion"):
                 acc = acc + self._zbl_site(params, lg, d, acc_dtype)
 
+        # per-edge rows in chunk order, laid out ONCE for both interactions
+        # (nothing here is an interaction's own): chunk boundaries aligned
+        # to the interior/frontier split so every chunk's dst stays sorted
+        # (fast-path hint holds); static slices, so the transpose of the
+        # layout is copies and the cotangents of both interactions pass
+        # through it once
+        from ..ops.chunk import chunk_layout, chunked, take_rows
+
+        e_split = lg.e_split if lg.has_frontier_split else None
+        _, row_valid, K, chunk = chunk_layout(
+            lg.edge_src.shape[0], cfg.edge_chunk, e_split)
+        take = lambda x: chunked(take_rows(x, chunk, e_split), K, chunk)
+        with scope("edge_gather"):
+            Y_full = jnp.concatenate(
+                [Y[l] for l in range(cfg.l_max + 1)], axis=-1
+            ).astype(dtype)                               # (E, S_Y)
+            edge_xs = (
+                take(lg.edge_src),
+                take(lg.edge_dst),
+                take(lg.edge_mask) & chunked(jnp.asarray(row_valid), K, chunk),
+                take(Y_full),
+                take(bessel),
+            )
+
         for t, inter in enumerate(params["interactions"]):
-            body = partial(self._interaction, lg=lg, Y=Y, bessel=bessel,
+            body = partial(self._interaction, lg=lg, edge_xs=edge_xs,
                            z=z, t=t)
             if cfg.remat is True:
                 # full-remat mode only: with a policy, the inner edge/node
@@ -421,13 +445,16 @@ class MACE:
         # interior/frontier edge layout
         return 0.5 * lg.aggregate_edges(e_edge[:, None])[:, 0]
 
-    def _interaction(self, inter, h, *, lg, Y, bessel, z, t):
+    def _interaction(self, inter, h, *, lg, edge_xs, z, t):
         """One MACE interaction: density projection + symmetric contraction +
         linear update. Rematerialized under grad when cfg.remat (the per-edge
-        per-path tensors dominate activation memory)."""
+        per-path tensors dominate activation memory). ``edge_xs`` is
+        energy_fn's chunk-ordered ``(src, dst, mask, Y, bessel)``, each
+        ``(K, chunk, ...)``."""
         cfg = self.cfg
         C = cfg.channels
-        dtype = bessel.dtype
+        chunk = edge_xs[0].shape[1]
+        dtype = edge_xs[4].dtype
         # run the whole interaction in the compute dtype: cast the parameter
         # subtree so mixed-precision promotion can't silently upcast the
         # GEMMs back to fp32 (O(param bytes) per step — negligible next to
@@ -461,26 +488,7 @@ class MACE:
         # density projection A, accumulated over edge chunks (memory-bounded):
         # per chunk, outer(h_src, Y) -> one GEMM over every CG path -> radial
         # weight -> ONE sorted segment sum carrying all Q path components.
-        # chunk_layout aligns chunk boundaries to the interior/frontier
-        # split so every chunk's dst stays sorted (fast-path hint holds)
-        from ..ops.chunk import chunk_layout, chunked, scan_accumulate
-
-        e_cap = lg.edge_src.shape[0]
-        row_idx, row_valid, K, chunk = chunk_layout(
-            e_cap, cfg.edge_chunk,
-            lg.e_split if lg.has_frontier_split else None)
-        take = lambda x: chunked(jnp.asarray(x)[row_idx], K, chunk)
-        with scope("edge_gather"):
-            Y_full = jnp.concatenate(
-                [Y[l] for l in range(cfg.l_max + 1)], axis=-1
-            ).astype(dtype)                               # (E, S_Y)
-            src_ch = take(lg.edge_src)
-            dst_ch = take(lg.edge_dst)
-            mask_ch = chunked(
-                jnp.asarray(lg.edge_mask)[row_idx] & jnp.asarray(row_valid),
-                K, chunk)
-            bes_ch = take(bessel)
-            Y_ch = take(Y_full)
+        from ..ops.chunk import scan_accumulate
 
         Wp3 = Wp.reshape(proj["S_h"], proj["S_Y"], nQ)
 
@@ -511,13 +519,11 @@ class MACE:
                 )
 
         # the scan's own slicing of the chunked rows (and, transposed, the
-        # stacking of their cotangents) continues the gather's data path
+        # stacking of their cotangents) continues the layout's data path
         with scope("edge_gather"):
             A0 = jnp.zeros((n_nodes, nQ, C), dtype=dtype)
-            A_all = scan_accumulate(
-                chunk_body, A0, (src_ch, dst_ch, mask_ch, Y_ch, bes_ch),
-                remat=cfg.remat,
-            )
+            A_all = scan_accumulate(chunk_body, A0, edge_xs,
+                                    remat=cfg.remat)
         # per-path output mixing on nodes (upstream's post-conv_tp linear):
         # A[l] = sum_paths A_all[:, :, cols(path)] @ W_path — (P_l*C) GEMMs
         with scope("node_linear"):

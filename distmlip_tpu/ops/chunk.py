@@ -1,11 +1,12 @@
 """Edge-chunked scan scaffolding shared by the model zoo.
 
 Models bound per-edge memory by scanning over fixed-size edge chunks
-(MACE's density projection, eSCN's rotate/SO(2) pipeline). The padding
-contract here matches ops/segment.py: padded index rows repeat the LAST
-real value so dst stays nondecreasing for the ``indices_are_sorted``
-segment-sum fast path (padding is masked), and padded data rows are
-zero-filled.
+(MACE's density projection, eSCN's rotate/SO(2) pipeline). Per-edge rows
+reach chunk order through ``take_rows``: static slices of the one or two
+dst-sorted edge segments, each padded to a chunk multiple with copies of
+its LAST row, so dst stays nondecreasing for the ``indices_are_sorted``
+segment-sum fast path and gathers through padded index rows stay
+in-bounds. Padded rows are masked out by ``chunk_layout``'s ``row_valid``.
 """
 
 from __future__ import annotations
@@ -15,44 +16,70 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _segments(e_cap: int, chunk: int, e_split: int | None):
+    """``([(a, b, pad), ...], chunk)``: the non-empty edge segments, the pad
+    rows that bring each to a chunk multiple, and the chunk size clamped to
+    the longest segment (``chunk <= 0``: one chunk per segment). No edges:
+    no segments."""
+    if e_split is not None and 0 <= e_split < e_cap:
+        bounds = [(0, e_split), (e_split, e_cap)]
+    else:
+        bounds = [(0, e_cap)]
+    longest = max(b - a for a, b in bounds)
+    chunk = longest if chunk <= 0 else min(chunk, longest)
+    return [(a, b, -(b - a) % chunk) for a, b in bounds if b > a], chunk
+
+
 def chunk_layout(e_cap: int, chunk: int, e_split: int | None = None):
-    """Row-gather chunk layout for edge scans, aligned to the
-    interior/frontier boundary.
+    """Chunk layout for edge scans, aligned to the interior/frontier
+    boundary.
 
     Returns ``(row_index, row_valid, K, chunk)``: build each scan input as
-    ``chunked(x[row_index], K, chunk)`` and AND ``row_valid`` into the edge
-    mask. With an active split (``0 <= e_split < e_cap``) the two segments
-    are padded to chunk multiples INDEPENDENTLY, so no chunk ever straddles
-    the boundary — every chunk's dst rows stay nondecreasing and the
+    ``chunked(take_rows(x, chunk, e_split), K, chunk)`` and AND
+    ``row_valid`` into the edge mask. With an active split
+    (``0 <= e_split < e_cap``) the two segments are padded to chunk
+    multiples INDEPENDENTLY, so no chunk ever straddles the boundary —
+    every chunk's dst rows stay nondecreasing and the
     ``indices_are_sorted=True`` scatter fast path survives the split
     layout (a straddling chunk would silently break the hint). Padding
     rows repeat each segment's last row (sorted, in-bounds, masked out by
-    ``row_valid``). Without a split this is the chunk_spec/pad_index
-    layout expressed as a gather. Cost: at most one extra chunk (plus one
-    chunk of pad rows) versus the unaligned layout.
+    ``row_valid``). ``row_index`` names the source row of every chunk-order
+    row (``take_rows(x, ...)`` equals ``x[row_index]``); the models do not
+    gather through it — it is the layout's specification, for tests and
+    for host-side numpy. Cost: at most one extra chunk (plus one chunk of
+    pad rows) versus the unaligned layout.
     """
     if e_cap == 0:
         return (np.zeros(0, np.int32), np.zeros(0, bool), 1, 0)
-    if e_split is not None and 0 <= e_split < e_cap:
-        segments = [(0, e_split), (e_split, e_cap)]
-    else:
-        segments = [(0, e_cap)]
-    longest = max(b - a for a, b in segments)
-    chunk = longest if chunk <= 0 else min(chunk, longest)
+    segments, chunk = _segments(e_cap, chunk, e_split)
     idx, valid = [], []
-    for a, b in segments:
-        n = b - a
-        if n == 0:
-            continue
-        pad = -(-n // chunk) * chunk - n
-        idx.append(np.arange(a, b, dtype=np.int32))
-        valid.append(np.ones(n, dtype=bool))
-        if pad:
-            idx.append(np.full(pad, b - 1, dtype=np.int32))
-            valid.append(np.zeros(pad, dtype=bool))
+    for a, b, pad in segments:
+        idx += [np.arange(a, b, dtype=np.int32),
+                np.full(pad, b - 1, dtype=np.int32)]
+        valid += [np.ones(b - a, dtype=bool), np.zeros(pad, dtype=bool)]
     row_index = np.concatenate(idx)
     row_valid = np.concatenate(valid)
     return row_index, row_valid, len(row_index) // chunk, chunk
+
+
+def take_rows(x, chunk: int, e_split: int | None = None):
+    """``x[row_index]`` of ``chunk_layout(len(x), chunk, e_split)``, built
+    from static slices: each segment ``x[a:b]`` followed by its last row
+    broadcast over the segment's pad rows, in one concatenate (``x`` itself
+    when nothing is padded). XLA sees contiguous copies, and the transpose
+    is slices, one sum over at most ``chunk - 1`` pad rows per segment and
+    an add into the segment's last row — where a gather through
+    ``row_index`` transposes to a scatter-add over every row."""
+    x = jnp.asarray(x)
+    segments, _ = _segments(x.shape[0], chunk, e_split)
+    if not any(pad for _, _, pad in segments):
+        return x
+    parts = []
+    for a, b, pad in segments:
+        parts.append(x[a:b])
+        if pad:
+            parts.append(jnp.broadcast_to(x[b - 1:b], (pad,) + x.shape[1:]))
+    return jnp.concatenate(parts)
 
 
 def chunk_spec(e_cap: int, chunk: int):
@@ -67,22 +94,6 @@ def chunk_spec(e_cap: int, chunk: int):
     return K, chunk, K * chunk - e_cap
 
 
-def pad_rows(x, pad: int, fill=0):
-    """Pad ``pad`` rows of ``fill`` onto axis 0."""
-    if pad == 0:
-        return x
-    widths = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(x, widths, constant_values=fill)
-
-
-def pad_index(x, pad: int):
-    """Pad axis 0 by repeating the last element (keeps sorted indices
-    sorted and eager gathers in-bounds; padded rows must be masked)."""
-    if pad == 0:
-        return x
-    return jnp.concatenate([x, jnp.broadcast_to(x[-1], (pad,))])
-
-
 def chunked(x, K: int, chunk: int):
     """(K*chunk, ...) -> (K, chunk, ...) for lax.scan."""
     return x.reshape((K, chunk) + x.shape[1:])
@@ -92,14 +103,13 @@ def remat_wrap(body, remat):
     """Apply the requested rematerialization mode to a scan body.
 
     ``remat`` is False (save everything), True (full checkpoint: recompute
-    the whole chunk in the backward — minimal memory, ~2x backward FLOPs),
-    or the name of a jax checkpoint policy — most usefully ``"dots"``
-    (``dots_with_no_batch_dims_saveable``: keep GEMM outputs resident,
-    recompute only the cheap elementwise/gather glue; backward stops
-    re-running the MXU work that dominates the step, for a bounded
-    activation-memory increase). The policy axis is a measurement knob for
-    the round-3 finding that the remat backward is ~3x the forward
-    (ROADMAP.md): tools/tune_mace.py sweeps it on chip.
+    the whole chunk in the backward — minimal memory, the chunk's forward
+    runs again), or the name of a jax checkpoint policy — most usefully
+    ``"dots"`` (``dots_with_no_batch_dims_saveable``: keep GEMM outputs
+    resident, recompute only the cheap elementwise/gather glue, for a
+    bounded activation-memory increase). The benchmark's MACE cells run
+    ``remat=True``; what the recompute costs there is the ``recompute``
+    pass of the stage tables (PERF.md section 5, ROADMAP S5).
     """
     if remat is False:
         return body

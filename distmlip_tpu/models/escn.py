@@ -50,6 +50,7 @@ from ..kernels.dispatch import fused_segment_sum, fused_so2_conv
 from ..ops import radial
 from ..ops.nn import cast_params_subtrees, linear, linear_init, mlp, mlp_init
 from ..ops.so3_e3nn import CoeffLayout, wigner_blocks_from_edges
+from ..telemetry import scope
 
 
 @dataclass(frozen=True)
@@ -219,20 +220,25 @@ class ESCN:
         e_split = lg.e_split if lg.has_frontier_split else None
         _, row_valid, K, chunk = chunk_layout(e_cap, cfg.edge_chunk, e_split)
         take = lambda x: chunked(take_rows(x, chunk, e_split), K, chunk)
-        edge_xs = (
-            take(lg.edge_src),
-            take(lg.edge_dst),
-            take(lg.edge_mask) & chunked(jnp.asarray(row_valid), K, chunk),
-            take(rhat),
-            take(bessel),
-            take(env),
-        )
+        # stage scopes (telemetry/stages.py) on the scaffolding this model
+        # shares with models/escn_md.py, and only there
+        with scope("edge_gather"):
+            edge_xs = (
+                take(lg.edge_src),
+                take(lg.edge_dst),
+                take(lg.edge_mask)
+                & chunked(jnp.asarray(row_valid), K, chunk),
+                take(rhat),
+                take(bessel),
+                take(env),
+            )
         # single-chunk path: build D once (fp32) and share it across the
         # edge-degree pass and every layer instead of per edge_scan call
-        D_shared = (
-            wigner_blocks_from_edges(cfg.l_max, edge_xs[3][0])
-            if K == 1 else None
-        )
+        with scope("edge_rotation"):
+            D_shared = (
+                wigner_blocks_from_edges(cfg.l_max, edge_xs[3][0])
+                if K == 1 else None
+            )
 
         def edge_scan(per_chunk, out_shape):
             """Accumulate sum_chunks per_chunk(...) over the edge chunks.
@@ -242,25 +248,29 @@ class ESCN:
 
             def body(acc, xs):
                 srcc, dstc, maskc, rhatc, besc, envc = xs
-                D = (
-                    D_shared
-                    if D_shared is not None
-                    else wigner_blocks_from_edges(cfg.l_max, rhatc)
-                )
+                with scope("edge_rotation"):
+                    D = (
+                        D_shared
+                        if D_shared is not None
+                        else wigner_blocks_from_edges(cfg.l_max, rhatc)
+                    )
                 msg = per_chunk(srcc, dstc, maskc, D, besc, envc)
-                return (
-                    acc
-                    + fused_segment_sum(
-                        # sorted within every chunk by chunk_layout;
-                        # Pallas dst-tiled scatter on TPU (kernels/dispatch)
-                        msg, dstc, lg.n_cap, maskc, indices_are_sorted=True,
-                        kernels=lg.kernels,
-                    ),
-                    None,
-                )
+                with scope("edge_aggregate"):
+                    return (
+                        acc
+                        + fused_segment_sum(
+                            # sorted within every chunk by chunk_layout;
+                            # Pallas dst-tiled scatter on TPU
+                            # (kernels/dispatch)
+                            msg, dstc, lg.n_cap, maskc,
+                            indices_are_sorted=True, kernels=lg.kernels,
+                        ),
+                        None,
+                    )
 
-            acc0 = jnp.zeros((lg.n_cap,) + out_shape, dtype=dtype)
-            return scan_accumulate(body, acc0, edge_xs, remat=cfg.remat)
+            with scope("edge_gather"):
+                acc0 = jnp.zeros((lg.n_cap,) + out_shape, dtype=dtype)
+                return scan_accumulate(body, acc0, edge_xs, remat=cfg.remat)
 
         # device array: the chunked scan indexes z with traced chunk indices,
         # which a host numpy species array cannot support

@@ -21,13 +21,22 @@ implementations/uma/escn_md.py):
   target species emb) feeding both the edge-degree embedding and the
   SO(2) radial scaling (escn_md.py:221-247);
 - MOLE: SO(2) weights as per-system convex expert mixtures, coefficients
-  replicated/psum-consistent across partitions (escn_md.py:343-357).
+  replicated/psum-consistent across partitions (escn_md.py:343-357). The
+  expert axis is collapsed ONCE a step, before the first edge scan
+  (``_merge_experts``): a system is served by one merged model.
 
 Internals fairchem does NOT expose through the wrapper (block wiring,
-norm/activation/FFN details, RadialFunction shape) are reconstructed from
-the public equiformer_v2/eSCN lineage and documented inline; every such
-choice is mirrored exactly by the float64 torch oracle in
-tests/test_convert_escn.py, which is the converter's golden contract.
+norm/activation/FFN details, RadialFunction shape) are RECONSTRUCTIONS from
+the public equiformer_v2/eSCN lineage, documented inline; every such choice
+is mirrored exactly by the float64 torch oracle in
+tests/test_convert_escn.py, which is the converter's golden contract, and
+listed under ``assumed`` in ``benchmark/configs/uma-s-1.json``, the
+configuration the benchmark runs: the feed-forward is the SPECTRAL form (a
+linear map per degree, gate activation, a linear map per degree) where
+``uma-s-1`` is believed to set ``ff_type: grid``; ``RadialFunction`` is
+Linear, LayerNorm, SiLU, Linear at ``edge_channels``; no neighbour cap
+(``max_neighbors``) is applied. ``ESCNMDConfig``'s defaults are the tests'
+sizes, not a published model's; the published widths live in that file.
 Layout is channels-LAST (C in the TPU lane axis) per the round-3 finding.
 """
 
@@ -40,10 +49,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..geometry import COORD_PRECISION
 from ..ops import radial
 from ..ops.nn import cast_params_subtrees
 from ..kernels.dispatch import fused_segment_sum
 from ..ops.so3_e3nn import CoeffLayout, wigner_blocks_from_edges
+from ..telemetry import scope
 
 
 @dataclass(frozen=True)
@@ -93,8 +104,8 @@ def _linear_init(key, d_in, d_out, bias=True):
     return p
 
 
-def _linear(p, x):
-    y = x @ p["w"].T
+def _linear(p, x, precision=None):
+    y = jnp.matmul(x, p["w"].T, precision=precision)
     if "b" in p:
         y = y + p["b"]
     return y
@@ -231,21 +242,17 @@ class ESCNMD:
                             axis=0)
         return x * w_full
 
-    def _so2_mix(self, W, mole):
-        """Collapse the expert axis with the per-system MOLE coefficients."""
-        if self.cfg.num_experts > 1:
-            return jnp.einsum("k,kab->ab", mole.astype(W.dtype), W)
-        return W
-
-    def _so2_conv(self, p, fr, rad_scale, mole, c_in, c_out, extra_m0):
+    def _so2_conv(self, p, fr, rad_scale, c_in, c_out, extra_m0):
         """SO(2) convolution on edge-frame features fr (E_c, S_nar, c_in).
 
         Per |m|, the (l >= m) coefficients flatten l-major to (nl * c_in)
         and pass through one linear map; m > 0 uses the (W_r, W_i) complex
         pair structure y+ = W_r f+ - W_i f-, y- = W_r f- + W_i f+ (the
         fairchem SO2_m_Convolution packing: fc output = [real | imag]
-        halves). ``rad_scale``: optional per-coefficient input scaling from
-        the radial function, same scale for the +m and -m partners."""
+        halves). ``p["m<k>"]`` are plain ``(out, in)`` matrices: the expert
+        axis is collapsed once a step (``_merge_experts``). ``rad_scale``:
+        optional per-coefficient input scaling from the radial function,
+        same scale for the +m and -m partners."""
         lay = self.lay
         E = fr.shape[0]
         y = jnp.zeros((E, lay.size, c_out), dtype=fr.dtype)
@@ -257,8 +264,7 @@ class ESCNMD:
                 f0 = fr[:, lay.plus_idx[0], :].reshape(E, nl * c_in)
                 if rad_scale is not None:
                     f0 = f0 * rad_scale[:, off:off + nl * c_in]
-                W0 = self._so2_mix(p["m0"], mole)
-                out0 = f0 @ W0.T + p["m0_b"].astype(fr.dtype)
+                out0 = f0 @ p["m0"].T + p["m0_b"].astype(fr.dtype)
                 main, extra = (out0[:, :nl * c_out], out0[:, nl * c_out:])
                 y = y.at[:, lay.plus_idx[0], :].set(
                     main.reshape(E, nl, c_out))
@@ -268,7 +274,7 @@ class ESCNMD:
                 if rad_scale is not None:
                     s = rad_scale[:, off:off + nl * c_in]
                     fp, fm = fp * s, fm * s
-                W = self._so2_mix(p[f"m{m}"], mole)
+                W = p[f"m{m}"]
                 d_out = nl * c_out
                 Wr, Wi = W[:d_out], W[d_out:]
                 yp = fp @ Wr.T - fm @ Wi.T
@@ -310,57 +316,57 @@ class ESCNMD:
         reps = np.array([2 * l + 1 for l in range(self.cfg.lmax + 1)])
         return jnp.repeat(w.astype(dtype), reps, axis=0)
 
+    def _merge_experts(self, params, mole):
+        """The expert axis of every SO(2) weight collapsed by the system's
+        MOLE coefficients, in float32: the blocks again, each ``m<k>`` a
+        plain ``(out, in)`` matrix. Once a step; the edge scans see one
+        merged model, never the experts."""
+        merged = []
+        for blk in params["blocks"]:
+            blk = dict(blk)
+            for conv in ("so2_1", "so2_2"):
+                blk[conv] = {
+                    # a weighted sum on the vector unit, exact in float32
+                    # (a float32 matmul is one bfloat16 pass on a TPU)
+                    k: (jnp.sum(mole[:, None, None] * w, axis=0)
+                        if k[0] == "m" and k[1:].isdigit() else w)
+                    for k, w in blk[conv].items()}
+            merged.append(blk)
+        return {**params, "blocks": merged}
+
     # ---- forward ---------------------------------------------------------
     def energy_fn(self, params, lg, positions):
+        """Which precision runs where (``cfg.dtype = "bfloat16"``): edge
+        geometry, Wigner blocks (cast to bfloat16 at every use), the MOLE
+        gate with its softmax and the expert collapse, the energy head and
+        ``species_ref`` stay float32; rotations (the blocks cast per use),
+        radial functions, SO(2) convolutions, gate activation, norms and
+        feed-forward run in the compute dtype. Every line sits in a stage
+        scope (telemetry/stages.py)."""
         cfg, lay = self.cfg, self.lay
         C, H, S = cfg.sphere_channels, cfg.hidden_channels, cfg.sphere_dim
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else positions.dtype
-        if cfg.dtype == "bfloat16":
-            params = cast_params_subtrees(
-                params, dtype, keep_fp32=("species_ref", "energy_head"))
-
-        # fairchem's edge vector points src -> ... pos[src] - pos[dst]
-        # (reference compute.py:169-173); lg.edge_vectors is dst - src
-        vec = -lg.edge_vectors(positions)
-        d = jnp.linalg.norm(jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
-        # masked (padding) edges get a fixed safe direction: their rhat is
-        # (0,0,0), and atan2's gradient at the origin is NaN — which would
-        # poison the whole force array through the 0-weighted messages
-        safe = jnp.asarray([0.0, 0.0, 1.0], dtype=positions.dtype)
-        rhat = jnp.where(lg.edge_mask[:, None],
-                         vec / jnp.maximum(d, 1e-9)[:, None], safe)
-        env = (
-            radial.polynomial_cutoff(d, cfg.cutoff) * lg.edge_mask
-            if cfg.use_envelope else lg.edge_mask.astype(positions.dtype)
-        ).astype(dtype)
-        # gaussian smearing over [0, cutoff]; sigma = basis_width_scalar x
-        # center spacing (fairchem GaussianSmearing convention)
-        centers = jnp.linspace(0.0, cfg.cutoff, cfg.num_distance_basis)
-        width = (cfg.basis_width_scalar * cfg.cutoff
-                 / (cfg.num_distance_basis - 1))
-        gauss = jnp.exp(-0.5 * ((d[:, None] - centers) / width) ** 2
-                        ).astype(dtype)
-
         z = jnp.asarray(lg.species)
-        zemb = params["sphere_embedding"]["w"][z].astype(dtype)
 
-        # csd (charge/spin/dataset) system embedding
-        sys_state = lg.system or {}
-        qi = jnp.clip(jnp.asarray(sys_state.get("charge", 0)) - cfg.charge_min,
-                      0, cfg.num_charges - 1)
-        si = jnp.clip(jnp.asarray(sys_state.get("spin", 0)), 0, cfg.num_spins - 1)
-        di = jnp.clip(jnp.asarray(sys_state.get("dataset", 0)), 0,
-                      cfg.num_datasets - 1)
-        csd = _linear(params["csd"]["mix"], jnp.concatenate([
-            params["csd"]["charge"]["w"][qi],
-            params["csd"]["spin"]["w"][si],
-            params["csd"]["dataset"]["w"][di],
-        ], axis=-1).astype(dtype))  # (C,)
+        with scope("node_linear"):
+            # csd (charge/spin/dataset) system embedding, in the weights'
+            # own precision: it feeds the gate as well as the node scalars
+            sys_state = lg.system or {}
+            qi = jnp.clip(
+                jnp.asarray(sys_state.get("charge", 0)) - cfg.charge_min,
+                0, cfg.num_charges - 1)
+            si = jnp.clip(jnp.asarray(sys_state.get("spin", 0)), 0,
+                          cfg.num_spins - 1)
+            di = jnp.clip(jnp.asarray(sys_state.get("dataset", 0)), 0,
+                          cfg.num_datasets - 1)
+            csd = _linear(params["csd"]["mix"], jnp.concatenate([
+                params["csd"]["charge"]["w"][qi],
+                params["csd"]["spin"]["w"][si],
+                params["csd"]["dataset"]["w"][di],
+            ], axis=-1), precision="highest")  # (C,)
 
-        h = jnp.zeros((positions.shape[0], S, C), dtype=dtype)
-        h = h.at[:, 0, :].set(zemb + csd[None, :])
-
-        # MOLE coefficients: psum-consistent composition + csd gate
+        # MOLE: psum-consistent composition + csd gate, then ONE merged set
+        # of SO(2) weights for the step, before the first edge scan
         if cfg.num_experts > 1:
             if lg.struct_id is not None and lg.batch_size > 0:
                 # the composition pool below spans the WHOLE graph — on a
@@ -372,14 +378,55 @@ class ESCNMD:
                     "batched (packed) graphs would mix structures. Use "
                     "models.escn.ESCN for batched inference, or "
                     "num_experts=1.")
-            owned = lg.owned_mask.astype(dtype)[:, None]
-            comp = lg.psum(jnp.sum(zemb * owned, axis=0))
-            count = lg.psum(jnp.sum(owned))
-            gate_in = jnp.concatenate([comp / jnp.maximum(count, 1.0), csd])
-            g = jax.nn.silu(_linear(params["mole_gate"]["lin1"], gate_in))
-            mole = jax.nn.softmax(_linear(params["mole_gate"]["lin2"], g))
-        else:
-            mole = None
+            with scope("expert_mix"):
+                zemb_g = params["sphere_embedding"]["w"][z]
+                owned = lg.owned_mask.astype(zemb_g.dtype)[:, None]
+                comp = lg.psum(jnp.sum(zemb_g * owned, axis=0))
+                count = lg.psum(jnp.sum(owned))
+                gate_in = jnp.concatenate(
+                    [comp / jnp.maximum(count, 1.0), csd])
+                g = jax.nn.silu(_linear(params["mole_gate"]["lin1"], gate_in,
+                                        precision="highest"))
+                mole = jax.nn.softmax(_linear(params["mole_gate"]["lin2"], g,
+                                              precision="highest"))
+                params = self._merge_experts(params, mole)
+
+        if cfg.dtype == "bfloat16":
+            with scope("node_linear"):
+                # after the collapse: the experts are read once, in float32
+                params = cast_params_subtrees(
+                    params, dtype,
+                    keep_fp32=("species_ref", "energy_head", "mole_gate"))
+        with scope("edge_geometry"):
+            # fairchem's edge vector points src -> ... pos[src] - pos[dst]
+            # (reference compute.py:169-173); lg.edge_vectors is dst - src
+            vec = -lg.edge_vectors(positions)
+            d = jnp.linalg.norm(
+                jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
+            # masked (padding) edges get a fixed safe direction: their rhat
+            # is (0,0,0), and atan2's gradient at the origin is NaN — which
+            # would poison the whole force array through the 0-weighted
+            # messages
+            safe = jnp.asarray([0.0, 0.0, 1.0], dtype=positions.dtype)
+            rhat = jnp.where(lg.edge_mask[:, None],
+                             vec / jnp.maximum(d, 1e-9)[:, None], safe)
+            env = (
+                radial.polynomial_cutoff(d, cfg.cutoff) * lg.edge_mask
+                if cfg.use_envelope else lg.edge_mask.astype(positions.dtype)
+            ).astype(dtype)
+            # gaussian smearing over [0, cutoff]; sigma = basis_width_scalar
+            # x center spacing (fairchem GaussianSmearing convention)
+            centers = jnp.linspace(0.0, cfg.cutoff, cfg.num_distance_basis)
+            width = (cfg.basis_width_scalar * cfg.cutoff
+                     / (cfg.num_distance_basis - 1))
+            gauss = jnp.exp(-0.5 * ((d[:, None] - centers) / width) ** 2
+                            ).astype(dtype)
+
+        with scope("node_linear"):
+            csd = csd.astype(dtype)
+            zemb = params["sphere_embedding"]["w"][z].astype(dtype)
+            h = jnp.zeros((positions.shape[0], S, C), dtype=dtype)
+            h = h.at[:, 0, :].set(zemb + csd[None, :])
 
         # --- edge-chunked scan scaffolding (shared with models/escn.py);
         # chunk_layout keeps every chunk inside one dst-sorted edge segment
@@ -390,18 +437,23 @@ class ESCNMD:
         e_split = lg.e_split if lg.has_frontier_split else None
         _, row_valid, K_ch, chunk = chunk_layout(e_cap, cfg.edge_chunk, e_split)
         take = lambda x: chunked(take_rows(x, chunk, e_split), K_ch, chunk)
-        edge_xs = (
-            take(lg.edge_src),
-            take(lg.edge_dst),
-            take(lg.edge_mask) & chunked(jnp.asarray(row_valid), K_ch, chunk),
-            take(rhat),
-            take(gauss),
-            take(env),
-        )
+        with scope("edge_gather"):
+            edge_xs = (
+                take(lg.edge_src),
+                take(lg.edge_dst),
+                take(lg.edge_mask)
+                & chunked(jnp.asarray(row_valid), K_ch, chunk),
+                take(rhat),
+                take(gauss),
+                take(env),
+            )
 
         # per-l lab-from-edge blocks; ops/so3_e3nn builds them at >= fp32
-        # with pole-safe angles, downcast per-use in rotate_in/rotate_out
-        wigner_blocks = partial(wigner_blocks_from_edges, cfg.lmax)
+        # with pole-safe angles, downcast per-use in rotate_in/rotate_out:
+        # COORD_PRECISION products only where float32 blocks are used
+        wigner_blocks = partial(
+            wigner_blocks_from_edges, cfg.lmax,
+            precision=None if dtype == jnp.bfloat16 else COORD_PRECISION)
 
         def rotate_in(hvecs, D):
             """Lab (E_c, S_full, c) -> edge frame (E_c, S_nar, c): transpose
@@ -428,71 +480,90 @@ class ESCNMD:
         def edge_scan(per_chunk, out_shape):
             def body(acc, xs):
                 srcc, dstc, maskc, rhatc, gaussc, envc = xs
-                D = wigner_blocks(rhatc)
+                with scope("edge_rotation"):
+                    D = wigner_blocks(rhatc)
                 msg = per_chunk(srcc, dstc, maskc, D, gaussc, envc)
-                return (
-                    acc + fused_segment_sum(
-                        # sorted within every chunk by chunk_layout;
-                        # Pallas dst-tiled scatter on TPU (kernels/dispatch)
-                        msg, dstc, lg.n_cap, maskc,
-                        indices_are_sorted=True, kernels=lg.kernels),
-                    None,
-                )
+                with scope("edge_aggregate"):
+                    return (
+                        acc + fused_segment_sum(
+                            # sorted within every chunk by chunk_layout;
+                            # Pallas dst-tiled scatter on TPU
+                            # (kernels/dispatch)
+                            msg, dstc, lg.n_cap, maskc,
+                            indices_are_sorted=True, kernels=lg.kernels),
+                        None,
+                    )
 
-            acc0 = jnp.zeros((lg.n_cap,) + out_shape, dtype=dtype)
-            return scan_accumulate(body, acc0, edge_xs, remat=cfg.remat)
+            with scope("edge_gather"):
+                acc0 = jnp.zeros((lg.n_cap,) + out_shape, dtype=dtype)
+                return scan_accumulate(body, acc0, edge_xs, remat=cfg.remat)
 
-        def edge_scalars(srcc, dstc, gaussc):
-            return jnp.concatenate([
-                gaussc,
-                params["source_embedding"]["w"][z[srcc]].astype(dtype),
-                params["target_embedding"]["w"][z[dstc]].astype(dtype),
-            ], axis=-1)
+        def radial_of(p, srcc, dstc, gaussc):
+            """Radial function of [gaussians | source | target species]."""
+            with scope("radial_mlp"):
+                return _rad_apply(p, jnp.concatenate([
+                    gaussc,
+                    params["source_embedding"]["w"][z[srcc]].astype(dtype),
+                    params["target_embedding"]["w"][z[dstc]].astype(dtype),
+                ], axis=-1))
 
         # --- edge-degree embedding (escn_md.py:221-247): radial weights
         # placed in the edge frame's m=0 slots, rotated to the lab frame,
         # degree-summed onto the receiver, / avg_degree
         def deg_chunk(srcc, dstc, maskc, D, gaussc, envc):
-            w = _rad_apply(params["edge_deg_rad"], edge_scalars(srcc, dstc, gaussc))
-            w = w.reshape(-1, cfg.lmax + 1, C)
-            y = jnp.zeros((w.shape[0], lay.size, C), dtype=dtype)
-            y = y.at[:, lay.plus_idx[0], :].set(w)
-            return rotate_out(y, D) * env_mult(envc)
-
-        def env_mult(envc):
-            return envc[:, None, None]
+            w = radial_of(params["edge_deg_rad"], srcc, dstc, gaussc)
+            with scope("edge_message"):
+                w = w.reshape(-1, cfg.lmax + 1, C)
+                y = jnp.zeros((w.shape[0], lay.size, C), dtype=dtype)
+                y = y.at[:, lay.plus_idx[0], :].set(w)
+            with scope("edge_rotation"):
+                return rotate_out(y, D) * envc[:, None, None]
 
         inv_deg = jnp.asarray(1.0 / cfg.avg_degree, dtype=dtype)
-        h = h + edge_scan(deg_chunk, (S, C)) * inv_deg
-        h = lg.halo_exchange(h)
-
-        for blk in params["blocks"]:
-
-            def so2_chunk(srcc, dstc, maskc, D, gaussc, envc, blk=blk):
-                xe = edge_scalars(srcc, dstc, gaussc)
-                rad = _rad_apply(blk["so2_1"]["rad"], xe)  # per-coeff scales
-                xn_src = hn[srcc]
-                xn_dst = hn[dstc]
-                fr = jnp.concatenate([
-                    rotate_in(xn_src, D), rotate_in(xn_dst, D)], axis=-1)
-                y, gates = self._so2_conv(
-                    blk["so2_1"], fr, rad, mole, 2 * C, H, cfg.lmax * H)
-                y = self._gate_act(y, gates)
-                y = self._so2_conv(blk["so2_2"], y, None, mole, H, C, 0)
-                return rotate_out(y, D) * env_mult(envc)
-
-            # message path reads the NORMALIZED features (with the system
-            # embedding re-injected into the scalars); residual keeps h
-            hn = self._rms_norm_sh(blk["norm1"]["w"], h)
-            hn = hn.at[:, 0, :].add(csd[None, :])
-            h = h + edge_scan(so2_chunk, (S, C)) * inv_deg
-            # FFN with pre-norm and residual
-            h = h + self._ffn(blk["ff"], self._rms_norm_sh(blk["ff_norm"]["w"], h))
+        with scope("embedding"):
+            deg = edge_scan(deg_chunk, (S, C))
+            with scope("node_linear"):
+                h = h + deg * inv_deg
             h = lg.halo_exchange(h)
 
-        h = self._rms_norm_sh(params["norm"]["w"], h)
-        s = h[:, 0, :]
-        e = _linear(params["energy_head"]["lin2"],
-                    jax.nn.silu(_linear(params["energy_head"]["lin1"],
-                                        s.astype(positions.dtype))))[:, 0]
-        return e + params["species_ref"]["w"][z].astype(positions.dtype)
+        for t, blk in enumerate(params["blocks"]):
+
+            def so2_chunk(srcc, dstc, maskc, D, gaussc, envc, blk=blk):
+                # per-coefficient scales
+                rad = radial_of(blk["so2_1"]["rad"], srcc, dstc, gaussc)
+                with scope("edge_message"):
+                    xn_src = hn[srcc]
+                    xn_dst = hn[dstc]
+                with scope("edge_rotation"):
+                    fr = jnp.concatenate([
+                        rotate_in(xn_src, D), rotate_in(xn_dst, D)], axis=-1)
+                with scope("edge_message"):
+                    y, gates = self._so2_conv(
+                        blk["so2_1"], fr, rad, 2 * C, H, cfg.lmax * H)
+                    y = self._gate_act(y, gates)
+                    y = self._so2_conv(blk["so2_2"], y, None, H, C, 0)
+                with scope("edge_rotation"):
+                    return rotate_out(y, D) * envc[:, None, None]
+
+            with scope(f"layer{t}"):
+                # message path reads the NORMALIZED features (with the
+                # system embedding re-injected into the scalars); residual
+                # keeps h
+                with scope("node_tensor"):
+                    hn = self._rms_norm_sh(blk["norm1"]["w"], h)
+                    hn = hn.at[:, 0, :].add(csd[None, :])
+                msg = edge_scan(so2_chunk, (S, C))
+                with scope("node_tensor"):
+                    h = h + msg * inv_deg
+                    # FFN with pre-norm and residual
+                    h = h + self._ffn(
+                        blk["ff"], self._rms_norm_sh(blk["ff_norm"]["w"], h))
+                h = lg.halo_exchange(h)
+
+        with scope("readout"):
+            h = self._rms_norm_sh(params["norm"]["w"], h)
+            s = h[:, 0, :]
+            e = _linear(params["energy_head"]["lin2"],
+                        jax.nn.silu(_linear(params["energy_head"]["lin1"],
+                                            s.astype(positions.dtype))))[:, 0]
+            return e + params["species_ref"]["w"][z].astype(positions.dtype)

@@ -34,6 +34,7 @@ import functools
 import jax.numpy as jnp
 import numpy as np
 
+from ..geometry import COORD_PRECISION
 from .so3 import _sh_general
 
 
@@ -122,7 +123,8 @@ def edge_angles(rhat, eps: float = 1e-4):
     return alpha, beta
 
 
-def wigner_blocks_from_edges(l_max: int, rhat, gamma=None):
+def wigner_blocks_from_edges(l_max: int, rhat, gamma=None,
+                             precision=COORD_PRECISION):
     """Per-l lab-from-edge Wigner blocks for a batch of edge directions.
 
     Returns ``[D_0, ..., D_lmax]`` with ``D_l``: (E, 2l+1, 2l+1) in the
@@ -139,6 +141,15 @@ def wigner_blocks_from_edges(l_max: int, rhat, gamma=None):
     tests/test_escn_md.py proves output invariance under random per-edge
     gamma AND under the construction-derived gamma of a fairchem-style
     edge frame, so the gamma=0 choice is certified, not assumed.
+
+    ``precision`` is the matmul precision of the products that build a
+    block: ``geometry.COORD_PRECISION`` unless the caller says otherwise. A
+    float32 matmul is one bfloat16 pass on a TPU by default, and a block
+    good to three digits is no rotation. A caller that casts the blocks to
+    bfloat16 at every use loses those digits there anyway and may pass
+    ``None``: at ``highest`` the hundred 5 x 5 products of an eSCN-MD step,
+    batched over 32,768 edges, cost 3.2 % of it on a TPU v5e and moved the
+    forces' error by nothing (PERF.md section 6, PR 28).
     """
     wdt = jnp.promote_types(rhat.dtype, jnp.float32)  # never bf16: the trig
     alpha, beta = edge_angles(rhat.astype(wdt))       # chains compound
@@ -147,10 +158,11 @@ def wigner_blocks_from_edges(l_max: int, rhat, gamma=None):
         J = jnp.asarray(jd_np(l), dtype=wdt)
         Xa = _z_rot_jnp(l, alpha)
         Xb = _z_rot_jnp(l, beta)
-        D = jnp.einsum("epq,qr,ers,st->ept", Xa, J, Xb, J)
+        D = jnp.einsum("epq,qr,ers,st->ept", Xa, J, Xb, J,
+                       precision=precision)
         if gamma is not None:
             Xg = _z_rot_jnp(l, jnp.asarray(gamma, dtype=wdt))
-            D = jnp.einsum("ept,etu->epu", D, Xg)
+            D = jnp.einsum("ept,etu->epu", D, Xg, precision=precision)
         out.append(D)
     return out
 

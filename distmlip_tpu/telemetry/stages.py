@@ -21,7 +21,9 @@ STAGES = (
     "edge_gather",      # per-edge rows into chunk order, and the scan's slices
     "radial_mlp",       # radial functions through their MLP / linears
     "edge_message",     # per-edge tensor products, incl. the src-row gather
+    "edge_rotation",    # Wigner blocks; features into and out of the edge frame
     "edge_aggregate",   # segment sum onto dst, and the accumulate around it
+    "expert_mix",       # per-system gate, softmax, expert weights merged
     "node_linear",      # channel-mixing linears on nodes
     "node_tensor",      # symmetric contraction / rank-2 node products
     "readout",          # per-atom energies, scale and shift
@@ -65,6 +67,7 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _FUSED = re.compile(r"\bcalls=%?([\w.\-]+)")
+_LOOP = re.compile(r"\b(?:body|condition)=%?([\w.\-]+)")
 _TARGET = re.compile(r'custom_call_target="[^"]*"')
 _OPERAND = re.compile(r"%([\w.\-]+)")
 # never a device event of their own
@@ -94,34 +97,44 @@ def stage_table(hlo_text: str) -> list[dict]:
     the root's metadata to the fusion); where the root carries none and the
     fused instructions agree on one stage, that one. An instruction with no
     metadata at all is the compiler's own (a copy into another layout, an
-    asynchronous copy or slice, their concatenation): it takes stage and
-    pass of the instruction that made its first operand, and says so
+    asynchronous copy or slice, their concatenation, an update written into
+    a buffer a loop carries): it takes stage and pass of the instruction
+    that made its first operand, or of the first operand that has a stage
+    where the first has none; failing that, of the ``while`` whose body or
+    condition it sits in (a scatter over a few indices becomes a loop of
+    whole-array updates whose body has no metadata); and says so
     (``inherited``)."""
     computations: dict[str, list] = {}  # name -> [(head, opcode, ...)]
     fused_bodies = set()
-    body = None
+    loops = {}  # a while's body or condition -> (its op_name, where it is)
+    body = name = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m is None:
             c = _COMPUTATION.match(line)
             if c is not None:
-                body = computations.setdefault(c.group(1), [])
+                name = c.group(1)
+                body = computations.setdefault(name, [])
             continue
         if body is None:
             continue
         result, tail = _split_type(line[m.end():])
         opcode = tail.partition("(")[0].strip()
         op = _OP_NAME.search(line)
+        if opcode == "while":
+            for loop in _LOOP.findall(line):
+                loops[loop] = (op.group(1) if op else "", name)
         called = _FUSED.search(line) if opcode == "fusion" else None
         if called is not None:
             fused_bodies.add(called.group(1))
         target = _TARGET.search(line) if opcode == "custom-call" else None
         head = f"%{m.group(1)} = {result} {opcode}"
-        first = _OPERAND.search(tail.partition("(")[2])
+        operands = _OPERAND.findall(
+            tail.partition("(")[2].partition(")")[0])
         body.append((head + (" " + target.group(0) if target else ""),
                      opcode, op.group(1) if op else "",
                      called.group(1) if called else None,
-                     m.group(1), first.group(1) if first else None))
+                     m.group(1), operands))
 
     def fused_stages(name: str, seen: frozenset) -> Counter:
         found: Counter = Counter()
@@ -133,12 +146,25 @@ def stage_table(hlo_text: str) -> list[dict]:
                 found += fused_stages(called, seen | {called})
         return found
 
+    def loop_label(name: str):
+        """(stage, pass) of the nearest enclosing ``while`` that has a
+        stage: the compiler expands a scatter into a loop whose body
+        carries no metadata, the loop itself does."""
+        seen = set()
+        while name in loops and name not in seen:
+            seen.add(name)
+            op_name, name = loops[name]
+            stage = stage_of(op_name)
+            if stage is not None:
+                return stage, pass_of(op_name)
+        return None
+
     rows = []
     for name, instructions in computations.items():
         if name in fused_bodies:
             continue
         made = {}  # instruction name -> its row, in the computation's order
-        for head, opcode, op_name, called, own, operand in instructions:
+        for head, opcode, op_name, called, own, operands in instructions:
             row = {"head": head, "stage": stage_of(op_name),
                    "pass": pass_of(op_name)}
             if called is not None:
@@ -146,10 +172,20 @@ def stage_table(hlo_text: str) -> list[dict]:
                 row["stages"] = sorted(inside)
                 if row["stage"] is None and len(inside) == 1:
                     row["stage"] = row["stages"][0]
-            source = made.get(operand)
-            if not op_name and called is None and source is not None:
-                row.update(stage=source["stage"], inherited=True)
-                row["pass"] = source["pass"]
+            if not op_name and called is None:
+                sources = [made[o] for o in operands if o in made]
+                # the first operand that has a stage (an update written
+                # into a carried buffer has it second), else the first
+                source = next((r for r in sources if r["stage"]),
+                              sources[0] if sources else None)
+                if source is not None:
+                    row.update(stage=source["stage"], inherited=True)
+                    row["pass"] = source["pass"]
+            if not op_name and row["stage"] is None:
+                label = loop_label(name)
+                if label is not None:
+                    row.update(stage=label[0], inherited=True)
+                    row["pass"] = label[1]
             made[own] = row
             if opcode not in _SILENT:
                 rows.append(row)

@@ -242,61 +242,85 @@ class ESCNMD:
                             axis=0)
         return x * w_full
 
-    def _so2_conv(self, p, fr, rad_scale, c_in, c_out, extra_m0):
-        """SO(2) convolution on edge-frame features fr (E_c, S_nar, c_in).
+    # Between ``_rotate_in`` and ``_rotate_out`` the edge-frame coefficients
+    # travel as PIECES: a dict ``{m: (E_c, nl_m * c)}`` over the signed m of
+    # ``lay.signed_ms`` (0, +1, -1, ..), each the l = |m|..lmax coefficients
+    # of that m side by side on the lane axis, l-major, c channels a degree
+    # (``lay.piece_rows``). That is the operand the SO(2) weights multiply, so
+    # every step in between is a matrix product, an elementwise product or a
+    # static lane slice: no (E_c, S, c) array, no index list, no scatter.
 
-        Per |m|, the (l >= m) coefficients flatten l-major to (nl * c_in)
-        and pass through one linear map; m > 0 uses the (W_r, W_i) complex
-        pair structure y+ = W_r f+ - W_i f-, y- = W_r f- + W_i f+ (the
-        fairchem SO2_m_Convolution packing: fc output = [real | imag]
+    def _rotate_in(self, hvecs, D):
+        """Lab ``(E_c, S_full, c_k)`` arrays ``hvecs`` -> the edge frame's
+        pieces: per degree the transposed block times the features, of which
+        only the center 2*min(l,mmax)+1 rows are computed. A piece's lanes
+        run l-major and within a degree through ``hvecs`` in order (source
+        channels, then target channels), ``sum(c_k)`` lanes a degree."""
+        lay = self.lay
+        parts = [[jnp.einsum("epn,epc->enc",
+                             D[l][:, :, lay.block_rows(l)].astype(h.dtype),
+                             h[:, l * l:(l + 1) ** 2, :]) for h in hvecs]
+                 for l in range(lay.l_max + 1)]
+        return {m: jnp.concatenate(
+            [part[:, row, :] for l, row in lay.piece_rows(m)
+             for part in parts[l]], axis=-1) for m in lay.signed_ms}
+
+    def _rotate_out(self, y, D):
+        """Pieces ``y`` (c lanes a degree) -> lab ``(E_c, S_full, c)``: block
+        l times the rows its degree has among the pieces, each row a static
+        lane slice. Pieces that are absent (the edge-degree embedding has
+        m = 0 only) are skipped, not multiplied as zeros."""
+        lay = self.lay
+        c = y[0].shape[1] // lay.m_size(0)
+        blocks = []
+        for l in range(lay.l_max + 1):
+            # a contiguous run: every |m| <= min(l, mmax), or 0 alone
+            ms = [m for m in range(-l, l + 1) if m in y]
+            rows = jnp.stack([y[m][:, (l - abs(m)) * c:(l + 1 - abs(m)) * c]
+                              for m in ms], axis=1)
+            Dl = D[l][:, :, l + ms[0]:l + ms[-1] + 1].astype(rows.dtype)
+            blocks.append(jnp.einsum("epn,enc->epc", Dl, rows))
+        return jnp.concatenate(blocks, axis=1)
+
+    def _so2_conv(self, p, fr, rad_scale, c_out):
+        """SO(2) convolution on pieces ``fr``; returns ``(pieces, extra)``
+        with ``c_out`` lanes a degree and ``extra`` the m = 0 map's outputs
+        beyond its ``nl_0 * c_out`` (the gate scalars; none: ``(E_c, 0)``).
+
+        Per |m| one linear map of the piece; m > 0 uses the (W_r, W_i)
+        complex pair structure y+ = W_r f+ - W_i f-, y- = W_r f- + W_i f+
+        (the fairchem SO2_m_Convolution packing: fc output = [real | imag]
         halves). ``p["m<k>"]`` are plain ``(out, in)`` matrices: the expert
         axis is collapsed once a step (``_merge_experts``). ``rad_scale``:
-        optional per-coefficient input scaling from the radial function,
-        same scale for the +m and -m partners."""
+        optional per-lane input scaling from the radial function, the pieces'
+        lanes in the order m = 0, 1, .. and the same for +m and -m."""
         lay = self.lay
-        E = fr.shape[0]
-        y = jnp.zeros((E, lay.size, c_out), dtype=fr.dtype)
-        extra = None
-        off = 0
-        for m in range(lay.m_max + 1):
-            nl = lay.m_size(m)
-            if m == 0:
-                f0 = fr[:, lay.plus_idx[0], :].reshape(E, nl * c_in)
-                if rad_scale is not None:
-                    f0 = f0 * rad_scale[:, off:off + nl * c_in]
-                out0 = f0 @ p["m0"].T + p["m0_b"].astype(fr.dtype)
-                main, extra = (out0[:, :nl * c_out], out0[:, nl * c_out:])
-                y = y.at[:, lay.plus_idx[0], :].set(
-                    main.reshape(E, nl, c_out))
-            else:
-                fp = fr[:, lay.plus_idx[m], :].reshape(E, nl * c_in)
-                fm = fr[:, lay.minus_idx[m], :].reshape(E, nl * c_in)
-                if rad_scale is not None:
-                    s = rad_scale[:, off:off + nl * c_in]
-                    fp, fm = fp * s, fm * s
-                W = p[f"m{m}"]
-                d_out = nl * c_out
-                Wr, Wi = W[:d_out], W[d_out:]
-                yp = fp @ Wr.T - fm @ Wi.T
-                ym = fm @ Wr.T + fp @ Wi.T
-                y = y.at[:, lay.plus_idx[m], :].set(yp.reshape(E, nl, c_out))
-                y = y.at[:, lay.minus_idx[m], :].set(ym.reshape(E, nl, c_out))
-            off += nl * c_in
-        return (y, extra) if extra_m0 else y
+        if rad_scale is not None:
+            offs = np.cumsum(
+                [0] + [fr[m].shape[1] for m in range(lay.m_max + 1)])
+            fr = {m: f * rad_scale[:, offs[abs(m)]:offs[abs(m) + 1]]
+                  for m, f in fr.items()}
+        d0 = lay.m_size(0) * c_out
+        out0 = fr[0] @ p["m0"].T + p["m0_b"].astype(fr[0].dtype)
+        out = {0: out0[:, :d0]}
+        for m in range(1, lay.m_max + 1):
+            d_out = lay.m_size(m) * c_out
+            Wr, Wi = p[f"m{m}"][:d_out], p[f"m{m}"][d_out:]
+            out[m] = fr[m] @ Wr.T - fr[-m] @ Wi.T
+            out[-m] = fr[-m] @ Wr.T + fr[m] @ Wi.T
+        return out, out0[:, d0:]
 
-    def _gate_act(self, x, gates, full_layout=False):
-        """Gate activation: scalars -> silu, l > 0 coefficients scaled by
-        sigmoid(per-l gate scalars) broadcast over m. ``full_layout``
-        selects (lmax+1)^2 node-block slices instead of the mmax-narrowed
-        edge-frame slices."""
-        cfg, lay = self.cfg, self.lay
-        E, H = gates.shape[0], cfg.hidden_channels
-        g = jax.nn.sigmoid(gates.reshape(E, cfg.lmax, H))
-        y = x.at[:, 0, :].set(jax.nn.silu(x[:, 0, :]))
-        for l in range(1, cfg.lmax + 1):
-            sl = (slice(l * l, l * l + 2 * l + 1) if full_layout
-                  else lay.block_slices[l])
-            y = y.at[:, sl, :].multiply(g[:, l - 1][:, None, :])
+    def _gate_act(self, x, gates):
+        """Gate activation on pieces ``x`` (H lanes a degree): scalars (the
+        first H lanes of the m = 0 piece) -> silu, every l > 0 coefficient
+        times sigmoid of its degree's gate scalars. ``gates`` ``(E_c, lmax *
+        H)`` runs l-major from l = 1, so a piece's l = max(|m|, 1)..lmax
+        lanes meet the gates' lanes from ``(max(|m|, 1) - 1) * H`` on."""
+        H = self.cfg.hidden_channels
+        g = jax.nn.sigmoid(gates)
+        y = {m: x[m] * g[:, (abs(m) - 1) * H:] for m in x if m}
+        y[0] = jnp.concatenate(
+            [jax.nn.silu(x[0][:, :H]), x[0][:, H:] * g], axis=-1)
         return y
 
     def _ffn(self, p, x):
@@ -306,7 +330,11 @@ class ESCNMD:
         gates = _linear(p["gate"], x[:, 0, :])  # from input scalars
         h = jnp.einsum("nsc,shc->nsh", x, self._expand_lweights(p["lin1"]["w"], x.dtype))
         h = h.at[:, 0, :].add(p["lin1"]["b"].astype(x.dtype))
-        h = self._gate_act(h, gates, full_layout=True)
+        # the gate activation of ``_gate_act`` on (N, S, H) node blocks
+        g = jax.nn.sigmoid(gates.reshape(-1, cfg.lmax, cfg.hidden_channels))
+        g = jnp.repeat(g, np.arange(1, cfg.lmax + 1) * 2 + 1, axis=1)
+        h = jnp.concatenate(
+            [jax.nn.silu(h[:, :1, :]), h[:, 1:, :] * g], axis=1)
         y = jnp.einsum("nsh,sch->nsc", h, self._expand_lweights(p["lin2"]["w"], x.dtype))
         y = y.at[:, 0, :].add(p["lin2"]["b"].astype(x.dtype))
         return y
@@ -343,7 +371,7 @@ class ESCNMD:
         radial functions, SO(2) convolutions, gate activation, norms and
         feed-forward run in the compute dtype. Every line sits in a stage
         scope (telemetry/stages.py)."""
-        cfg, lay = self.cfg, self.lay
+        cfg = self.cfg
         C, H, S = cfg.sphere_channels, cfg.hidden_channels, cfg.sphere_dim
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else positions.dtype
         z = jnp.asarray(lg.species)
@@ -455,28 +483,6 @@ class ESCNMD:
             wigner_blocks_from_edges, cfg.lmax,
             precision=None if dtype == jnp.bfloat16 else COORD_PRECISION)
 
-        def rotate_in(hvecs, D):
-            """Lab (E_c, S_full, c) -> edge frame (E_c, S_nar, c): transpose
-            blocks, keep the center 2*min(l,mmax)+1 rows."""
-            parts = []
-            for l in range(cfg.lmax + 1):
-                rows = lay.block_rows(l)
-                Dl = D[l][:, :, rows].astype(hvecs.dtype)  # (E, 2l+1, nar)
-                o = l * l
-                parts.append(jnp.einsum(
-                    "epn,epc->enc", Dl, hvecs[:, o:o + 2 * l + 1, :]))
-            return jnp.concatenate(parts, axis=1)
-
-        def rotate_out(y, D):
-            """Edge frame (E_c, S_nar, c) -> lab (E_c, S_full, c)."""
-            parts = []
-            for l in range(cfg.lmax + 1):
-                rows = lay.block_rows(l)
-                Dl = D[l][:, :, rows].astype(y.dtype)
-                parts.append(jnp.einsum("epn,enc->epc", Dl,
-                                        y[:, lay.block_slices[l], :]))
-            return jnp.concatenate(parts, axis=1)
-
         def edge_scan(per_chunk, out_shape):
             def body(acc, xs):
                 srcc, dstc, maskc, rhatc, gaussc, envc = xs
@@ -512,12 +518,9 @@ class ESCNMD:
         # degree-summed onto the receiver, / avg_degree
         def deg_chunk(srcc, dstc, maskc, D, gaussc, envc):
             w = radial_of(params["edge_deg_rad"], srcc, dstc, gaussc)
-            with scope("edge_message"):
-                w = w.reshape(-1, cfg.lmax + 1, C)
-                y = jnp.zeros((w.shape[0], lay.size, C), dtype=dtype)
-                y = y.at[:, lay.plus_idx[0], :].set(w)
             with scope("edge_rotation"):
-                return rotate_out(y, D) * envc[:, None, None]
+                # w is the m = 0 piece as the radial function leaves it
+                return self._rotate_out({0: w}, D) * envc[:, None, None]
 
         inv_deg = jnp.asarray(1.0 / cfg.avg_degree, dtype=dtype)
         with scope("embedding"):
@@ -535,15 +538,13 @@ class ESCNMD:
                     xn_src = hn[srcc]
                     xn_dst = hn[dstc]
                 with scope("edge_rotation"):
-                    fr = jnp.concatenate([
-                        rotate_in(xn_src, D), rotate_in(xn_dst, D)], axis=-1)
+                    fr = self._rotate_in((xn_src, xn_dst), D)
                 with scope("edge_message"):
-                    y, gates = self._so2_conv(
-                        blk["so2_1"], fr, rad, 2 * C, H, cfg.lmax * H)
+                    y, gates = self._so2_conv(blk["so2_1"], fr, rad, H)
                     y = self._gate_act(y, gates)
-                    y = self._so2_conv(blk["so2_2"], y, None, H, C, 0)
+                    y, _ = self._so2_conv(blk["so2_2"], y, None, C)
                 with scope("edge_rotation"):
-                    return rotate_out(y, D) * envc[:, None, None]
+                    return self._rotate_out(y, D) * envc[:, None, None]
 
             with scope(f"layer{t}"):
                 # message path reads the NORMALIZED features (with the

@@ -181,6 +181,8 @@ class CoeffLayout:
     positions of the (l, +m) and (l, -m) coefficients over l = m..lmax —
     the (cos, sin) pairs the SO(2) convolutions mix (fairchem packs the
     same pairs via its to_m permutation, escn_md.py:117-129).
+    ``signed_ms`` and ``piece_rows`` describe the same coefficients cut into
+    one flat piece per signed m (models/escn_md.py), by static rows.
     """
 
     def __init__(self, l_max: int, m_max: int | None = None):
@@ -202,6 +204,8 @@ class CoeffLayout:
                 minus.append(base + mm - m)   # center - m
             self.plus_idx[m] = np.array(plus)
             self.minus_idx[m] = np.array(minus)
+        self.signed_ms = [0] + [
+            s * m for m in range(1, self.m_max + 1) for s in (1, -1)]
 
     def m_size(self, m: int) -> int:
         return self.l_max + 1 - m
@@ -210,3 +214,9 @@ class CoeffLayout:
         """Rows of the full (2l+1) e3nn block kept after mmax narrowing."""
         mm = min(l, self.m_max)
         return slice(l - mm, l + mm + 1)
+
+    def piece_rows(self, m: int) -> list[tuple[int, int]]:
+        """``(l, row of narrowed block l)`` of the signed-m coefficients,
+        l = |m|..lmax: the order in which a per-m piece's lanes run."""
+        return [(l, min(l, self.m_max) + m)
+                for l in range(abs(m), self.l_max + 1)]

@@ -181,9 +181,9 @@ def _run_with_gamma(model, params, rng, gamma_of_rhat):
     cart, lattice, species = _system(rng, reps=(2, 2, 2))
     orig = escn_md_mod.wigner_blocks_from_edges
 
-    def patched(l_max, rhat, gamma=None):
+    def patched(l_max, rhat, gamma=None, **kw):
         assert gamma is None  # the model itself always passes the default
-        return orig(l_max, rhat, gamma=gamma_of_rhat(rhat))
+        return orig(l_max, rhat, gamma=gamma_of_rhat(rhat), **kw)
 
     escn_md_mod.wigner_blocks_from_edges = patched
     try:
@@ -251,3 +251,163 @@ def test_gauge_invariance_fairchem_style_edge_frame(model, params):
     assert abs(e0 - e1) / n < 1e-6, (e0, e1)
     np.testing.assert_allclose(f0, f1, atol=2e-4)
     np.testing.assert_allclose(s0, s1, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The flat per-m pieces against the definition: the l-major (E, S, c) stack
+# addressed through CoeffLayout's index lists, in plain numpy (float64).
+# ---------------------------------------------------------------------------
+
+
+def _definition(lay, cfg, blk, hs, hd, D, rad):
+    """rotate_in -> so2_1 -> gate -> so2_2 -> rotate_out on the l-major
+    narrowed stack; returns every intermediate."""
+    C, H = cfg.sphere_channels, cfg.hidden_channels
+    E = hs.shape[0]
+
+    def rotate_in(h):
+        return np.concatenate([
+            np.einsum("epn,epc->enc", D[l][:, :, lay.block_rows(l)],
+                      h[:, l * l:(l + 1) ** 2]) for l in range(cfg.lmax + 1)],
+            axis=1)
+
+    def so2(p, fr, scale, c_in, c_out):
+        y = np.zeros((E, lay.size, c_out))
+        extra, off = None, 0
+        for m in range(lay.m_max + 1):
+            nl = lay.m_size(m)
+            s = 1.0 if scale is None else scale[:, off:off + nl * c_in]
+            fp = fr[:, lay.plus_idx[m]].reshape(E, nl * c_in) * s
+            fm = fr[:, lay.minus_idx[m]].reshape(E, nl * c_in) * s
+            if m == 0:
+                out0 = fp @ p["m0"].T + p["m0_b"]
+                y[:, lay.plus_idx[0]] = out0[:, :nl * c_out].reshape(
+                    E, nl, c_out)
+                extra = out0[:, nl * c_out:]
+            else:
+                Wr, Wi = p[f"m{m}"][:nl * c_out], p[f"m{m}"][nl * c_out:]
+                y[:, lay.plus_idx[m]] = (fp @ Wr.T - fm @ Wi.T).reshape(
+                    E, nl, c_out)
+                y[:, lay.minus_idx[m]] = (fm @ Wr.T + fp @ Wi.T).reshape(
+                    E, nl, c_out)
+            off += nl * c_in
+        return y, extra
+
+    fr = np.concatenate([rotate_in(hs), rotate_in(hd)], axis=-1)
+    y1, gates = so2(blk["so2_1"], fr, rad, 2 * C, H)
+    g = 1.0 / (1.0 + np.exp(-gates.reshape(E, cfg.lmax, H)))
+    y2 = y1.copy()
+    y2[:, 0] = y1[:, 0] / (1.0 + np.exp(-y1[:, 0]))
+    for l in range(1, cfg.lmax + 1):
+        y2[:, lay.block_slices[l]] *= g[:, l - 1][:, None, :]
+    y3, _ = so2(blk["so2_2"], y2, None, H, C)
+    out = np.concatenate([
+        np.einsum("epn,enc->epc", D[l][:, :, lay.block_rows(l)],
+                  y3[:, lay.block_slices[l]]) for l in range(cfg.lmax + 1)],
+        axis=1)
+    return fr, y1, gates, y2, y3, out
+
+
+@pytest.mark.parametrize("lmax, mmax", [(2, 2), (2, 1), (3, 2)])
+def test_pieces_match_the_l_major_definition(lmax, mmax):
+    """Every stage between ``_rotate_in`` and ``_rotate_out``, piece by
+    piece, equals the l-major stack read through ``plus_idx`` /
+    ``minus_idx``; so does the gradient with respect to the node features
+    (against central differences of the definition), and the edge-degree
+    embedding's m = 0 piece rotated out alone."""
+    import jax.numpy as jnp
+
+    from distmlip_tpu.ops.so3_e3nn import wigner_blocks_from_edges
+
+    cfg = ESCNMDConfig(**{**CFG.__dict__, "lmax": lmax, "mmax": mmax,
+                          "sphere_channels": 4, "hidden_channels": 6,
+                          "num_layers": 1})
+    model = ESCNMD(cfg)
+    lay, C, E = model.lay, cfg.sphere_channels, 7
+    rng = np.random.default_rng(29)
+    blk = jax.tree.map(lambda x: np.asarray(x, np.float64),
+                       model.init(jax.random.PRNGKey(1))["blocks"][0])
+    hs, hd = rng.normal(size=(2, E, cfg.sphere_dim, C))
+    cot = rng.normal(size=(E, cfg.sphere_dim, C))
+    rad = rng.normal(size=(E, sum(model._rad_splits) * 2 * C))
+    rhat = rng.normal(size=(E, 3))
+    rhat /= np.linalg.norm(rhat, axis=1, keepdims=True)
+    signed = lambda m: (lay.plus_idx if m >= 0 else lay.minus_idx)[abs(m)]
+
+    def assert_pieces(pieces, stack):
+        assert sorted(pieces) == sorted(lay.signed_ms)
+        for m, piece in pieces.items():
+            np.testing.assert_allclose(
+                piece, stack[:, signed(m)].reshape(E, -1), atol=1e-12)
+
+    with jax.enable_x64():
+        D = wigner_blocks_from_edges(lmax, jnp.asarray(rhat))
+        Dn = [np.asarray(d) for d in D]
+        assert Dn[0].dtype == np.float64
+        ref = _definition(lay, cfg, blk, hs, hd, Dn, rad)
+
+        def chain(hs, hd):
+            fr = model._rotate_in((hs, hd), D)
+            y1, gates = model._so2_conv(blk["so2_1"], fr, rad,
+                                        cfg.hidden_channels)
+            y2 = model._gate_act(y1, gates)
+            y3, none = model._so2_conv(blk["so2_2"], y2, None, C)
+            return fr, y1, gates, y2, y3, none, model._rotate_out(y3, D)
+
+        fr, y1, gates, y2, y3, none, out = chain(hs, hd)
+        for pieces, stack in zip((fr, y1, y2, y3),
+                                 (ref[0], ref[1], ref[3], ref[4])):
+            assert_pieces(pieces, stack)
+        np.testing.assert_allclose(gates, ref[2], atol=1e-12)
+        assert none.shape == (E, 0)
+        assert out.shape == (E, cfg.sphere_dim, C)
+        np.testing.assert_allclose(out, ref[5], atol=1e-12)
+
+        grads = jax.grad(lambda a, b: jnp.sum(chain(a, b)[-1] * cot),
+                         argnums=(0, 1))(hs, hd)
+        vs, vd = rng.normal(size=(2,) + hs.shape)
+        loss = lambda t: np.sum(_definition(
+            lay, cfg, blk, hs + t * vs, hd + t * vd, Dn, rad)[-1] * cot)
+        fd = (loss(1e-5) - loss(-1e-5)) / 2e-5
+        np.testing.assert_allclose(
+            np.sum(grads[0] * vs) + np.sum(grads[1] * vd), fd, rtol=1e-7)
+
+        # the edge-degree embedding: the m = 0 piece alone
+        w = rng.normal(size=(E, (lmax + 1) * C))
+        deg = np.concatenate([
+            Dn[l][:, :, l, None] * w[:, None, l * C:(l + 1) * C]
+            for l in range(lmax + 1)], axis=1)
+        np.testing.assert_allclose(model._rotate_out({0: w}, D), deg,
+                                   atol=1e-12)
+
+
+# energy (eV) and forces (eV/A) of atoms 0, 5 and 17 as PR 28's tree (the
+# l-major index-list layout) gives them in float32 on the CPU, weights from
+# PRNGKey(29), crystal from default_rng(2929)
+PARENT_NUMBERS = {
+    "l2m2": ({}, -14.307394981384277,
+        [[-1.402688911e-03, -1.923336647e-02, 1.613003388e-02],
+         [-5.695698317e-03, 7.462555543e-03, 3.505037166e-03],
+         [-2.210015897e-03, 2.763571218e-03, -5.191113451e-04]]),
+    "l2m1_chunked": (dict(mmax=1, edge_chunk=128), -18.212867736816406,
+        [[2.852639416e-03, -2.467999794e-02, 1.768270135e-02],
+         [-4.961216822e-03, 1.132356096e-02, 7.674073800e-03],
+         [-6.594459410e-04, -1.288142754e-03, 2.347774105e-03]]),
+    "l3m2_chunked": (dict(lmax=3, mmax=2, edge_chunk=128), -15.852083206176758,
+        [[6.782680284e-04, -2.752223983e-02, 2.140100300e-02],
+         [-6.790874526e-03, 1.158589963e-02, 6.399306003e-03],
+         [-2.535175066e-03, 3.426501295e-03, -9.482129244e-04]]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_NUMBERS))
+def test_energy_and_forces_equal_the_index_list_layouts(name):
+    kw, e_ref, f_ref = PARENT_NUMBERS[name]
+    model = ESCNMD(ESCNMDConfig(**{**CFG.__dict__, **kw}))
+    params = model.init(jax.random.PRNGKey(29))
+    cart, lattice, species = _system(np.random.default_rng(2929),
+                                     reps=(2, 2, 2))
+    e, f, _ = run_potential(model.energy_fn, params, cart, lattice, species,
+                            CUT, nparts=1)
+    assert abs(e - e_ref) < 2e-6 * abs(e_ref)
+    np.testing.assert_allclose(f[[0, 5, 17]], f_ref, atol=5e-7)

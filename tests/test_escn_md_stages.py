@@ -41,13 +41,14 @@ def atoms_of(nparts=1):
     return Atoms(numbers=numbers, positions=cart, cell=lattice)
 
 
-def step_sites(cfg, nparts=1, **kw):
+def step_sites(cfg, nparts=1, with_graph=False, **kw):
     model = ESCNMD(cfg)
     pot = DistPotential(model, model.init(jax.random.PRNGKey(0)),
                         num_partitions=nparts, skin=0.3, **kw)
     graph, _, positions = pot._prepare(atoms_of(nparts))
     jaxpr = jax.make_jaxpr(pot._potential)(pot.params, graph, positions)
-    return list(iter_sites(jaxpr))
+    sites = list(iter_sites(jaxpr))
+    return (sites, graph) if with_graph else sites
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -96,6 +97,34 @@ def test_every_equation_of_the_model_carries_a_stage(nparts, kernels):
         calls = [s for s in model if s.primitive == "pallas_call"]
         assert calls and all(stage_of(s.stack) == "edge_aggregate"
                              for s in calls)
+
+
+@pytest.mark.parametrize("nparts", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_edge_message_addresses_rows_by_index_only_on_nodes(dtype, nparts):
+    """Between ``_rotate_in`` and ``_rotate_out`` the coefficients are flat
+    per-m pieces cut by static slices: under ``edge_message`` nothing is
+    written by index (no ``scatter``, no ``scatter-mul``) and the only rows
+    read by index are the model's own ``hn[src]`` / ``hn[dst]``, with the
+    scatter-adds onto nodes that transpose them. The l-major ``(E, 9, c)``
+    layout addressed through index lists counted 48 ``scatter``, 12
+    ``scatter-mul``, 66 ``scatter-add`` and 117 rank-3 ``gather`` here
+    (PR 28's tree, this config), each a loop of whole-array updates on the
+    chip."""
+    cfg = config(dtype=dtype)
+    sites, graph = step_sites(cfg, nparts, with_graph=True)
+    msg = [s for s in sites if stage_of(s.stack) == "edge_message"]
+    assert any(s.primitive == "dot_general" for s in msg)
+    assert not [s for s in msg if s.primitive in ("scatter", "scatter-mul")]
+    node_shape = (graph.n_cap, cfg.sphere_dim, cfg.sphere_channels)
+    adds = [s.eqn.outvars[0].aval.shape for s in msg
+            if s.primitive == "scatter-add"]
+    gathers = [s.eqn.invars[0].aval.shape for s in msg
+               if s.primitive == "gather"
+               and len(s.eqn.invars[0].aval.shape) == 3]
+    # two a layer; forward and recompute read, the backward adds
+    assert adds == [node_shape] * (2 * cfg.num_layers), adds
+    assert gathers == [node_shape] * (4 * cfg.num_layers), gathers
 
 
 def block_products(sites):

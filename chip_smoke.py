@@ -120,8 +120,8 @@ class CompileWatch:
 
 
 def build_cell(reps, seed: int, a: float = 3.9, sigma: float = 0.04):
-    """Perturbed fcc 'Si-like' supercell, the bench workload's recipe
-    (tools/bench_common.py): 4 * prod(reps) atoms."""
+    """Perturbed fcc 'Si-like' supercell, the benchmark's recipe
+    (benchmark/harness/structures.py): 4 * prod(reps) atoms."""
     from distmlip_tpu import geometry
     from distmlip_tpu.calculators import Atoms
 
@@ -476,7 +476,7 @@ def phase_kernels(bands: dict, seed: int = 0) -> list:
 # ---------------------------------------------------------------------------
 
 def mace_mp0_medium():
-    """MACE-MP-0 medium at full width (tools/bench_common.py)."""
+    """MACE-MP-0 medium at full width (benchmark/configs/mace-mp0-medium.json)."""
     from distmlip_tpu.models import MACE, MACEConfig
 
     return MACE(MACEConfig(

@@ -39,7 +39,7 @@ import time
 import numpy as np
 
 from ..neighbors import neighbor_list
-from ..parallel import graph_mesh, make_potential_fn, make_site_fn
+from ..parallel import graph_mesh, make_potential_fn
 from ..partition import CapacityPolicy, build_partitioned_graph, build_plan
 from ..telemetry import StepRecord, annotate, note_dispatch
 from .atoms import EV_A3_TO_GPA, Atoms, map_species, max_displacement
@@ -50,6 +50,12 @@ from .atoms import EV_A3_TO_GPA, Atoms, map_species, max_displacement
 # historical private names stay importable (and monkeypatchable) here
 from ..utils.memory import device_memory_stats as _device_memory_stats
 from ..utils.memory import hbm_usage_frac as _hbm_usage_frac
+
+# HBM guard of the speculative background rebuild, which double-books graph
+# HBM while it runs: past ~1/3 occupancy the speculation risks an OOM that
+# costs far more than the rebuild stall it hides (``_maybe_prefetch``;
+# skips are counted in ``prefetch_skipped_hbm`` and surfaced in telemetry)
+PREFETCH_HBM_FRAC = 1.0 / 3.0
 
 
 def _discard_abandoned_build(future):
@@ -85,22 +91,6 @@ class DistPotential:
     num_partitions : number of graph partitions (default: all devices).
     species_map : optional (max_Z+1,) int array mapping atomic numbers to the
         model's species indices. Default: identity (model indexes by Z).
-    halo_mode : "coalesced" (default — one ppermute per ring shift per sync
-        point) or "legacy" (historical per-array exchange loop, for A/B
-        equivalence runs); see parallel/halo.py.
-    fused_site_readout : when compute_magmom and the model exposes
-        ``energy_and_aux_fn``, ride the sitewise readout on the energy
-        forward (no second full pass). False falls back to the deprecated
-        separate ``make_site_fn`` program.
-    prefetch_hbm_frac : HBM guard scale for the speculative background
-        rebuild, which transiently double-books graph HBM. PREDICTIVE
-        where the backend reports a ``bytes_limit``: the build is skipped
-        when current occupancy PLUS the cached graph's statically
-        estimated per-device residency would exceed ``2x`` this fraction
-        (so a small graph on a busy device is no longer falsely vetoed);
-        where no limit is reported, falls back to the historical rule
-        (skip while occupancy alone exceeds the fraction). Skips are
-        counted in ``prefetch_skipped_hbm`` and surfaced in telemetry.
     device_rebuild : "auto" (default) rebuilds the neighbor graph ON DEVICE
         when the Verlet skin cache invalidates — single-partition,
         non-bond-graph potentials only (``neighbors.device`` cell list +
@@ -126,10 +116,6 @@ class DistPotential:
         compute_magmom: bool = False,
         async_rebuild: bool = True,
         prefetch_frac: float = 0.5,
-        prefetch_hbm_frac: float = 1.0 / 3.0,
-        halo_mode: str = "coalesced",
-        fused_site_readout: bool = True,
-        collective_audit: bool = True,
         device_rebuild: bool | str = "auto",
         kernels=None,
         telemetry=None,
@@ -182,14 +168,14 @@ class DistPotential:
         self.bond_cutoff = float(getattr(model.cfg, "bond_cutoff", 0.0))
         self.use_bond_graph = bool(getattr(model.cfg, "use_bond_graph", False))
         self.compute_stress = bool(compute_stress)
-        if compute_magmom and not hasattr(model, "magmom_fn"):
+        if compute_magmom and not hasattr(model, "energy_and_aux_fn"):
+            # magmoms ride the energy forward as an aux output (runtime
+            # aux=True): no second forward pass
             raise ValueError(
-                f"{type(model).__name__} has no magmom_fn (sitewise "
-                f"readout); compute_magmom is a CHGNet-family capability")
+                f"{type(model).__name__} has no energy_and_aux_fn (fused "
+                f"sitewise readout); compute_magmom is a CHGNet-family "
+                f"capability")
         self.compute_magmom = bool(compute_magmom)
-        from ..parallel.halo import validate_halo_mode
-
-        self.halo_mode = validate_halo_mode(halo_mode)
         # Pallas fused-kernel routing (kernels/dispatch.resolve_kernel_mode):
         # None = env/backend default (Pallas on TPU, XLA elsewhere),
         # False = force the pure-XLA path, "interpret" = interpreter-mode
@@ -201,16 +187,6 @@ class DistPotential:
         self._kernel_mode = ""
         self._kernel_coverage = 0.0
         self._kernel_ops: dict = {}   # op -> [pallas, xla] call sites
-        # collective_count telemetry: one extra ABSTRACT trace (make_jaxpr,
-        # no compile) per runtime build, on the first record emit — a small
-        # fraction of that build's compile cost, but disable for
-        # trace-latency-sensitive sweeps over many models
-        self.collective_audit = bool(collective_audit)
-        # fused site readout: magmoms ride the energy forward as an aux
-        # output (runtime aux=True) instead of make_site_fn's SEPARATE full
-        # forward — requires the model to expose energy_and_aux_fn
-        self.fused_site_readout = bool(
-            fused_site_readout and hasattr(model, "energy_and_aux_fn"))
         self.skin = float(skin)
         # default num_partitions is AUTO: all devices, clamped by the slab
         # rule (box extent / partition > 2 * build cutoff) for the first
@@ -252,12 +228,6 @@ class DistPotential:
         # the NEXT graph while the device steps on the current one
         self.async_rebuild = bool(async_rebuild) and self.skin > 0.0
         self.prefetch_frac = float(prefetch_frac)
-        # HBM guard (VERDICT weak #4): skip the speculative build while the
-        # live graph already occupies more than this fraction of the
-        # device's bytes_limit — a prefetch transiently double-books graph
-        # HBM, so past ~1/3 occupancy the speculation risks an OOM that
-        # costs far more than the rebuild stall it hides
-        self.prefetch_hbm_frac = float(prefetch_hbm_frac)
         self._executor = None
         self._prefetch = None   # (future, snapshot_atoms)
         self.prefetch_hits = 0  # rebuilds absorbed by a background build
@@ -286,18 +256,11 @@ class DistPotential:
             graph_mesh(self.num_partitions, self._devices)
             if self.num_partitions > 1 else None
         )
-        fused = self.compute_magmom and self.fused_site_readout
         self._potential = make_potential_fn(
-            self.model.energy_and_aux_fn if fused else self.model.energy_fn,
+            self.model.energy_and_aux_fn if self.compute_magmom
+            else self.model.energy_fn,
             self.mesh, compute_stress=self.compute_stress,
-            halo_mode=self.halo_mode, aux=fused, kernels=self.kernels,
-        )
-        # legacy separate-forward readout only when the fused path is
-        # unavailable or explicitly disabled
-        self._site_fn = (
-            make_site_fn(self.model.magmom_fn, self.mesh,
-                         halo_mode=self.halo_mode, kernels=self.kernels)
-            if (self.compute_magmom and not fused) else None
+            aux=self.compute_magmom, kernels=self.kernels,
         )
         # compile telemetry: a runtime (re)build means the next dispatch
         # re-traces — record the build itself so rebuild storms show up
@@ -506,24 +469,18 @@ class DistPotential:
         pos0 = self._cache[3]
         if self._disp_frac(pos0, atoms.positions) < self.prefetch_frac:
             return
-        # HBM-aware guard, PREDICTIVE: the speculative build transiently
-        # adds ~one graph of per-device residency. When the build's
-        # footprint is statically estimable (bytes_limit known), skip only
-        # if current occupancy + the estimated build residency would pass
-        # 2x prefetch_hbm_frac (the historical ceiling the 1/3 default
-        # implied for a graph-dominated live set) — a tiny graph on a busy
-        # chip no longer gets a false veto. Without a limit estimate fall
-        # back to the historical occupancy-only rule.
+        # HBM guard, PREDICTIVE: the speculative build transiently adds
+        # ~one graph of per-device residency. Where that footprint can be
+        # estimated (bytes_limit known), skip only if current occupancy +
+        # the estimate would pass 2x PREFETCH_HBM_FRAC — a tiny graph on a
+        # busy chip gets no false veto (the estimate excludes neighbor-build
+        # temporaries, so real residency runs higher). Without an estimate,
+        # skip while occupancy alone exceeds the fraction.
         frac = _hbm_usage_frac()
         if frac is not None:
             add = self._estimate_prefetch_frac()
-            # predicted ceiling capped at 0.9: whatever the knob says,
-            # a speculative build pushing predicted occupancy past 90%
-            # is vetoed (the estimate excludes neighbor-build
-            # temporaries, so real residency runs higher)
-            ceiling = min(2.0 * self.prefetch_hbm_frac, 0.9)
-            veto = (frac + add > ceiling if add is not None
-                    else frac > self.prefetch_hbm_frac)
+            veto = (frac + add > 2.0 * PREFETCH_HBM_FRAC if add is not None
+                    else frac > PREFETCH_HBM_FRAC)
             if veto:
                 self.prefetch_skipped_hbm += 1
                 self._prefetch_skip_hbm_flag = True
@@ -788,13 +745,6 @@ class DistPotential:
                     # as an aux output — no second forward pass
                     m = np.asarray(out["aux"]["magmoms"])
                     result["magmoms"] = host.gather_owned(m, len(atoms))
-            if "aux" not in out and self._site_fn is not None:
-                # legacy separate-forward readout (CHGNet magmoms; reference
-                # ase.py magmoms surface) over the SAME cached graph/positions
-                with annotate("distmlip/site_readout"):
-                    m = np.asarray(self._site_fn(self.params, graph,
-                                                 positions))
-                    result["magmoms"] = host.gather_owned(m, len(atoms))
         self.last_timings["device_s"] = time.perf_counter() - t2
         self.last_stats = dict(getattr(host, "stats", None) or {})
         self.last_stats.update(
@@ -862,7 +812,6 @@ class DistPotential:
             compile_cache_size=cache_size, compiled=compiled,
             compile_s=compile_s, compile_kind=compile_kind,
             device_memory=_device_memory_stats(),
-            halo_mode=self.halo_mode,
             prefetch_skipped_hbm=self._prefetch_skip_hbm_flag,
             rebuild_overflow_count=overflow_count,
             extra=extra, **flags,
@@ -896,12 +845,6 @@ class DistPotential:
                 rec.hbm_headroom_frac = 1.0 - rec.est_peak_bytes / limit
         tel.emit(rec)
 
-    def _collective_count(self) -> int:
-        """Collectives per potential step (traced once per runtime build and
-        cached — a host-side jaxpr walk, no device work). 0 when tracing is
-        not possible (no cached graph yet)."""
-        return self._contract_audit()[0]
-
     def _contract_audit(self) -> tuple:
         """(collective_count, contract_errors, contract_warnings,
         kernel_mode, kernel_coverage, est_peak_bytes) of the step program:
@@ -926,8 +869,7 @@ class DistPotential:
                              out[5])
             self._collective_count_cache = (self._potential, out)
             return out
-        if (not self.collective_audit or self._cache is None
-                or self._potential is None):
+        if self._cache is None or self._potential is None:
             # no cached graph to trace (skin=0 runs) — the observed
             # dispatch tally is still authoritative
             return (0, 0, 0, self._kernel_mode, self._kernel_coverage, 0)
@@ -1101,7 +1043,6 @@ class EnsemblePotential:
             # built lazily: AUTO partitioning defers base._potential until
             # the first cell is seen
             self._vpot = None
-            self._vsite = None
         else:
             self.members = [base] + [
                 DistPotential(model, p, **kwargs) for p in params_list[1:]
@@ -1128,9 +1069,6 @@ class EnsemblePotential:
                 import jax
 
                 self._vpot = jax.vmap(base._potential, in_axes=(0, None, None))
-                if base._site_fn is not None:
-                    self._vsite = jax.vmap(base._site_fn,
-                                           in_axes=(0, None, None))
             t2 = time.perf_counter()
             out = self._vpot(self.stacked_params, graph, positions)
             energies = np.asarray(out["energy"], dtype=np.float64)
@@ -1145,13 +1083,6 @@ class EnsemblePotential:
                 # fused readout: per-member magmoms came out of the same
                 # vmapped energy forward
                 m_all = np.asarray(out["aux"]["magmoms"])
-                magmoms = np.stack([
-                    host.gather_owned(m_all[k], len(atoms))
-                    for k in range(m_all.shape[0])
-                ])
-            elif self._vsite is not None:
-                m_all = np.asarray(self._vsite(self.stacked_params, graph,
-                                               positions))
                 magmoms = np.stack([
                     host.gather_owned(m_all[k], len(atoms))
                     for k in range(m_all.shape[0])

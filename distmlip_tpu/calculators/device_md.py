@@ -273,7 +273,6 @@ class DeviceMD:
         self.taut = float(taut) if temperature is not None else 0.0
         self._total_energy = make_total_energy(
             potential.model.energy_fn, potential.mesh,
-            halo_mode=getattr(potential, "halo_mode", "coalesced"),
             # inherit the potential's Pallas routing; the MD force program
             # differentiates positions only, so the force-program policy
             # applies (no weight cotangents riding the scan carry / mesh)

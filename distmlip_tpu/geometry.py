@@ -66,10 +66,6 @@ def plane_spacings(lattice: np.ndarray) -> np.ndarray:
     return 1.0 / np.linalg.norm(inv, axis=0)
 
 
-def cell_volume(lattice: np.ndarray) -> float:
-    return float(abs(np.linalg.det(np.asarray(lattice, dtype=np.float64))))
-
-
 def make_supercell(
     frac: np.ndarray, lattice: np.ndarray, reps: tuple[int, int, int]
 ) -> tuple[np.ndarray, np.ndarray]:

@@ -159,9 +159,8 @@ class CHGNet:
     def energy_and_aux_fn(self, params, lg, positions):
         """Fused readout: per-atom energies plus the sitewise outputs
         (magmoms) from the SAME forward pass — the runtime's ``aux=True``
-        contract. Replaces make_site_fn's separate full forward for
-        magmom-every-step MD (the parity oracle lives in
-        tests/test_halo_overlap.py)."""
+        contract: magmom-every-step MD pays no second forward (parity
+        against ``magmom_fn``: tests/test_halo_overlap.py)."""
         v, site = self._trunk(params, lg, positions)
         e_atom = mlp(params["final"], v)[:, 0]
         e_ref = params["species_ref"]["w"][lg.species, 0]

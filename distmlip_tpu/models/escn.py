@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.dispatch import fused_segment_sum, fused_so2_conv
+from ..kernels.dispatch import fused_so2_conv
 from ..ops import radial
 from ..ops.nn import cast_params_subtrees, linear, linear_init, mlp, mlp_init
 from ..ops.so3_e3nn import CoeffLayout, wigner_blocks_from_edges
@@ -209,68 +209,31 @@ class ESCN:
         # (E, S, C) are the memory giants of eSCN; both are rebuilt per
         # chunk inside a lax.scan (the Jd-pipeline build is 3 z-rotations
         # + 2 constant matmuls per l — noise next to the SO(2) GEMMs), so
-        # peak memory is O(chunk), not O(E). Scaffolding shared with MACE
-        # (ops/chunk.py).
-        from ..ops.chunk import (chunk_layout, chunked, scan_accumulate,
-                                 take_rows)
-
-        e_cap = lg.edge_src.shape[0]
-        # chunk boundaries aligned to the interior/frontier split so every
-        # chunk's dst stays sorted (indices_are_sorted survives the layout)
-        e_split = lg.e_split if lg.has_frontier_split else None
-        _, row_valid, K, chunk = chunk_layout(e_cap, cfg.edge_chunk, e_split)
-        take = lambda x: chunked(take_rows(x, chunk, e_split), K, chunk)
-        # stage scopes (telemetry/stages.py) on the scaffolding this model
-        # shares with models/escn_md.py, and only there
-        with scope("edge_gather"):
-            edge_xs = (
-                take(lg.edge_src),
-                take(lg.edge_dst),
-                take(lg.edge_mask)
-                & chunked(jnp.asarray(row_valid), K, chunk),
-                take(rhat),
-                take(bessel),
-                take(env),
-            )
+        # peak memory is O(chunk), not O(E) (LocalGraph.scan_edges).
+        edge_xs = lg.edge_chunks(cfg.edge_chunk, rhat, bessel, env)
         # single-chunk path: build D once (fp32) and share it across the
         # edge-degree pass and every layer instead of per edge_scan call
         with scope("edge_rotation"):
             D_shared = (
                 wigner_blocks_from_edges(cfg.l_max, edge_xs[3][0])
-                if K == 1 else None
+                if edge_xs[0].shape[0] == 1 else None
             )
 
-        def edge_scan(per_chunk, out_shape):
-            """Accumulate sum_chunks per_chunk(...) over the edge chunks.
+        def edge_scan(per_chunk):
+            """Chunked edge sum of ``per_chunk(srcc, dstc, maskc, D, besc,
+            envc) -> (E_c, S, C)`` message rows."""
 
-            per_chunk(srcc, dstc, maskc, D, besc, envc) -> (E_c, ...) message
-            rows, segment-summed onto their dst inside the scan."""
-
-            def body(acc, xs):
-                srcc, dstc, maskc, rhatc, besc, envc = xs
+            def with_blocks(srcc, dstc, maskc, rhatc, besc, envc):
                 with scope("edge_rotation"):
                     D = (
                         D_shared
                         if D_shared is not None
                         else wigner_blocks_from_edges(cfg.l_max, rhatc)
                     )
-                msg = per_chunk(srcc, dstc, maskc, D, besc, envc)
-                with scope("edge_aggregate"):
-                    return (
-                        acc
-                        + fused_segment_sum(
-                            # sorted within every chunk by chunk_layout;
-                            # Pallas dst-tiled scatter on TPU
-                            # (kernels/dispatch)
-                            msg, dstc, lg.n_cap, maskc,
-                            indices_are_sorted=True, kernels=lg.kernels,
-                        ),
-                        None,
-                    )
+                return per_chunk(srcc, dstc, maskc, D, besc, envc)
 
-            with scope("edge_gather"):
-                acc0 = jnp.zeros((lg.n_cap,) + out_shape, dtype=dtype)
-                return scan_accumulate(body, acc0, edge_xs, remat=cfg.remat)
+            return lg.scan_edges(with_blocks, edge_xs, (S, C), dtype,
+                                 remat=cfg.remat)
 
         # device array: the chunked scan indexes z with traced chunk indices,
         # which a host numpy species array cannot support
@@ -323,7 +286,7 @@ class ESCN:
                 y_deg = y_deg.at[:, l * l + l, :].set(w_deg[:, l, :])
             return rotate(y_deg, D) * envc[:, None, None]
 
-        h = h + edge_scan(deg_chunk, (S, C)) * jnp.asarray(
+        h = h + edge_scan(deg_chunk) * jnp.asarray(
             1.0 / cfg.avg_num_neighbors, dtype=dtype
         )
         h = lg.halo_exchange(h)
@@ -448,7 +411,7 @@ class ESCN:
 
                 return rotate(y, D) * envc[:, None, None]
 
-            agg = edge_scan(so2_chunk, (S, C)) * inv_avg
+            agg = edge_scan(so2_chunk) * inv_avg
 
             # gated nonlinearity: scalars via MLP, higher l scaled by gates
             s = agg[:, 0, :]

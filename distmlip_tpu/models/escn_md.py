@@ -52,7 +52,6 @@ import numpy as np
 from ..geometry import COORD_PRECISION
 from ..ops import radial
 from ..ops.nn import cast_params_subtrees
-from ..kernels.dispatch import fused_segment_sum
 from ..ops.so3_e3nn import CoeffLayout, wigner_blocks_from_edges
 from ..telemetry import scope
 
@@ -456,25 +455,8 @@ class ESCNMD:
             h = jnp.zeros((positions.shape[0], S, C), dtype=dtype)
             h = h.at[:, 0, :].set(zemb + csd[None, :])
 
-        # --- edge-chunked scan scaffolding (shared with models/escn.py);
-        # chunk_layout keeps every chunk inside one dst-sorted edge segment
-        from ..ops.chunk import (chunk_layout, chunked, scan_accumulate,
-                                 take_rows)
-
-        e_cap = lg.edge_src.shape[0]
-        e_split = lg.e_split if lg.has_frontier_split else None
-        _, row_valid, K_ch, chunk = chunk_layout(e_cap, cfg.edge_chunk, e_split)
-        take = lambda x: chunked(take_rows(x, chunk, e_split), K_ch, chunk)
-        with scope("edge_gather"):
-            edge_xs = (
-                take(lg.edge_src),
-                take(lg.edge_dst),
-                take(lg.edge_mask)
-                & chunked(jnp.asarray(row_valid), K_ch, chunk),
-                take(rhat),
-                take(gauss),
-                take(env),
-            )
+        # per-edge rows in chunk order, laid out once for the five scans
+        edge_xs = lg.edge_chunks(cfg.edge_chunk, rhat, gauss, env)
 
         # per-l lab-from-edge blocks; ops/so3_e3nn builds them at >= fp32
         # with pole-safe angles, downcast per-use in rotate_in/rotate_out:
@@ -483,26 +465,16 @@ class ESCNMD:
             wigner_blocks_from_edges, cfg.lmax,
             precision=None if dtype == jnp.bfloat16 else COORD_PRECISION)
 
-        def edge_scan(per_chunk, out_shape):
-            def body(acc, xs):
-                srcc, dstc, maskc, rhatc, gaussc, envc = xs
+        def edge_scan(per_chunk):
+            """Chunked edge sum of ``per_chunk(srcc, dstc, maskc, D, gaussc,
+            envc) -> (E_c, S, C)``; the blocks are rebuilt per chunk."""
+            def with_blocks(srcc, dstc, maskc, rhatc, gaussc, envc):
                 with scope("edge_rotation"):
                     D = wigner_blocks(rhatc)
-                msg = per_chunk(srcc, dstc, maskc, D, gaussc, envc)
-                with scope("edge_aggregate"):
-                    return (
-                        acc + fused_segment_sum(
-                            # sorted within every chunk by chunk_layout;
-                            # Pallas dst-tiled scatter on TPU
-                            # (kernels/dispatch)
-                            msg, dstc, lg.n_cap, maskc,
-                            indices_are_sorted=True, kernels=lg.kernels),
-                        None,
-                    )
+                return per_chunk(srcc, dstc, maskc, D, gaussc, envc)
 
-            with scope("edge_gather"):
-                acc0 = jnp.zeros((lg.n_cap,) + out_shape, dtype=dtype)
-                return scan_accumulate(body, acc0, edge_xs, remat=cfg.remat)
+            return lg.scan_edges(with_blocks, edge_xs, (S, C), dtype,
+                                 remat=cfg.remat)
 
         def radial_of(p, srcc, dstc, gaussc):
             """Radial function of [gaussians | source | target species]."""
@@ -524,7 +496,7 @@ class ESCNMD:
 
         inv_deg = jnp.asarray(1.0 / cfg.avg_degree, dtype=dtype)
         with scope("embedding"):
-            deg = edge_scan(deg_chunk, (S, C))
+            deg = edge_scan(deg_chunk)
             with scope("node_linear"):
                 h = h + deg * inv_deg
             h = lg.halo_exchange(h)
@@ -553,7 +525,7 @@ class ESCNMD:
                 with scope("node_tensor"):
                     hn = self._rms_norm_sh(blk["norm1"]["w"], h)
                     hn = hn.at[:, 0, :].add(csd[None, :])
-                msg = edge_scan(so2_chunk, (S, C))
+                msg = edge_scan(so2_chunk)
                 with scope("node_tensor"):
                     h = h + msg * inv_deg
                     # FFN with pre-norm and residual

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.dispatch import fused_segment_sum
 from ..ops import radial
 from ..ops.nn import linear, linear_init, linear_init_vp, mlp, mlp_init, mlp_init_vp
 from ..ops.so3 import (
@@ -370,28 +369,13 @@ class MACE:
                 acc = acc + self._zbl_site(params, lg, d, acc_dtype)
 
         # per-edge rows in chunk order, laid out ONCE for both interactions
-        # (nothing here is an interaction's own): chunk boundaries aligned
-        # to the interior/frontier split so every chunk's dst stays sorted
-        # (fast-path hint holds); static slices, so the transpose of the
-        # layout is copies and the cotangents of both interactions pass
-        # through it once
-        from ..ops.chunk import chunk_layout, chunked, take_rows
-
-        e_split = lg.e_split if lg.has_frontier_split else None
-        _, row_valid, K, chunk = chunk_layout(
-            lg.edge_src.shape[0], cfg.edge_chunk, e_split)
-        take = lambda x: chunked(take_rows(x, chunk, e_split), K, chunk)
+        # (nothing here is an interaction's own): the cotangents of both
+        # pass through the layout's transpose once (LocalGraph.edge_chunks)
         with scope("edge_gather"):
             Y_full = jnp.concatenate(
                 [Y[l] for l in range(cfg.l_max + 1)], axis=-1
             ).astype(dtype)                               # (E, S_Y)
-            edge_xs = (
-                take(lg.edge_src),
-                take(lg.edge_dst),
-                take(lg.edge_mask) & chunked(jnp.asarray(row_valid), K, chunk),
-                take(Y_full),
-                take(bessel),
-            )
+        edge_xs = lg.edge_chunks(cfg.edge_chunk, Y_full, bessel)
 
         for t, inter in enumerate(params["interactions"]):
             body = partial(self._interaction, lg=lg, edge_xs=edge_xs,
@@ -487,13 +471,11 @@ class MACE:
 
         # density projection A, accumulated over edge chunks (memory-bounded):
         # per chunk, outer(h_src, Y) -> one GEMM over every CG path -> radial
-        # weight -> ONE sorted segment sum carrying all Q path components.
-        from ..ops.chunk import scan_accumulate
-
+        # weight -> ONE sorted segment sum carrying all Q path components
+        # (LocalGraph.scan_edges).
         Wp3 = Wp.reshape(proj["S_h"], proj["S_Y"], nQ)
 
-        def chunk_body(A_acc, xs):
-            srcc, dstc, maskc, Yc, besc = xs
+        def chunk_message(srcc, dstc, maskc, Yc, besc):
             with scope("radial_mlp"):
                 Rc = mlp(inter["radial"], besc).reshape(chunk, len(paths), C)
             # factor the CG contraction: T[e,m,q] = sum_n Y[e,n] W[(m,n),q]
@@ -504,26 +486,10 @@ class MACE:
             with scope("edge_message"):
                 T = jnp.einsum("en,mnq->emq", Yc, Wp3)
                 M = jnp.einsum("emq,emc->eqc", T, hu[srcc])  # (E_c, Q, C)
-                M = M * Rc[:, q_path, :]                     # per-path radial
-            with scope("edge_aggregate"):
-                return (
-                    A_acc
-                    + fused_segment_sum(
-                        # sorted within every chunk by chunk_layout
-                        # construction; dispatches to the dst-tiled Pallas
-                        # scatter kernel on TPU (kernels/dispatch)
-                        M, dstc, n_nodes, maskc, indices_are_sorted=True,
-                        kernels=lg.kernels,
-                    ),
-                    None,
-                )
+                return M * Rc[:, q_path, :]                  # per-path radial
 
-        # the scan's own slicing of the chunked rows (and, transposed, the
-        # stacking of their cotangents) continues the layout's data path
-        with scope("edge_gather"):
-            A0 = jnp.zeros((n_nodes, nQ, C), dtype=dtype)
-            A_all = scan_accumulate(chunk_body, A0, edge_xs,
-                                    remat=cfg.remat)
+        A_all = lg.scan_edges(chunk_message, edge_xs, (nQ, C), dtype,
+                              remat=cfg.remat)
         # per-path output mixing on nodes (upstream's post-conv_tp linear):
         # A[l] = sum_paths A_all[:, :, cols(path)] @ W_path — (P_l*C) GEMMs
         with scope("node_linear"):
@@ -582,8 +548,8 @@ class MACE:
 
             body = remat_wrap(node_body, cfg.remat)
             if Kn == 1:
-                # single-chunk path keeps the remat mode too (same contract
-                # as scan_accumulate: a system just under one node chunk must
+                # single-chunk path keeps the remat mode too (the edge
+                # scan's contract: a system just under one node chunk must
                 # have the same backward memory bound as one just over)
                 _, out_flat = body(None, (A_ch[0], z_ch[0], h_ch[0]))
             else:

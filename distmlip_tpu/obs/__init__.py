@@ -25,9 +25,8 @@ untouched):
   bucket key; ``distmlip_compile_seconds`` +
   ``distmlip_compiles_total{kind=}``) and roofline rows (intensity /
   achieved vs peak / MFU) joined from the FLOP and memory planners.
-  CLIs: ``tools/roofline.py`` and ``tools/perf_gate.py`` (baseline
-  regression gate). Where device time goes, stage by stage, is read from
-  a profiler trace and the compiled step's own metadata:
+  CLI: ``tools/roofline.py``. Where device time goes, stage by stage, is
+  read from a profiler trace and the compiled step's own metadata:
   ``telemetry.set_tracing`` + ``telemetry.stage_tables()``.
 
 Plus the incident plane: :class:`~.slo.SLOMonitor` evaluates per-tenant

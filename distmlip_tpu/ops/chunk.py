@@ -1,4 +1,5 @@
-"""Edge-chunked scan scaffolding shared by the model zoo.
+"""Primitives of the chunked edge sum (``LocalGraph.edge_chunks`` /
+``scan_edges``, parallel/halo.py).
 
 Models bound per-edge memory by scanning over fixed-size edge chunks
 (MACE's density projection, eSCN's rotate/SO(2) pipeline). Per-edge rows
@@ -34,9 +35,9 @@ def chunk_layout(e_cap: int, chunk: int, e_split: int | None = None):
     """Chunk layout for edge scans, aligned to the interior/frontier
     boundary.
 
-    Returns ``(row_index, row_valid, K, chunk)``: build each scan input as
-    ``chunked(take_rows(x, chunk, e_split), K, chunk)`` and AND
-    ``row_valid`` into the edge mask. With an active split
+    Returns ``(row_index, row_valid, K, chunk)``: each scan input is
+    ``chunked(take_rows(x, chunk, e_split), K, chunk)`` and ``row_valid``
+    is ANDed into the edge mask (``LocalGraph.edge_chunks``). With an active split
     (``0 <= e_split < e_cap``) the two segments are padded to chunk
     multiples INDEPENDENTLY, so no chunk ever straddles the boundary —
     every chunk's dst rows stay nondecreasing and the
@@ -80,18 +81,6 @@ def take_rows(x, chunk: int, e_split: int | None = None):
         if pad:
             parts.append(jnp.broadcast_to(x[b - 1:b], (pad,) + x.shape[1:]))
     return jnp.concatenate(parts)
-
-
-def chunk_spec(e_cap: int, chunk: int):
-    """(n_chunks K, chunk size, pad rows) for scanning ``e_cap`` edges in
-    chunks of ``chunk`` (``chunk <= 0`` disables chunking: one chunk)."""
-    if e_cap == 0:
-        # edgeless graph (single atom / nothing within cutoff): one empty
-        # chunk; the body sees (0, ...) arrays and the segment sums yield 0
-        return 1, 0, 0
-    chunk = e_cap if chunk <= 0 else min(chunk, e_cap)
-    K = -(-e_cap // chunk)
-    return K, chunk, K * chunk - e_cap
 
 
 def chunked(x, K: int, chunk: int):

@@ -132,10 +132,6 @@ def layernorm(p, x, eps: float = 1e-5):
     return (x - mu) / jnp.sqrt(var + eps) * p["g"] + p["b"]
 
 
-def embedding_init(key, num: int, dim: int):
-    return {"w": jax.random.normal(key, (num, dim)) / np.sqrt(dim)}
-
-
 def gather_rows(table, idx):
     """Row gather whose GRADIENT accumulates in fp32.
 

@@ -16,12 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def gaussian_expansion(d, centers, width):
-    """exp(-(d - c)^2 / width^2) for each center. d: (...,), -> (..., C)."""
-    c = jnp.asarray(centers, dtype=d.dtype)
-    return jnp.exp(-((d[..., None] - c) ** 2) / (width**2))
-
-
 def spherical_bessel_basis(d, cutoff: float, num_basis: int):
     """Normalized j0 Bessel basis: sqrt(2/rc) * sin(n pi d / rc) / d.
 
@@ -36,17 +30,6 @@ def spherical_bessel_basis(d, cutoff: float, num_basis: int):
     out = jnp.sqrt(2.0 / rc) * jnp.sin(arg) / safe_x
     limit = jnp.sqrt(2.0 / rc) * n * jnp.pi / rc
     return jnp.where(small, limit, out)
-
-
-def fourier_expansion(x, max_f: int, interval: float = np.pi):
-    """[1/sqrt(2), cos(n pi x / L), sin(n pi x / L)] for n=1..max_f.
-
-    x: (...,) -> (..., 2*max_f + 1). CHGNet's angle basis over x = theta.
-    """
-    n = jnp.arange(1, max_f + 1, dtype=x.dtype)
-    arg = x[..., None] * n * jnp.pi / interval
-    const = jnp.full(x.shape + (1,), 1.0 / jnp.sqrt(2.0), dtype=x.dtype)
-    return jnp.concatenate([const, jnp.cos(arg), jnp.sin(arg)], axis=-1)
 
 
 def radial_bessel(d, frequencies, cutoff: float):

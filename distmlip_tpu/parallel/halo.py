@@ -10,23 +10,12 @@ drop-out-of-bounds scatter discards them. ``jax.grad`` transposes the
 ppermute automatically, which is exactly the reverse force flow the reference
 gets from torch autograd through device copies (reference pes.py:121-124).
 
-Two exchange implementations coexist behind ``halo_mode``:
-
-- ``"coalesced"`` (default): ONE ``ppermute`` per ring shift per sync
-  point, no matter how many feature arrays are refreshed together. All
-  arrays' masked payloads are flattened and concatenated into a single
-  flat buffer per shift (atom + bond features ride the same collective),
-  and all shifts' received rows land in one scatter. This is the payload
-  half of the overlap-aware pipeline: fewer, larger collectives expose the
-  latency XLA's async-collective scheduler can hide behind interior edge
-  compute (see ``LocalGraph.overlapped_edge_sum``).
-- ``"legacy"``: the historical per-shift, per-array loop — one gather /
-  ppermute / scatter round per (shift, array). Kept for A/B equivalence
-  testing; results are identical (set-scatter of the same rows).
-
-The two orders are interchangeable because send rows are always OWNED
-locals and recv slots are always HALO locals — no scatter ever feeds a
-later gather within one sync point.
+One exchange (``_exchange_round``): ONE ``ppermute`` per ring shift per sync
+point, no matter how many feature arrays are refreshed together (atom + bond
+features ride the same collective), and all shifts' received rows land in
+one scatter: fewer, larger collectives expose the latency XLA's
+async-collective scheduler can hide behind interior edge compute (see
+``LocalGraph.overlapped_edge_sum``).
 """
 
 from __future__ import annotations
@@ -41,42 +30,12 @@ from jax import lax
 from ..geometry import COORD_PRECISION
 from ..kernels.dispatch import (Gather, fused_edge_aggregate,
                                 fused_segment_sum)
+from ..ops.chunk import chunk_layout, chunked, scan_accumulate, take_rows
 from ..telemetry import scope
 
-HALO_MODES = ("coalesced", "legacy")
 
-
-def validate_halo_mode(halo_mode: str) -> str:
-    """Shared guard for every halo_mode entry point; returns the mode."""
-    if halo_mode not in HALO_MODES:
-        raise ValueError(
-            f"halo_mode={halo_mode!r}: expected one of {HALO_MODES}")
-    return halo_mode
-
-
-def _exchange(feats, send_idx, send_mask, recv_idx, shifts, axis_name):
-    """Legacy round: one gather->ppermute->scatter per shift (S collectives
-    per array)."""
-    if not shifts or axis_name is None:
-        return feats
-    n_dev = lax.axis_size(axis_name)
-    for si, shift in enumerate(shifts):
-        with scope(f"halo/shift{shift}"):
-            idx = send_idx[si]
-            mask = send_mask[si]
-            payload = feats[idx]
-            m = mask.astype(feats.dtype).reshape(
-                mask.shape + (1,) * (feats.ndim - 1))
-            payload = payload * m
-            perm = [(p, (p + shift) % n_dev) for p in range(n_dev)]
-            with scope("ppermute"):
-                received = lax.ppermute(payload, axis_name, perm)
-            feats = feats.at[recv_idx[si]].set(received, mode="drop")
-    return feats
-
-
-def _coalesced_round(groups, shifts, axis_name):
-    """Coalesced round: ONE ppermute per ring shift for ALL groups.
+def _exchange_round(groups, shifts, axis_name):
+    """One exchange round: ONE ppermute per ring shift for ALL groups.
 
     ``groups``: list of ``(feats, send_idx, send_mask, recv_idx)`` with
     per-shift tables shaped (S, H). Every group's masked payload is
@@ -86,8 +45,7 @@ def _coalesced_round(groups, shifts, axis_name):
     back on receive. Returns the updated feats list.
 
     Valid because send rows are owned locals and recv slots are halo
-    locals: gathering every payload before any scatter reads exactly the
-    rows the legacy sequential loop reads.
+    locals: no scatter feeds a later gather within the round.
     """
     if not shifts or axis_name is None:
         return [g[0] for g in groups]
@@ -135,10 +93,10 @@ class LocalGraph:
     Edge layout contract: ``edge_dst`` is nondecreasing within each of the
     interior ``[0, e_split)`` and frontier ``[e_split, e_cap)`` segments
     (``indices_are_sorted`` segment sums per segment — use
-    ``aggregate_edges``/``overlapped_edge_sum``, never a raw full-array
-    sorted segment sum when ``has_frontier_split``). Interior edges read
-    only owned rows; frontier edges read halo src rows. Same contract for
-    ``line_dst`` (unsplit, globally sorted).
+    ``aggregate_edges``/``overlapped_edge_sum``/``scan_edges``, never a raw
+    full-array sorted segment sum when ``has_frontier_split``). Interior
+    edges read only owned rows; frontier edges read halo src rows. Same
+    contract for ``line_dst`` (unsplit, globally sorted).
     """
 
     axis_name: str | None
@@ -173,7 +131,6 @@ class LocalGraph:
     # interior/frontier edge split (PartitionedGraph.e_split); < 0 or
     # == e_cap means unsplit
     e_split: int = -1
-    halo_mode: str = "coalesced"
     # batched multi-structure packing (PartitionedGraph.batch_size /
     # struct_id); 0 = unbatched. Models never need these — the per-
     # structure readout lives in the batched runtime — but they ride the
@@ -207,10 +164,7 @@ class LocalGraph:
     def halo_exchange(self, feats):
         """Refresh halo (from-section) rows of a node feature array."""
         with scope("halo_exchange"):
-            if self.halo_mode == "legacy":
-                return _exchange(feats, *self._node_tables(), self.shifts,
-                                 self.axis_name)
-            return _coalesced_round([(feats,) + self._node_tables()],
+            return _exchange_round([(feats,) + self._node_tables()],
                                     self.shifts, self.axis_name)[0]
 
     def bond_halo_exchange(self, feats):
@@ -218,49 +172,29 @@ class LocalGraph:
         if not self.has_bond_graph:
             return feats
         with scope("bond_halo_exchange"):
-            if self.halo_mode == "legacy":
-                return _exchange(feats, *self._bond_tables(), self.shifts,
-                                 self.axis_name)
-            return _coalesced_round([(feats,) + self._bond_tables()],
+            return _exchange_round([(feats,) + self._bond_tables()],
                                     self.shifts, self.axis_name)[0]
 
     def exchange_all(self, node_feats=(), bond_feats=()):
         """Refresh several feature arrays at one sync point.
 
-        In ``"coalesced"`` mode every array rides the SAME ppermute (one
-        collective per ring shift total — CHGNet's per-block atom+bond
-        refresh pays 1 instead of 2); in ``"legacy"`` mode this is just the
-        per-array loop. Returns ``(node_feats_out, bond_feats_out)`` tuples
-        in input order. Bond arrays pass through untouched when the graph
-        has no bond graph.
+        Every array rides the SAME ppermute (one collective per ring shift
+        total — CHGNet's per-block atom+bond refresh pays 1 instead of 2).
+        Returns ``(node_feats_out, bond_feats_out)`` tuples in input order.
+        Bond arrays pass through untouched when the graph has no bond
+        graph.
         """
-        node_feats = tuple(node_feats)
-        bond_feats = tuple(bond_feats)
-        use_bond = self.has_bond_graph
-        if self.axis_name is None or not self.shifts:
+        node_feats, bond_feats = tuple(node_feats), tuple(bond_feats)
+        groups = [(f,) + self._node_tables() for f in node_feats]
+        if self.has_bond_graph:
+            groups += [(f,) + self._bond_tables() for f in bond_feats]
+        if self.axis_name is None or not self.shifts or not groups:
             return node_feats, bond_feats
         with scope("halo_exchange_all"):
-            if self.halo_mode == "legacy":
-                nodes = tuple(
-                    _exchange(f, *self._node_tables(), self.shifts,
-                              self.axis_name) for f in node_feats)
-                bonds = tuple(
-                    _exchange(f, *self._bond_tables(), self.shifts,
-                              self.axis_name) if use_bond else f
-                    for f in bond_feats)
-                return nodes, bonds
-            groups = [(f,) + self._node_tables() for f in node_feats]
-            groups += [(f,) + self._bond_tables()
-                       for f in bond_feats if use_bond]
-            if not groups:
-                return node_feats, bond_feats
-            out = _coalesced_round(groups, self.shifts, self.axis_name)
-            nodes = tuple(out[: len(node_feats)])
-            if use_bond:
-                bonds = tuple(out[len(node_feats):])
-            else:
-                bonds = bond_feats
-            return nodes, bonds
+            out = _exchange_round(groups, self.shifts, self.axis_name)
+        n = len(node_feats)
+        return (tuple(out[:n]),
+                tuple(out[n:]) if self.has_bond_graph else bond_feats)
 
     def psum(self, x):
         if self.axis_name is None:
@@ -332,16 +266,60 @@ class LocalGraph:
                 out = part if out is None else out + part
         return out
 
-    def chunk_sorted(self, chunk: int) -> bool:
-        """Whether every ``chunk``-row slice of ``edge_dst`` is
-        nondecreasing — the per-chunk ``indices_are_sorted`` hint for the
-        edge-chunked models (MACE/eSCN). True when the layout is unsplit or
-        the split boundary lands on a chunk boundary; otherwise exactly one
-        chunk straddles the interior->frontier reset and the hint must be
-        dropped (correctness over the scatter fast path)."""
-        if not self.has_frontier_split or chunk <= 0:
-            return True
-        return self.e_split % chunk == 0
+    def edge_chunks(self, chunk: int, *per_edge):
+        """Per-edge rows in chunk order, for :meth:`scan_edges`.
+
+        Returns the ``(K, chunk, ...)`` tuple ``(src, dst, mask, *rows)``:
+        ``edge_src``, ``edge_dst``, ``edge_mask`` and each ``per_edge``
+        array laid out by ``ops/chunk.take_rows`` — static slices of the
+        one or two dst-sorted segments, each padded to a chunk multiple
+        with copies of its last row, so no chunk straddles the
+        interior/frontier boundary and every chunk's dst stays
+        nondecreasing. ``mask`` is ``edge_mask`` with the pad rows cut
+        out. ``chunk <= 0``: one chunk per segment. Lay rows out ONCE for
+        every scan that reads them: the cotangents of all of them pass
+        through the layout's transpose (copies) once.
+        """
+        e_split = self.e_split if self.has_frontier_split else None
+        _, row_valid, K, chunk = chunk_layout(
+            self.edge_src.shape[0], chunk, e_split)
+        take = lambda x: chunked(take_rows(x, chunk, e_split), K, chunk)
+        with scope("edge_gather"):
+            return (
+                take(self.edge_src),
+                take(self.edge_dst),
+                take(self.edge_mask)
+                & chunked(jnp.asarray(row_valid), K, chunk),
+                *[take(x) for x in per_edge],
+            )
+
+    def scan_edges(self, per_chunk, edge_xs, out_shape, dtype, *, remat):
+        """Chunked edge sum ((n_cap, *out_shape)): the messages
+        ``per_chunk(src, dst, mask, *rows) -> (chunk, *out_shape)`` of each
+        chunk of ``edge_xs`` (:meth:`edge_chunks`), segment-summed onto
+        their dst nodes and accumulated over the chunks, so per-edge
+        memory is O(chunk). Each chunk's dst is sorted by the layout's
+        construction, so the sum keeps the ``indices_are_sorted`` fast
+        path and dispatches to the dst-tiled Pallas scatter on TPU
+        (kernels/dispatch). ``remat`` (bool or policy name,
+        ``ops/chunk.remat_wrap``) checkpoints the chunk body.
+        """
+        def body(acc, xs):
+            srcc, dstc, maskc, *rows = xs
+            msg = per_chunk(srcc, dstc, maskc, *rows)
+            with scope("edge_aggregate"):
+                return (
+                    acc + fused_segment_sum(
+                        msg, dstc, self.n_cap, maskc,
+                        indices_are_sorted=True, kernels=self.kernels),
+                    None,
+                )
+
+        # the scan's own slicing of the chunked rows (and, transposed, the
+        # stacking of their cotangents) continues the layout's data path
+        with scope("edge_gather"):
+            acc0 = jnp.zeros((self.n_cap,) + tuple(out_shape), dtype=dtype)
+            return scan_accumulate(body, acc0, edge_xs, remat=remat)
 
     def overlapped_edge_sum(self, msg_fn, v_pre, v_post, edge_data=(),
                             mask=None):
@@ -455,21 +433,18 @@ class LocalGraph:
 
 
 def local_graph_from_stacked(
-    g, axis_name: str | None, halo_mode: str = "coalesced", kernels=None,
+    g, axis_name: str | None, kernels=None,
     kernels_diff_params: bool = True,
 ) -> tuple[LocalGraph, Any]:
     """Build a LocalGraph from shard-local (1, ...) slices of a PartitionedGraph.
 
     Returns (local_graph, positions_local) where positions keep their leading
-    1-axis squeezed. ``halo_mode`` selects the exchange implementation
-    (``"coalesced"`` | ``"legacy"``, see module docstring); ``kernels``
-    is the Pallas-kernel routing flag the aggregation helpers dispatch on
-    (None = env/backend default, False = pure XLA, "interpret" = the
-    chip-free interpreter kernels); ``kernels_diff_params`` is whether
-    kernel custom VJPs propagate into model weights (training True,
-    force/stress programs False).
+    1-axis squeezed. ``kernels`` is the Pallas-kernel routing flag the
+    aggregation helpers dispatch on (None = env/backend default, False =
+    pure XLA, "interpret" = the chip-free interpreter kernels);
+    ``kernels_diff_params`` is whether kernel custom VJPs propagate into
+    model weights (training True, force/stress programs False).
     """
-    validate_halo_mode(halo_mode)
     sq = lambda a: a[0] if a is not None and hasattr(a, "shape") and a.ndim >= 1 else a
     lg = LocalGraph(
         axis_name=axis_name,
@@ -478,7 +453,6 @@ def local_graph_from_stacked(
         e_cap=g.e_cap,
         b_cap=g.b_cap,
         e_split=g.e_split,
-        halo_mode=halo_mode,
         kernels=kernels,
         kernels_diff_params=kernels_diff_params,
         species=sq(g.species),

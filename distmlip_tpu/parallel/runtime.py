@@ -15,12 +15,7 @@ Fused site readout (``aux=True``): the model function instead returns
 ``(e_atoms, aux)`` where ``aux`` is a pytree of per-atom arrays (leading
 axis N_cap — e.g. CHGNet magmoms). The aux rides the SAME forward pass as
 the energy (``jax.value_and_grad(..., has_aux=True)``), so sitewise
-quantities no longer cost a second full forward the way the separate
-``make_site_fn`` program does.
-
-``halo_mode`` selects the halo-exchange implementation
-(``"coalesced"`` — one ppermute per ring shift per sync point — or the
-historical ``"legacy"`` per-array loop; see parallel/halo.py).
+quantities cost no second forward.
 """
 
 from __future__ import annotations
@@ -98,8 +93,7 @@ def graph_shardings(mesh: Mesh, graph: PartitionedGraph):
     )
 
 
-def make_total_energy(model_energy_fn, mesh: Mesh | None,
-                      halo_mode: str = "coalesced", aux: bool = False,
+def make_total_energy(model_energy_fn, mesh: Mesh | None, aux: bool = False,
                       kernels=None, kernels_diff_params: bool = True):
     """Sharded total-energy fn: (params, graph, positions, strain) -> scalar
     (or (scalar, aux_pytree) with ``aux=True``).
@@ -117,10 +111,6 @@ def make_total_energy(model_energy_fn, mesh: Mesh | None,
     return ``(e_atoms, aux)``; aux leaves keep their per-partition leading
     layout ((P, N_cap, ...) outside the shard_map).
     """
-    from .halo import validate_halo_mode
-
-    validate_halo_mode(halo_mode)  # fail at build, not first trace
-
     def local_energy(params, strain, graph_local, positions):
         axis = GRAPH_AXIS if mesh is not None else None
         if not kernels_diff_params:
@@ -132,7 +122,7 @@ def make_total_energy(model_energy_fn, mesh: Mesh | None,
             # ships. Cut ALL of them here, inside the shard-local fn.
             params = jax.lax.stop_gradient(params)
         lg, _ = local_graph_from_stacked(
-            graph_local, axis, halo_mode, kernels=kernels,
+            graph_local, axis, kernels=kernels,
             kernels_diff_params=kernels_diff_params)
         dtype = positions.dtype
         with scope("edge_geometry"), scope("apply_strain"):
@@ -174,64 +164,8 @@ def make_total_energy(model_energy_fn, mesh: Mesh | None,
     return total_energy
 
 
-def make_site_fn(model_site_fn, mesh: Mesh | None,
-                 halo_mode: str = "coalesced", kernels=None):
-    """Jitted sharded per-atom readout: (params, graph, positions) ->
-    (P, N_cap) site values (e.g. CHGNet magmoms — reference
-    PESCalculator_Dist's compute_magmom surface, implementations/matgl/
-    ase.py:53-127). Halo rows are refreshed in-jit like the energy path;
-    reassemble owned rows with HostGraphData.gather_owned.
-
-    .. deprecated::
-        This runs a SEPARATE forward pass from the energy program — for
-        magmom-every-step MD that doubles device time. Models exposing
-        ``energy_and_aux_fn`` (CHGNet) now ride the sitewise readout on the
-        energy forward via ``make_potential_fn(..., aux=True)``;
-        DistPotential prefers that path automatically. make_site_fn remains
-        for models without a fused readout and as the parity oracle for the
-        fused path (tests/test_halo_overlap.py)."""
-    from .halo import validate_halo_mode
-
-    validate_halo_mode(halo_mode)
-
-    def local_site(params, graph_local, positions):
-        axis = GRAPH_AXIS if mesh is not None else None
-        # forward-only readout: no grads at all, so no param cotangents
-        lg, _ = local_graph_from_stacked(graph_local, axis, halo_mode,
-                                         kernels=kernels,
-                                         kernels_diff_params=False)
-        pos = lg.halo_exchange(positions[0])
-        with scope("model_site"):
-            return model_site_fn(params, lg, pos)[None]
-
-    if mesh is None:
-        @jax.jit
-        def site_fn(params, graph, positions):
-            if graph.num_partitions != 1:
-                raise ValueError(
-                    f"mesh=None requires a single-partition graph, got "
-                    f"P={graph.num_partitions}; pass mesh=graph_mesh(P).")
-            return local_site(params, graph, positions)
-        return site_fn
-
-    @jax.jit
-    def site_fn(params, graph, positions):
-        axes = mesh_row_axes(mesh)
-        sharded = jax.shard_map(
-            local_site,
-            mesh=mesh,
-            in_specs=(P(), graph_in_specs(graph, axes), P(axes)),
-            out_specs=P(axes),
-            check_vma=False,
-        )
-        return sharded(params, graph, positions)
-
-    return site_fn
-
-
 def make_potential_fn(model_energy_fn, mesh: Mesh | None,
-                      compute_stress: bool = True,
-                      halo_mode: str = "coalesced", aux: bool = False,
+                      compute_stress: bool = True, aux: bool = False,
                       kernels=None):
     """Jitted (params, graph, positions) -> dict(energy, forces, stress).
 
@@ -241,8 +175,7 @@ def make_potential_fn(model_energy_fn, mesh: Mesh | None,
     ``(e_atoms, aux)`` and the result dict gains an ``"aux"`` pytree of
     (P, N_cap, ...) per-atom outputs computed on the SAME forward pass.
     """
-    total_energy = make_total_energy(model_energy_fn, mesh,
-                                     halo_mode=halo_mode, aux=aux,
+    total_energy = make_total_energy(model_energy_fn, mesh, aux=aux,
                                      kernels=kernels,
                                      kernels_diff_params=False)
 
@@ -276,8 +209,7 @@ def make_potential_fn(model_energy_fn, mesh: Mesh | None,
 
 
 def make_packed_energy_fn(model_energy_fn, mesh: Mesh | None = None,
-                          diff_params: bool = True,
-                          halo_mode: str = "coalesced", kernels=None):
+                          diff_params: bool = True, kernels=None):
     """Per-structure energies of a packed batch, params-DIFFERENTIABLE.
 
     ``(params, graph, positions, strain) -> (B_total,)`` energies, where
@@ -294,39 +226,61 @@ def make_packed_energy_fn(model_energy_fn, mesh: Mesh | None = None,
     the update). Not jitted here: callers embed it inside their own jitted
     step (one program per accumulation window).
     """
-    local_energy = _local_batched_energy(model_energy_fn, aux=False,
-                                         halo_mode=halo_mode,
-                                         kernels=kernels,
-                                         diff_params=diff_params)
+    energy = _packed_energy(
+        _local_batched_energy(model_energy_fn, aux=False, kernels=kernels,
+                              diff_params=diff_params),
+        mesh, "make_packed_energy_fn")
 
+    def packed_energy(params, graph, positions, strain):
+        return energy(params, strain, graph, positions)[0]
+
+    return packed_energy
+
+
+def _packed_energy(local_energy, mesh: Mesh | None, who: str):
+    """``local_energy`` (see ``_local_batched_energy``) over a whole packed
+    graph: ``(params, strain, graph, positions) -> (energies (B_total,),
+    aux)``, on the single-partition pack (``mesh=None``: no collectives)
+    or under ``shard_map`` on the 2-D mesh the graph was packed for. The
+    mesh's axes are checked here, the graph's placement at trace time;
+    ``who`` names the public factory in the messages."""
     if mesh is None:
-        def packed_energy(params, graph, positions, strain):
+        def energy(params, strain, graph, positions):
             if graph.num_partitions != 1 or graph.batch_size < 1:
                 raise ValueError(
-                    "make_packed_energy_fn(mesh=None) requires a "
-                    f"single-partition packed graph (got "
-                    f"P={graph.num_partitions}, "
+                    f"{who}(mesh=None) requires a single-partition packed "
+                    f"graph (got P={graph.num_partitions}, "
                     f"batch_size={graph.batch_size}); build it with "
                     "pack_structures(), or pass the 2-D mesh the graph "
                     "was packed for.")
-            return local_energy(params, strain, graph, positions)[0]
-        return packed_energy
+            return local_energy(params, strain, graph, positions)
+        return energy
 
+    # the shard_map addresses BOTH named axes (strain/energies shard over
+    # "batch"); a user-built mesh missing either name would only fail deep
+    # inside jax's axis resolution at first trace
     missing = [ax for ax in (BATCH_AXIS, SPATIAL_AXIS)
                if ax not in mesh.axis_names]
     if missing:
         raise ValueError(
-            f"make_packed_energy_fn needs a mesh with named axes "
+            f"{who} needs a mesh with named axes "
             f"({BATCH_AXIS!r}, {SPATIAL_AXIS!r}); this mesh "
             f"{tuple(mesh.axis_names)} lacks {missing} — build it with "
             f"parallel.device_mesh(batch, spatial).")
     mesh_bp, mesh_sp = mesh_shape(mesh)
 
-    def packed_energy(params, graph, positions, strain):
+    def local(params, strain, graph_local, positions):
+        energies, aux_out = local_energy(params, strain, graph_local,
+                                         positions)
+        # restore the leading shard axis so aux rows concat back to the
+        # packed (P, N_cap, ...) layout
+        return energies, jax.tree.map(lambda a: a[None], aux_out)
+
+    def energy(params, strain, graph, positions):
         if graph.batch_size < 1 or graph.struct_id is None:
             raise ValueError(
-                "make_packed_energy_fn requires a packed graph "
-                "(batch_size >= 1); build it with pack_structures().")
+                f"{who} requires a packed graph (batch_size >= 1); build "
+                "it with pack_structures().")
         if graph.batch_parts != mesh_bp or graph.spatial_size != mesh_sp:
             raise ValueError(
                 f"graph placement {graph.batch_parts}x{graph.spatial_size} "
@@ -334,21 +288,19 @@ def make_packed_energy_fn(model_energy_fn, mesh: Mesh | None = None,
                 f"batch_parts={mesh_bp}, spatial_parts={mesh_sp}.")
         axes = mesh_row_axes(mesh)
         row = P(axes)
-
-        def local_e(params, strain, graph_local, positions):
-            return local_energy(params, strain, graph_local, positions)[0]
-
-        sharded = jax.shard_map(
-            local_e, mesh=mesh,
+        # strain shards over batch only: every spatial slab of a batch row
+        # sees its row's (B_local, 3, 3) slice
+        return jax.shard_map(
+            local, mesh=mesh,
             in_specs=(P(), P(BATCH_AXIS), graph_in_specs(graph, axes), row),
-            out_specs=P(BATCH_AXIS), check_vma=False)
-        return sharded(params, strain, graph, positions)
+            out_specs=(P(BATCH_AXIS), row), check_vma=False,
+        )(params, strain, graph, positions)
 
-    return packed_energy
+    return energy
 
 
-def _local_batched_energy(model_energy_fn, aux, halo_mode="coalesced",
-                          kernels=None, diff_params=False):
+def _local_batched_energy(model_energy_fn, aux, kernels=None,
+                          diff_params=False):
     """Shard-local batched energy: strain -> halo exchange -> model ->
     per-structure readout. Shared by the single-device packed path and the
     2-D mesh path (where it runs inside shard_map with the spatial axis
@@ -371,8 +323,7 @@ def _local_batched_energy(model_energy_fn, aux, halo_mode="coalesced",
             # cut param-bound kernel-VJP cotangents before the mesh
             # boundary (see make_total_energy)
             params = jax.lax.stop_gradient(params)
-        lg, _ = local_graph_from_stacked(graph_local, axis, halo_mode,
-                                         kernels=kernels,
+        lg, _ = local_graph_from_stacked(graph_local, axis, kernels=kernels,
                                          kernels_diff_params=diff_params)
         B = graph_local.batch_size
         dtype = positions.dtype
@@ -448,84 +399,16 @@ def make_batched_potential_fn(model_energy_fn, compute_stress: bool = True,
     One executable family covers pure batch-parallel (B x 1), the 1-D ring
     (1 x S) and the mixed B x S placement.
     """
-    local_energy = _local_batched_energy(model_energy_fn, aux,
-                                         kernels=kernels)
+    energy = _packed_energy(
+        _local_batched_energy(model_energy_fn, aux, kernels=kernels),
+        mesh, "make_batched_potential_fn")
 
-    if mesh is None:
-        def batched_energy(params, strain, graph, positions):
-            energies, aux_out = local_energy(params, strain, graph,
-                                             positions)
-            return jnp.sum(energies), (energies, aux_out)
-
-        def check(graph):
-            if graph.num_partitions != 1 or graph.batch_size < 1:
-                raise ValueError(
-                    "make_batched_potential_fn(mesh=None) requires a "
-                    f"single-partition packed graph (got "
-                    f"P={graph.num_partitions}, "
-                    f"batch_size={graph.batch_size}); build it with "
-                    "pack_structures(), or pass the 2-D mesh the graph "
-                    "was packed for.")
-    else:
-        # the batched shard_map addresses BOTH named axes (strain/energies
-        # shard over "batch"); a user-built mesh missing either name would
-        # only fail deep inside jax's axis resolution at first trace
-        missing = [ax for ax in (BATCH_AXIS, SPATIAL_AXIS)
-                   if ax not in mesh.axis_names]
-        if missing:
-            raise ValueError(
-                f"make_batched_potential_fn needs a mesh with named axes "
-                f"({BATCH_AXIS!r}, {SPATIAL_AXIS!r}); this mesh "
-                f"{tuple(mesh.axis_names)} lacks {missing} — build it "
-                f"with parallel.device_mesh(batch, spatial).")
-        mesh_bp, mesh_sp = mesh_shape(mesh)
-
-        def batched_energy(params, strain, graph, positions):
-            axes = mesh_row_axes(mesh)
-            row = P(axes)
-            # strain shards over batch only: every spatial slab of a batch
-            # row sees its row's (B_local, 3, 3) slice
-            in_specs = (P(), P(BATCH_AXIS), graph_in_specs(graph, axes), row)
-            if aux:
-                def local_aux(params, strain, graph_local, positions):
-                    energies, aux_out = local_energy(
-                        params, strain, graph_local, positions)
-                    # restore the leading shard axis so aux rows concat
-                    # back to the packed (P, N_cap, ...) layout
-                    return energies, jax.tree.map(lambda a: a[None], aux_out)
-
-                sharded = jax.shard_map(
-                    local_aux, mesh=mesh, in_specs=in_specs,
-                    out_specs=(P(BATCH_AXIS), row), check_vma=False)
-                energies, aux_out = sharded(params, strain, graph, positions)
-            else:
-                def local_e(params, strain, graph_local, positions):
-                    return local_energy(params, strain, graph_local,
-                                        positions)[0]
-
-                sharded = jax.shard_map(
-                    local_e, mesh=mesh, in_specs=in_specs,
-                    out_specs=P(BATCH_AXIS), check_vma=False)
-                energies = sharded(params, strain, graph, positions)
-                aux_out = None
-            return jnp.sum(energies), (energies, aux_out)
-
-        def check(graph):
-            if graph.batch_size < 1 or graph.struct_id is None:
-                raise ValueError(
-                    "make_batched_potential_fn requires a packed graph "
-                    "(batch_size >= 1); build it with pack_structures().")
-            if (graph.batch_parts != mesh_bp
-                    or graph.spatial_size != mesh_sp):
-                raise ValueError(
-                    f"graph placement {graph.batch_parts}x"
-                    f"{graph.spatial_size} does not match the "
-                    f"{mesh_bp}x{mesh_sp} mesh; pack with "
-                    f"batch_parts={mesh_bp}, spatial_parts={mesh_sp}.")
+    def batched_energy(params, strain, graph, positions):
+        energies, aux_out = energy(params, strain, graph, positions)
+        return jnp.sum(energies), (energies, aux_out)
 
     @jax.jit
     def potential(params, graph, positions):
-        check(graph)
         B_total = graph.batch_parts * graph.batch_size
         strain = jnp.zeros((B_total, 3, 3), dtype=positions.dtype)
         grad_fn = jax.value_and_grad(

@@ -1,7 +1,7 @@
 """Typed per-step telemetry records.
 
 ``StepRecord`` is the one schema every producer (DistPotential, DeviceMD,
-MolecularDynamics, Relaxer, bench.py) emits and every sink consumes. It
+MolecularDynamics, Relaxer) emits and every sink consumes. It
 replaces the untyped ``last_timings`` dicts: a record carries the per-phase
 host timings, the graph shape and capacity/padding occupancy, per-partition
 halo send/recv volumes, compile-cache and graph-cache hit/miss flags, and
@@ -98,7 +98,6 @@ class StepRecord:
     #                                  (no JIT trace/compile on this replica)
 
     # --- halo pipeline + device-program cost model ---
-    halo_mode: str = ""              # coalesced | legacy ("" = unknown)
     collective_count: int = 0        # collectives in the traced step program
     # static contract audit of the step program (distmlip_tpu.analysis:
     # one cached abstract trace per runtime build, all registered passes)

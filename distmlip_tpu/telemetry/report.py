@@ -115,12 +115,8 @@ class Report:
                 f"rebuilds: total={c['rebuilds_total']} on_device={n_dev} "
                 f"host={n_host} overflow_fallbacks={ovf} "
                 f"(overflow rate {ovf / max(c['rebuilds_total'], 1):.1%})")
-        if "halo_modes" in c or "collective_count" in c:
-            bits = []
-            if "halo_modes" in c:
-                bits.append(f"halo_mode={','.join(c['halo_modes'])}")
-            if "collective_count" in c:
-                bits.append(f"collectives/step={c['collective_count']}")
+        if "collective_count" in c:
+            bits = [f"collectives/step={c['collective_count']}"]
             if "mean_frontier_edge_frac" in c:
                 bits.append(
                     f"frontier_edge_frac={c['mean_frontier_edge_frac']:.3f}")
@@ -353,9 +349,6 @@ def aggregate(
         if sp_imb:
             c["max_spatial_halo_imbalance"] = max(sp_imb)
     # overlap pipeline + cost model (0-valued fields = producer didn't know)
-    modes = sorted({r.halo_mode for r in records if r.halo_mode})
-    if modes:
-        c["halo_modes"] = modes
     colls = [r.collective_count for r in records if r.collective_count > 0]
     if colls:
         c["collective_count"] = max(colls)

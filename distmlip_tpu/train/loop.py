@@ -127,7 +127,7 @@ class Trainer:
     def _probe_loader(self, samples, cutoff, B, lk, needs):
         lk = dict(lk)
         # a caller may hand a precomputed dataset census through
-        # loader_kwargs (e.g. bench.py's naive-vs-cost-model A/B shares
+        # loader_kwargs (e.g. a naive-vs-cost-model A/B shares
         # one census across two Trainers); an in-sizing-loop census from
         # a previous candidate wins — both are the same dataset property
         needs = needs if needs is not None else lk.pop(

@@ -2,7 +2,7 @@
 
 A MACE step at published width compiles for minutes and every fresh
 machine starts with no compiled code, so each entry point that compiles on
-the chip (``chip_smoke.py``, ``bench.py``, ``tools/load_test.py``) calls
+the chip (``chip_smoke.py``, ``benchmark/run.py``, ``tools/load_test.py``) calls
 :func:`enable_compile_cache` before its first jit.
 """
 

@@ -126,16 +126,6 @@ def pair_flops(cfg, n_atoms: float, n_edges: float) -> float:
     return 50.0 * n_edges  # elementwise pair math; negligible by design
 
 
-def edge_aggregate_flops(n_edges: float, w_in: float, w_out: float) -> float:
-    """Analytic FLOPs of the canonical gather -> edge-MLP -> scatter
-    pipeline (the tools/kernel_bench.py workload): one (w_in, w_out) GEMM
-    per edge (2*w_in*w_out), the silu gate (~4*w_in) and the masked
-    dst-scatter accumulation (2*w_out). Shared by the fused and unfused
-    arms so their MFU numbers are comparable."""
-    return float(n_edges) * (2.0 * float(w_in) * float(w_out)
-                             + 4.0 * float(w_in) + 2.0 * float(w_out))
-
-
 def escn_flops(cfg, n_atoms: float, n_edges: float) -> float:
     """eSCN/UMA: Wigner rotations + SO(2) convolutions per edge."""
     C = getattr(cfg, "channels", getattr(cfg, "sphere_channels", 128))

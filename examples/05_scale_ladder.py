@@ -210,7 +210,8 @@ def config5():
     boundary exactly where DCN would sit; jax.devices() spans hosts by
     construction, so the same program runs unchanged on a real pod
     slice). Validates 16-way == 4-way at the north-star atom count; model
-    is CPU-mesh-sized (the real-chip shape is bench.py's).
+    is CPU-mesh-sized (the real-chip shapes are the benchmark's cells,
+    benchmark/configs/).
 
     On a TPU this becomes the north-star TIMING run instead: the full 1,000,188-atom box through the MP-0-faithful MACE
     (128ch, l_max=a_lmax=3, correlation 3) in bfloat16 on ONE chip, edge-

@@ -15,6 +15,10 @@ exercised by ``chip_smoke.py``, not by pytest.
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# before numpy loads OpenBLAS: its threads spin while they wait, so six
+# xdist workers with eight threads each on eight cores ran 116 eigh calls
+# of the BFGS relaxer test in 730 s against 1.5 s with one thread each (PR 30)
+os.environ.setdefault("OPENBLAS_NUM_THREADS", "1")
 
 import jax
 import numpy as np

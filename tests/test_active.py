@@ -599,8 +599,18 @@ def test_active_loop_end_to_end_chgnet(rng, tmp_path):
 
     sink = CaptureSink()
     ens = EnsembleBatchedPotential(model, members, skin=0.3)
-    engine = ServeEngine(ens, max_batch=4, max_wait_s=0.005,
+    # the batch shapes are this test's, not the scheduler thread's timing:
+    # under a max-wait no request reaches, a micro-batch leaves only when
+    # max_batch requests are queued or on drain(), so the ten requests
+    # form 4 + 4 + 2 and the four in-flight ones the 4 compiled by then
+    # (every batch size is a bucket of its own, hence a compile)
+    engine = ServeEngine(ens, max_batch=4, max_wait_s=600.0,
                          telemetry=Telemetry([sink]))
+
+    def served_batches():
+        return [(r.batch_size, r.bucket_key) for r in sink.records
+                if r.kind == "serve_batch"]
+
     buf = ReplayBuffer(capacity=64, directory=str(tmp_path / "buf"))
     loop = ActiveLoop(
         engine, ens, buf,
@@ -621,8 +631,11 @@ def test_active_loop_end_to_end_chgnet(rng, tmp_path):
 
     pool = [traffic() for _ in range(10)]
     futs = [loop.submit(a) for a in pool]
+    assert engine.drain(timeout=300)
     for f in futs:
         assert np.isfinite(f.result(timeout=300)["energy"])
+    warm = served_batches()
+    assert sorted(b for b, _ in warm) == [2, 4, 4]
     loop.pump()
     assert len(buf) >= 6                   # high-variance traffic buffered
     var_before = float(np.mean(buf.variances()))
@@ -635,6 +648,8 @@ def test_active_loop_end_to_end_chgnet(rng, tmp_path):
     assert tick is not None and tick["shipped"], tick
     for f in inflight:
         assert np.isfinite(f.result(timeout=300)["energy"])
+    (swapped_over,) = served_batches()[len(warm):]
+    assert swapped_over in warm and swapped_over[0] == 4
     assert engine.compile_count == compile_before   # ZERO recompiles
     assert loop.stats.swaps == 1 and engine.stats.failed == 0
 
@@ -643,7 +658,9 @@ def test_active_loop_end_to_end_chgnet(rng, tmp_path):
     assert float(np.mean(post)) < 0.5 * var_before, (
         float(np.mean(post)), var_before)
     # serving now runs the fine-tuned primary (parity with a fresh pot)
-    served = loop.submit(pool[0]).result(timeout=300)
+    served = loop.submit(pool[0])
+    assert engine.drain(timeout=300)
+    served = served.result(timeout=300)
     ref = BatchedPotential(model, ens.params).calculate([pool[0]])[0]
     assert served["energy"] == pytest.approx(ref["energy"], abs=1e-5)
     engine.close()

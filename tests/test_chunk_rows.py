@@ -1,9 +1,12 @@
 """The chunk-ordered per-edge rows of the edge scans (ops/chunk.take_rows):
 the values of the gather through ``row_index`` that it replaced, a
 transpose without a scatter-add, and models whose energy-and-forces
-programs no longer scatter-add over the edge list."""
+programs no longer scatter-add over the edge list; then the seam the
+models reach them through (``LocalGraph.edge_chunks`` / ``scan_edges``)
+against ``LocalGraph.aggregate_edges`` of the unchunked messages."""
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +18,8 @@ from distmlip_tpu.calculators import Atoms, DistPotential
 from distmlip_tpu.models import ESCN, ESCNConfig, MACE, MACEConfig
 from distmlip_tpu.neighbors import neighbor_list_numpy
 from distmlip_tpu.ops.chunk import chunk_layout, take_rows
-from distmlip_tpu.parallel import graph_mesh, make_potential_fn
+from distmlip_tpu.parallel import GRAPH_AXIS, graph_mesh, make_potential_fn
+from distmlip_tpu.parallel.halo import LocalGraph
 from distmlip_tpu.partition import (CapacityPolicy, build_partitioned_graph,
                                     build_plan)
 from distmlip_tpu.telemetry import set_tracing, stage_tables
@@ -224,3 +228,159 @@ def test_chunked_forces_match_unchunked_under_a_frontier_split():
     assert abs(e0 - e1) < 1e-5 * max(1.0, abs(e0))
     np.testing.assert_allclose(f0, f1, atol=1e-5)
     np.testing.assert_allclose(s0, s1, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# the seam: LocalGraph.edge_chunks / scan_edges
+# ---------------------------------------------------------------------------
+
+N_CAP, FEAT = 20, 3
+# layout -> (e_cap, e_split): segments of 36 and 60 rows, or one of 96
+SEAM_LAYOUTS = {"unsplit": (96, 96), "split": (96, 36), "edgeless": (0, 0)}
+# 12 divides 36, 60 and 96; 25 divides none of them
+SEAM_CHUNKS = {"unchunked": 0, "divides_segments": 12, "remainders": 25,
+               "above_e_cap": 1000}
+SEAM_CASES = [(layout, chunk) for layout in ("unsplit", "split")
+              for chunk in SEAM_CHUNKS] + [("edgeless", "remainders")]
+
+
+def seam_arrays(rng, e_cap, e_split, shards=None):
+    """Toy per-edge and per-node arrays (a leading axis of ``shards``
+    when given): dst nondecreasing within each segment and restarting at
+    the split, a few edges masked out, the LAST row of each segment a
+    real edge (so a pad row that kept its mask would count twice)."""
+    lead = () if shards is None else (shards,)
+    bounds = [b for b in (0, e_split, e_cap) if b <= e_cap]
+    dst = np.zeros(lead + (e_cap,), np.int32)
+    for a, b in zip(bounds, bounds[1:]):
+        dst[..., a:b] = np.sort(rng.integers(0, N_CAP, lead + (b - a,)))
+    mask = rng.random(lead + (e_cap,)) < 0.8
+    for b in bounds[1:]:
+        mask[..., b - 1:b] = True
+    return dict(
+        src=rng.integers(0, N_CAP, lead + (e_cap,)).astype(np.int32),
+        dst=dst, mask=mask,
+        w=rng.normal(size=lead + (e_cap, FEAT)).astype(np.float32),
+        h=rng.normal(size=lead + (N_CAP, FEAT)).astype(np.float32),
+        r=rng.normal(size=lead + (N_CAP, FEAT)).astype(np.float32))
+
+
+def seam_graph(a, e_split, axis_name=None):
+    return LocalGraph(
+        axis_name=axis_name, shifts=(), n_cap=N_CAP,
+        e_cap=a["src"].shape[0], b_cap=0, species=None, node_mask=None,
+        owned_mask=None, edge_src=a["src"], edge_dst=a["dst"],
+        edge_offset=None, edge_mask=a["mask"], halo_send_idx=None,
+        halo_send_mask=None, halo_recv_idx=None, lattice=None,
+        e_split=e_split, kernels=False)
+
+
+def seam_and_reference(a, e_split, chunk, axis_name=None):
+    """Two functions of (node rows, per-edge rows), each returning (a
+    scalar for gradients, the sum onto nodes): the sum through the seam,
+    and aggregate_edges over the unchunked messages."""
+    lg = seam_graph(a, e_split, axis_name)
+
+    def through_seam(h, w):
+        def per_chunk(srcc, dstc, maskc, wc):
+            return jnp.tanh(h[srcc]) * wc * (1.0 + h[dstc])
+
+        out = lg.scan_edges(per_chunk, lg.edge_chunks(chunk, w),
+                            (FEAT,), h.dtype, remat=True)
+        return jnp.sum(out * a["r"]), out
+
+    def unchunked(h, w):
+        msg = jnp.tanh(h[lg.edge_src]) * w * (1.0 + h[lg.edge_dst])
+        out = lg.aggregate_edges(msg, lg.edge_mask)
+        return jnp.sum(out * a["r"]), out
+
+    return through_seam, unchunked
+
+
+def sum_and_grads(fn, a):
+    """(sum onto nodes, its gradient by node rows, by per-edge rows)."""
+    (_, out), (g_h, g_w) = jax.value_and_grad(
+        fn, argnums=(0, 1), has_aux=True)(a["h"], a["w"])
+    return out, g_h, g_w
+
+
+def assert_seam_matches(got, want):
+    for name, g, w in zip(("sum", "d/d node rows", "d/d edge rows"),
+                          got, want):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("layout,chunk", SEAM_CASES)
+def test_seam_sum_equals_aggregate_edges(rng, layout, chunk):
+    (e_cap, e_split), chunk = SEAM_LAYOUTS[layout], SEAM_CHUNKS[chunk]
+    a = jax.tree.map(jnp.asarray, seam_arrays(rng, e_cap, e_split))
+    lg = seam_graph(a, e_split)
+    assert lg.has_frontier_split == (layout == "split")
+    # the layout call: src, dst, mask & row_valid first, then the rows
+    srcc, dstc, maskc, wc = lg.edge_chunks(chunk, a["w"])
+    K, c = srcc.shape
+    assert dstc.shape == maskc.shape == (K, c) and wc.shape == (K, c, FEAT)
+    if e_cap:
+        segments = [36, 60] if layout == "split" else [96]
+        per = max(segments) if chunk <= 0 else min(chunk, max(segments))
+        assert (K, c) == (sum(-(-n // per) for n in segments), per)
+    # no pad row keeps its mask; every real edge keeps its own
+    assert int(maskc.sum()) == int(a["mask"].sum())
+    assert np.array_equal(np.asarray(wc)[np.asarray(maskc)],
+                          np.asarray(a["w"])[np.asarray(a["mask"])])
+    # no chunk straddles the split: dst is nondecreasing inside every one
+    assert np.all(np.diff(np.asarray(dstc), axis=1) >= 0)
+    if layout == "split":
+        assert np.any(np.diff(np.asarray(a["dst"])) < 0)
+
+    through_seam, unchunked = seam_and_reference(a, e_split, chunk)
+    got, want = sum_and_grads(through_seam, a), sum_and_grads(unchunked, a)
+    assert_seam_matches(got, want)
+    if e_cap:
+        assert np.abs(np.asarray(want[0])).max() > 0.1
+        # every sum onto nodes keeps the sorted fast path
+        sums = [eqn for eqn in scatter_adds(
+            jax.make_jaxpr(through_seam)(a["h"], a["w"]))
+            if eqn.invars[0].aval.shape[0] == N_CAP]
+        assert sums and all(e.params["indices_are_sorted"] for e in sums)
+    else:
+        assert not np.asarray(got[0]).any()
+
+
+@pytest.mark.parametrize("layout,chunk", SEAM_CASES)
+def test_seam_sum_under_shard_map(rng, layout, chunk):
+    """Four shards with their own rows, one static layout."""
+    from jax.sharding import PartitionSpec as P
+
+    (e_cap, e_split), chunk = SEAM_LAYOUTS[layout], SEAM_CHUNKS[chunk]
+    a = seam_arrays(rng, e_cap, e_split, shards=4)
+
+    def local(a, forward_only=False):
+        a = jax.tree.map(lambda x: x[0], a)
+        through_seam, unchunked = seam_and_reference(a, e_split, chunk,
+                                                     GRAPH_AXIS)
+        if forward_only:
+            return through_seam(a["h"], a["w"])[1][None]
+        dstc = seam_graph(a, e_split, GRAPH_AXIS).edge_chunks(chunk)[1]
+        return jax.tree.map(lambda x: x[None], (
+            sum_and_grads(through_seam, a), sum_and_grads(unchunked, a),
+            dstc))
+
+    def sharded(fn):
+        return jax.jit(jax.shard_map(
+            fn, mesh=graph_mesh(4), in_specs=P(GRAPH_AXIS),
+            out_specs=P(GRAPH_AXIS), check_vma=False))
+
+    got, want, dstc = sharded(local)(a)
+    assert got[0].shape == (4, N_CAP, FEAT)
+    assert_seam_matches(got, want)
+    assert np.all(np.diff(np.asarray(dstc), axis=-1) >= 0)
+    if e_cap:
+        # the shards' sums differ: each read its own rows
+        assert np.abs(np.asarray(got[0][0] - got[0][1])).max() > 0.1
+        sums = [e for e in scatter_adds(jax.make_jaxpr(sharded(
+            partial(local, forward_only=True)))(a))
+            if e.invars[0].aval.shape[0] == N_CAP]
+        assert sums and all(e.params["indices_are_sorted"] for e in sums)

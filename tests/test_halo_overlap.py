@@ -2,16 +2,14 @@
 interior/frontier edge split, and the fused site readout.
 
 Three certification surfaces:
-- jaxpr-level collective counts — the coalesced path emits exactly ONE
-  ppermute per exchange round, and a full magmom MD step pays >= 2x fewer
-  collectives than the legacy (per-array exchange + separate site forward)
-  pipeline;
-- numerical equivalence — halo_mode="coalesced" / "legacy" /
-  single-partition agree on energy/forces/stress, gradients still flow to
-  the owning partition, and the interior/frontier reorder is an exact
-  permutation of the unsplit edge list;
-- fused readout parity — energy_and_aux_fn magmoms match make_site_fn
-  without a second forward pass.
+- jaxpr-level collective counts — the exchange emits exactly ONE ppermute
+  per round, and the fused magmom readout adds no forward pass;
+- numerical equivalence — the multi-partition program agrees with the
+  single-partition one (no exchange at all) on energy/forces/stress,
+  gradients still flow to the owning partition, and the interior/frontier
+  reorder is an exact permutation of the unsplit edge list;
+- fused readout parity — energy_and_aux_fn magmoms of the two-partition
+  program match magmom_fn on the single-partition graph.
 """
 
 import jax
@@ -24,7 +22,7 @@ from distmlip_tpu.models.chgnet import CHGNet, CHGNetConfig
 from distmlip_tpu.models.pair import PairConfig, PairPotential
 from distmlip_tpu.neighbors import neighbor_list_numpy
 from distmlip_tpu.parallel import (GRAPH_AXIS, graph_in_specs, graph_mesh,
-                                   make_potential_fn, make_site_fn)
+                                   make_potential_fn)
 from distmlip_tpu.parallel.audit import (count_collectives,
                                          ppermutes_by_scope)
 from distmlip_tpu.parallel.halo import local_graph_from_stacked
@@ -82,7 +80,7 @@ def test_coalesced_one_ppermute_per_exchange_round(rng, params):
 
     def forward(params, graph, positions):
         def local(g, pos):
-            lg, _ = local_graph_from_stacked(g, GRAPH_AXIS, "coalesced")
+            lg, _ = local_graph_from_stacked(g, GRAPH_AXIS)
             return MODEL.energy_fn(params, lg, pos[0])[None]
 
         return jax.shard_map(
@@ -104,28 +102,6 @@ def test_coalesced_one_ppermute_per_exchange_round(rng, params):
     scopes = ppermutes_by_scope(jax.make_jaxpr(forward)(
         params, graph, graph.positions))
     assert sum(scopes.values()) == n
-
-
-@pytest.mark.tier1
-def test_collective_count_halves_for_magmom_step(rng, params):
-    """Acceptance: collectives per magmom-MD step drop >= 2x on a CHGNet
-    2-partition graph — legacy per-array exchanges + separate site forward
-    vs coalesced exchanges + fused aux readout."""
-    cart, nl, plan, graph, host = _graph(_system(rng), 2)
-    mesh = graph_mesh(2)
-
-    pot_legacy = make_potential_fn(MODEL.energy_fn, mesh, halo_mode="legacy")
-    site_legacy = make_site_fn(MODEL.magmom_fn, mesh, halo_mode="legacy")
-    pot_fused = make_potential_fn(MODEL.energy_and_aux_fn, mesh,
-                                  halo_mode="coalesced", aux=True)
-
-    args = (params, graph, graph.positions)
-    legacy = (_ppermute_count(pot_legacy, *args)
-              + _ppermute_count(site_legacy, *args))
-    fused = _ppermute_count(pot_fused, *args)
-    assert fused > 0
-    assert legacy / fused >= 2.0, (
-        f"collective reduction {legacy}/{fused} = {legacy / fused:.2f}x < 2x")
 
 
 @pytest.mark.tier1
@@ -161,34 +137,28 @@ def test_fused_readout_adds_no_forward(rng, params):
 
 @pytest.mark.tier1
 def test_halo_modes_match_single_partition_chgnet(rng, params):
-    """energy/forces/stress agree <= 1e-5 (fp32) between coalesced, legacy
-    and single-partition on a bond-graph CHGNet system (acceptance
-    criterion)."""
+    """energy/forces/stress of the two-partition program agree <= 1e-5
+    (fp32) with the single-partition one, which exchanges nothing, on a
+    bond-graph CHGNet system (acceptance criterion)."""
     caps = CapacityPolicy()
     system = _system(rng)
     outs = {}
-    for key, nparts, mode in (("single", 1, "coalesced"),
-                              ("coalesced", 2, "coalesced"),
-                              ("legacy", 2, "legacy")):
+    for nparts in (1, 2):
         cart, nl, plan, graph, host = _graph(system, nparts, caps=caps)
         mesh = graph_mesh(nparts) if nparts > 1 else None
-        pot = make_potential_fn(MODEL.energy_fn, mesh, halo_mode=mode)
+        pot = make_potential_fn(MODEL.energy_fn, mesh)
         out = pot(params, graph, graph.positions)
-        outs[key] = (
+        outs[nparts] = (
             float(out["energy"]),
             host.gather_owned(np.asarray(out["forces"]), len(cart)),
             np.asarray(out["stress"]),
         )
-    e0, f0, s0 = outs["single"]
+    e0, f0, s0 = outs[1]
     assert np.abs(f0).max() > 1e-4  # non-degeneracy guard
-    for key in ("coalesced", "legacy"):
-        e, f, s = outs[key]
-        assert abs(e - e0) <= 1e-5 * max(1.0, abs(e0)), key
-        np.testing.assert_allclose(f, f0, atol=1e-5, err_msg=key)
-        np.testing.assert_allclose(s, s0, atol=1e-5, err_msg=key)
-    # coalesced vs legacy on the SAME graph: same math, same masks
-    np.testing.assert_allclose(outs["coalesced"][1], outs["legacy"][1],
-                               atol=1e-6)
+    e, f, s = outs[2]
+    assert abs(e - e0) <= 1e-5 * max(1.0, abs(e0))
+    np.testing.assert_allclose(f, f0, atol=1e-5)
+    np.testing.assert_allclose(s, s0, atol=1e-5)
 
 
 @pytest.mark.tier1
@@ -197,63 +167,84 @@ def test_halo_modes_match_pair(rng):
     caps = CapacityPolicy()
     cart, lattice, species = make_crystal(rng, reps=(8, 3, 3), a=A_LAT)
     outs = {}
-    for key, nparts, mode in (("single", 1, "coalesced"),
-                              ("coalesced", 4, "coalesced"),
-                              ("legacy", 4, "legacy")):
+    for nparts in (1, 4):
         nl = neighbor_list_numpy(cart, lattice, [1, 1, 1], PAIR.cfg.cutoff)
         plan = build_plan(nl, lattice, [1, 1, 1], nparts, PAIR.cfg.cutoff)
         graph, host = build_partitioned_graph(plan, nl, species, lattice,
                                               caps=caps)
         mesh = graph_mesh(nparts) if nparts > 1 else None
-        pot = make_potential_fn(PAIR.energy_fn, mesh, halo_mode=mode)
+        pot = make_potential_fn(PAIR.energy_fn, mesh)
         out = pot(p, graph, graph.positions)
-        outs[key] = (float(out["energy"]),
-                     host.gather_owned(np.asarray(out["forces"]), len(cart)))
-    e0, f0 = outs["single"]
-    for key in ("coalesced", "legacy"):
-        e, f = outs[key]
-        assert abs(e - e0) <= 1e-5 * max(1.0, abs(e0)), key
-        np.testing.assert_allclose(f, f0, atol=1e-5, err_msg=key)
+        outs[nparts] = (float(out["energy"]),
+                        host.gather_owned(np.asarray(out["forces"]),
+                                          len(cart)))
+    e0, f0 = outs[1]
+    assert np.abs(f0).max() > 1e-4
+    e, f = outs[4]
+    assert abs(e - e0) <= 1e-5 * max(1.0, abs(e0))
+    np.testing.assert_allclose(f, f0, atol=1e-5)
 
 
-@pytest.mark.parametrize("mode", ["coalesced", "legacy"])
-def test_gradients_flow_to_owner_both_modes(rng, mode):
-    """d(sum of halo rows)/d(owned rows) is 1 at owner slots for BOTH
-    exchange implementations (the transposed-ppermute force flow)."""
-    nparts = 4
-    cart, lattice, species = make_crystal(rng, reps=(8, 2, 2), a=A_LAT)
-    nl = neighbor_list_numpy(cart, lattice, [1, 1, 1], 3.0)
-    plan = build_plan(nl, lattice, [1, 1, 1], nparts, 3.0)
-    graph, host = build_partitioned_graph(plan, nl, species, lattice)
+@pytest.mark.parametrize("tables", ["node", "bond"])
+def test_gradients_flow_to_owner_both_modes(rng, tables):
+    """d(sum of received rows)/d(local rows) counts, at every local row,
+    the partitions it is sent to, and is 0 elsewhere — through
+    ``halo_exchange`` (node tables) and ``bond_halo_exchange`` (bond
+    tables): the transposed-ppermute force flow."""
+    nparts = 2 if tables == "bond" else 4
+    cart, nl, plan, graph, host = _graph(
+        make_crystal(rng, reps=(8, 2, 2), a=A_LAT), nparts,
+        bond=tables == "bond")
     mesh = graph_mesh(nparts)
-    n = len(cart)
+    if tables == "bond":
+        exchange = lambda lg, x: lg.bond_halo_exchange(x)
+        cap = graph.b_cap
+        send, send_mask, recv = (np.asarray(graph.bond_halo_send_idx),
+                                 np.asarray(graph.bond_halo_send_mask),
+                                 np.asarray(graph.bond_halo_recv_idx))
+    else:
+        exchange = lambda lg, x: lg.halo_exchange(x)
+        cap = graph.n_cap
+        send, send_mask, recv = (np.asarray(graph.halo_send_idx),
+                                 np.asarray(graph.halo_send_mask),
+                                 np.asarray(graph.halo_recv_idx))
 
-    def loss(graph_l, feats):
-        lg, _ = local_graph_from_stacked(graph_l, GRAPH_AXIS, mode)
-        full = lg.halo_exchange(feats[0])
-        halo_mask = lg.node_mask & ~lg.owned_mask
-        return jax.lax.psum(jnp.sum(full * halo_mask[:, None]), GRAPH_AXIS)
+    def loss(graph_l, recv_l, feats):
+        lg, _ = local_graph_from_stacked(graph_l, GRAPH_AXIS)
+        full = exchange(lg, feats[0])
+        received = jnp.zeros(cap, bool).at[recv_l[:, 0].reshape(-1)].set(
+            True, mode="drop")
+        return jax.lax.psum(jnp.sum(full * received[:, None]), GRAPH_AXIS)
 
     def total(feats):
         return jax.shard_map(
-            loss, mesh=mesh, in_specs=(graph_in_specs(graph), P(GRAPH_AXIS)),
+            loss, mesh=mesh,
+            in_specs=(graph_in_specs(graph), P(None, GRAPH_AXIS),
+                      P(GRAPH_AXIS)),
             out_specs=P(), check_vma=False,
-        )(graph, feats)
+        )(graph, jnp.asarray(recv), feats)
 
-    local = jnp.asarray(host.scatter_global(
-        np.zeros((n, 2), np.float32), graph.n_cap))
-    g = np.asarray(jax.grad(total)(local))
-    for p in range(nparts):
-        m = plan.node_markers[p]
-        P_ = plan.num_partitions
-        np.testing.assert_allclose(g[p, : m[1]], 0.0)          # pure
-        np.testing.assert_allclose(g[p, m[1]: m[1 + P_]], 1.0)  # to-sections
-        np.testing.assert_allclose(g[p, m[1 + P_]:], 0.0)      # halo+pad
+    g = np.asarray(jax.grad(total)(jnp.zeros((nparts, cap, 2), jnp.float32)))
+    want = np.zeros((nparts, cap))
+    for si in range(send.shape[0]):
+        for p in range(nparts):
+            np.add.at(want[p], send[si, p][send_mask[si, p]], 1.0)
+    assert want.sum() > 0 and want.sum() == (recv < cap).sum()
+    np.testing.assert_array_equal(g[..., 0], want)
+    np.testing.assert_array_equal(g[..., 1], want)
+    if tables == "node":
+        for p in range(nparts):
+            m = plan.node_markers[p]
+            P_ = plan.num_partitions
+            np.testing.assert_allclose(g[p, : m[1]], 0.0)          # pure
+            np.testing.assert_allclose(g[p, m[1]: m[1 + P_]], 1.0)  # to-sections
+            np.testing.assert_allclose(g[p, m[1 + P_]:], 0.0)      # halo+pad
 
 
 def test_exchange_all_matches_sequential(rng):
     """Coalescing N arrays into one ppermute delivers exactly what N
-    separate exchanges deliver — mixed widths and dtypes included."""
+    separate ``halo_exchange`` calls deliver — mixed widths and dtypes
+    included — in one collective instead of N."""
     nparts = 2
     cart, nl, plan, graph, host = _graph(_system(rng), nparts)
     mesh = graph_mesh(nparts)
@@ -267,23 +258,28 @@ def test_exchange_all_matches_sequential(rng):
         la[p, oc:] = 0.0
         lb[p, oc:] = 0.0
 
-    def run(mode):
+    def run(together):
         def f(g, xa, xb):
-            lg, _ = local_graph_from_stacked(g, GRAPH_AXIS, mode)
-            (a, b), _ = lg.exchange_all(
-                (xa[0], xb[0].astype(jnp.bfloat16)), ())
+            lg, _ = local_graph_from_stacked(g, GRAPH_AXIS)
+            xb = xb[0].astype(jnp.bfloat16)
+            if together:
+                (a, b), _ = lg.exchange_all((xa[0], xb), ())
+            else:
+                a, b = lg.halo_exchange(xa[0]), lg.halo_exchange(xb)
             return a[None], b.astype(jnp.float32)[None]
 
         return jax.shard_map(
             f, mesh=mesh,
             in_specs=(graph_in_specs(graph), P(GRAPH_AXIS), P(GRAPH_AXIS)),
-            out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)), check_vma=False,
-        )(graph, jnp.asarray(la), jnp.asarray(lb))
+            out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS)), check_vma=False)
 
-    a_c, b_c = run("coalesced")
-    a_l, b_l = run("legacy")
+    args = (graph, jnp.asarray(la), jnp.asarray(lb))
+    a_c, b_c = run(True)(*args)
+    a_l, b_l = run(False)(*args)
     np.testing.assert_array_equal(np.asarray(a_c), np.asarray(a_l))
     np.testing.assert_array_equal(np.asarray(b_c), np.asarray(b_l))
+    assert (2 * _ppermute_count(run(True), *args)
+            == _ppermute_count(run(False), *args) > 0)
     # and the refreshed rows carry the owner's values
     for p in range(nparts):
         g_ids = plan.global_ids[p]
@@ -363,18 +359,6 @@ def test_aggregate_edges_matches_unsorted_reference(rng):
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
-def test_chunk_sorted_hint(rng):
-    cart, nl, plan, graph, host = _graph(_system(rng), 2)
-    lg, _ = local_graph_from_stacked(graph, None)
-    assert lg.has_frontier_split
-    assert lg.chunk_sorted(lg.e_split)      # boundary-aligned chunks
-    assert not lg.chunk_sorted(lg.e_split - 1) or lg.e_split % (
-        lg.e_split - 1) == 0
-    assert lg.chunk_sorted(0)               # chunking disabled
-    lg.e_split = lg.e_cap                   # unsplit view
-    assert lg.chunk_sorted(7)
-
-
 # ---------------------------------------------------------------------------
 # fused site readout
 # ---------------------------------------------------------------------------
@@ -382,22 +366,22 @@ def test_chunk_sorted_hint(rng):
 
 @pytest.mark.tier1
 def test_fused_magmom_parity_vs_site_fn(rng, params):
-    """DistPotential's fused aux magmoms == the legacy make_site_fn
-    readout, across partitionings."""
+    """DistPotential's fused aux magmoms on two partitions == the model's
+    own ``magmom_fn`` on the single-partition graph."""
     from distmlip_tpu.calculators import Atoms, DistPotential
 
-    cart, lattice, species = make_crystal(rng, reps=(4, 2, 2), a=A_LAT)
+    system = make_crystal(rng, reps=(4, 2, 2), a=A_LAT)
+    cart, lattice, species = system
     atoms = Atoms(numbers=species + 1, positions=cart, cell=lattice)
     smap = np.concatenate([[0], np.arange(0, 8)]).astype(np.int32)
-    outs = {}
-    for key, kw in (("fused", dict(fused_site_readout=True)),
-                    ("legacy", dict(fused_site_readout=False))):
-        pot = DistPotential(MODEL, params, num_partitions=2,
-                            species_map=smap, compute_magmom=True, **kw)
-        assert pot.fused_site_readout == (key == "fused")
-        outs[key] = pot.calculate(atoms)
-        if key == "fused":
-            assert pot._site_fn is None  # no separate readout program
-    np.testing.assert_allclose(outs["fused"]["magmoms"],
-                               outs["legacy"]["magmoms"], atol=1e-5)
-    assert abs(outs["fused"]["energy"] - outs["legacy"]["energy"]) < 1e-5
+    pot = DistPotential(MODEL, params, num_partitions=2,
+                        species_map=smap, compute_magmom=True)
+    fused = pot.calculate(atoms)["magmoms"]
+    pot.close()
+    _, _, _, graph, host = _graph(system, 1)
+    lg, pos = local_graph_from_stacked(graph, None)
+    want = host.gather_owned(
+        np.asarray(MODEL.magmom_fn(params, lg, pos))[None], len(cart))
+    assert fused.shape == want.shape == (len(cart),)
+    assert np.abs(want).max() > 1e-3
+    np.testing.assert_allclose(fused, want, atol=1e-5)

@@ -612,24 +612,6 @@ def test_kernel_telemetry_report(tmp_path):
 
 
 @pytest.mark.tier1
-def test_kernel_bench_interpret_smoke():
-    """tools/kernel_bench.py plumbing: fused and unfused arms agree and
-    the record carries the MFU/speedup fields bench.py publishes."""
-    import tools.kernel_bench as kb
-
-    out = kb.run_sweep([2000], [16], iters=2, interpret=True)
-    assert out["mode"] == "interpret" and len(out["points"]) == 1
-    p = out["points"][0]
-    assert p["max_abs_err"] < 1e-4
-    assert p["fused_s"] > 0 and p["unfused_s"] > 0
-    for key in ("speedup", "mfu_fused", "mfu_unfused", "flops"):
-        assert key in p
-    # 2000 edges / 125 nodes * 16 floats fits VMEM: the record must say
-    # the in-kernel gather variant ran (not the XLA pre-gather fallback)
-    assert p["in_kernel_gather"] is True
-
-
-@pytest.mark.tier1
 def test_env_kill_switch_forces_xla(rng, monkeypatch):
     """DISTMLIP_KERNELS=0 beats a kernels=None potential: the trace
     counts zero Pallas dispatches."""

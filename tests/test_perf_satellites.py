@@ -67,11 +67,13 @@ def test_mfu_accounting():
 
 
 def test_steprecord_new_fields_roundtrip():
-    rec = StepRecord(step=3, halo_mode="coalesced", collective_count=11,
+    rec = StepRecord(step=3, collective_count=11,
                      frontier_edge_frac=0.25, flops_per_step=1.5e9,
                      mfu=0.31, prefetch_skipped_hbm=True)
-    back = StepRecord.from_json(rec.to_json())
-    assert back.halo_mode == "coalesced"
+    # a file written before PR 30 carries the exchange's name: kept, in extra
+    old = json.dumps({**json.loads(rec.to_json()), "halo_mode": "coalesced"})
+    back = StepRecord.from_json(old)
+    assert back.extra["halo_mode"] == "coalesced"
     assert back.collective_count == 11
     assert back.frontier_edge_frac == pytest.approx(0.25)
     assert back.mfu == pytest.approx(0.31)
@@ -86,17 +88,16 @@ def test_report_surfaces_pipeline_counters(tmp_path):
         for i in range(4):
             f.write(StepRecord(
                 step=i, timings={"total_s": 0.1, "device_s": 0.08},
-                halo_mode="coalesced", collective_count=11, mfu=0.2,
+                collective_count=11, mfu=0.2,
                 frontier_edge_frac=0.3,
                 prefetch_skipped_hbm=(i == 2)).to_json() + "\n")
     rep = aggregate(read_jsonl(str(path)))
     c = rep.counters
-    assert c["halo_modes"] == ["coalesced"]
     assert c["collective_count"] == 11
     assert c["mean_mfu"] == pytest.approx(0.2)
     assert c["prefetch_skipped_hbm"] == 1
     text = rep.render()
-    assert "halo pipeline" in text and "mfu" in text
+    assert "halo pipeline: collectives/step=11" in text and "mfu" in text
 
 
 # ---------------------------------------------------------------------------
@@ -125,7 +126,6 @@ def test_calculate_emits_pipeline_telemetry(rng):
     pot.calculate(atoms)
     pot.calculate(atoms)  # warm path: cached graph -> collective count known
     rec = sink.records[-1]
-    assert rec.halo_mode == "coalesced"
     assert rec.frontier_edge_frac > 0.0
     assert rec.flops_per_step > 0.0
     assert rec.collective_count > 0
@@ -281,5 +281,5 @@ def test_halo_audit_cli(capsys):
     assert rc == 0
     report = json.loads(capsys.readouterr().out)
     progs = report["programs"]
-    assert "potential[coalesced]" in progs and "potential[legacy]" in progs
-    assert progs["potential[coalesced]"]["total"] > 0
+    assert list(progs) == ["potential"]
+    assert progs["potential"]["total"] > 0

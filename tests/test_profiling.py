@@ -1,5 +1,5 @@
 """Compiler/device observability plane: compile telemetry, roofline
-accounting, perf-regression baseline gate.
+accounting.
 
 The contracts under test:
 
@@ -9,17 +9,10 @@ The contracts under test:
   rehydrates and keeps ``compile_count == 0`` (the restart gate);
 - ``jaxpr_flop_estimate`` is dot_general-exact; roofline rows derive
   intensity/achieved/MFU without a chip, and record-derived rows
-  tolerate mixed rounds where only some records carry FLOP estimates;
-- ``tools/perf_gate.py`` classifies identity rounds ok (exit 0),
-  synthetic regressions as regressions (exit 3), respects the
-  allow-list, rejects malformed baselines (exit 2), and the
-  ``--check-schema`` self-test catches a comparator that stops doing
-  any of that.
+  tolerate mixed rounds where only some records carry FLOP estimates.
 """
 
-import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -270,153 +263,3 @@ def test_roofline_cli_jsonl_times(tmp_path):
 
     times = rl._times_from_jsonl(str(path))
     assert times["b1"] == pytest.approx(0.04)  # warm median, compile skipped
-
-
-# ---------------------------------------------------------------------------
-# perf-regression baseline gate
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture()
-def pg():
-    import tools.perf_gate as pg
-
-    return pg
-
-
-def test_validate_baseline_schema(pg):
-    good = {"schema": 1, "metrics": {
-        "v": {"value": 1.0, "tolerance_frac": 0.5,
-              "direction": "higher_is_better"}},
-        "allow_regressions": []}
-    assert pg.validate_baseline(good) == []
-    assert pg.validate_baseline([]) != []
-    assert pg.validate_baseline({"schema": 99, "metrics": {}}) != []
-    bad_dir = {"schema": 1, "metrics": {
-        "v": {"value": 1.0, "tolerance_frac": 0.5, "direction": "up"}}}
-    assert any("direction" in e for e in pg.validate_baseline(bad_dir))
-    bad_allow = {"schema": 1, "metrics": {
-        "v": {"value": 1.0, "tolerance_frac": 0.5,
-              "direction": "higher_is_better"}},
-        "allow_regressions": ["ghost"]}
-    assert any("ghost" in e for e in pg.validate_baseline(bad_allow))
-
-
-def test_compare_statuses_and_allow_list(pg):
-    base = {"schema": 1, "allow_regressions": ["lat"], "metrics": {
-        "thr": {"value": 100.0, "tolerance_frac": 0.1,
-                "direction": "higher_is_better"},
-        "lat": {"value": 1.0, "tolerance_frac": 0.1,
-                "direction": "lower_is_better"},
-        "cnt": {"value": 3.0, "tolerance_frac": 0.0,
-                "direction": "lower_is_better"}}}
-    by = {n: s for n, s, _ in pg.compare(
-        base, {"thr": 50.0, "lat": 2.0, "cnt": 3.0})}
-    assert by == {"thr": "regression", "lat": "allowed_regression",
-                  "cnt": "ok"}
-    by = {n: s for n, s, _ in pg.compare(base, {"thr": 200.0, "cnt": 2.0})}
-    assert by["thr"] == "improved" and by["cnt"] == "improved"
-    assert by["lat"] == "missing"
-    # within-band noise is ok in both directions
-    by = {n: s for n, s, _ in pg.compare(
-        base, {"thr": 95.0, "lat": 1.05, "cnt": 3.0})}
-    assert set(by.values()) == {"ok"}
-
-
-def test_hbm_drift_watch_runs_whenever_measured(pg):
-    assert pg.hbm_drift_findings({}) == []
-    flagged = pg.hbm_drift_findings({"hbm_est_over_measured": 5.0})
-    assert flagged and flagged[0][1] == "regression"
-    ok = pg.hbm_drift_findings({"hbm_estimator_ratio": 1.2})
-    assert ok and ok[0][1] == "ok"
-
-
-def test_perf_gate_cli_exit_codes(pg, tmp_path):
-    result = tmp_path / "round.json"
-    result.write_text("# noise line\n" + json.dumps(
-        {"value": 100.0, "batched_compiles": 2, "note": "str ignored",
-         "flag": True}) + "\n")
-    baseline = tmp_path / "BASELINE.json"
-    assert pg.main(["--input", str(result),
-                    "--write-baseline", str(baseline)]) == 0
-    doc = json.loads(baseline.read_text())
-    assert doc["metrics"]["value"]["direction"] == "higher_is_better"
-    assert doc["metrics"]["batched_compiles"]["tolerance_frac"] == 0.0
-    assert "flag" not in doc["metrics"] and "note" not in doc["metrics"]
-
-    # identity: exit 0
-    assert pg.main(["--input", str(result),
-                    "--baseline", str(baseline)]) == 0
-    # seeded synthetic regression: exit 3
-    reg = tmp_path / "regressed.json"
-    reg.write_text(json.dumps({"value": 10.0, "batched_compiles": 5}))
-    assert pg.main(["--input", str(reg),
-                    "--baseline", str(baseline)]) == 3
-    # allow-listed: back to exit 0
-    doc["allow_regressions"] = ["value", "batched_compiles"]
-    baseline.write_text(json.dumps(doc))
-    assert pg.main(["--input", str(reg),
-                    "--baseline", str(baseline)]) == 0
-    # malformed baseline: exit 2
-    baseline.write_text("{\"schema\": 1}")
-    assert pg.main(["--input", str(result),
-                    "--baseline", str(baseline)]) == 2
-    # usage error: both/neither input
-    assert pg.main(["--baseline", str(baseline)]) == 2
-
-
-def test_perf_gate_check_schema_self_test(pg, tmp_path):
-    good = tmp_path / "B.json"
-    good.write_text(json.dumps({
-        "schema": 1, "allow_regressions": [], "metrics": {
-            "v": {"value": 1.0, "tolerance_frac": 0.5,
-                  "direction": "higher_is_better"}}}))
-    assert pg.main(["--check-schema", "--baseline", str(good)]) == 0
-    assert pg.main(["--check-schema",
-                    "--baseline", str(tmp_path / "missing.json")]) == 2
-    bad = tmp_path / "bad.json"
-    bad.write_text("not json")
-    assert pg.main(["--check-schema", "--baseline", str(bad)]) == 2
-
-
-def test_no_baseline_is_committed(pg, tmp_path):
-    """PR 21 deleted PERF_BASELINE.json (CPU figures under device metric
-    names). Until a chip run writes the next one, --check-schema tests the
-    comparator alone (the form contract_check --lint chains), and a gate
-    run without --baseline has nothing to read: exit 2, not a silent
-    pass."""
-    assert not os.path.exists(pg.DEFAULT_BASELINE)
-    assert pg.main(["--check-schema"]) == 0
-    result = tmp_path / "r.json"
-    result.write_text(json.dumps({"value": 1.0}))
-    assert pg.main(["--input", str(result)]) == 2
-
-
-def test_metrics_from_jsonl_compile_split(pg, tmp_path):
-    path = tmp_path / "run.jsonl"
-    recs = [
-        StepRecord(kind="batched_calculate", compiled=True,
-                   compile_s=0.5, compile_kind="fresh",
-                   timings={"device_s": 0.6}),
-        StepRecord(kind="batched_calculate", compile_s=0.01,
-                   compile_kind="aot", timings={"device_s": 0.02}),
-        StepRecord(kind="batched_calculate", timings={"device_s": 0.01}),
-    ]
-    path.write_text("".join(r.to_json() + "\n" for r in recs))
-    m = pg.metrics_from_jsonl(str(path))
-    assert m["compiles_fresh"] == 1.0
-    assert m["compiles_aot"] == 1.0
-    assert m["compile_time_s"] == pytest.approx(0.51)
-    assert m["n_records"] == 3.0
-
-
-def test_contract_check_lint_chains_perf_gate():
-    import subprocess
-
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "contract_check.py"),
-         "--only-lint", "--json"],
-        capture_output=True, text=True, timeout=300)
-    rep = json.loads(out.stdout)
-    gate = rep["lint"].get("perf_gate")
-    assert gate is not None and gate["returncode"] == 0, gate

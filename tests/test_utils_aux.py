@@ -91,18 +91,6 @@ def test_checkpoint_escn_roundtrip(tmp_path):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
 
 
-def test_chunk_spec_edge_cases():
-    """chunk_spec: disabled chunking, exact division, remainder, and the
-    edgeless-graph guard (e_cap=0 must not divide by zero)."""
-    from distmlip_tpu.ops.chunk import chunk_spec
-
-    assert chunk_spec(100, 0) == (1, 100, 0)       # disabled -> one chunk
-    assert chunk_spec(100, 25) == (4, 25, 0)       # exact
-    assert chunk_spec(100, 30) == (4, 30, 20)      # remainder padded
-    assert chunk_spec(10, 1000) == (1, 10, 0)      # chunk > e_cap clamps
-    assert chunk_spec(0, 32768) == (1, 0, 0)       # edgeless graph
-
-
 def test_checkpoint_layout_version_gate(tmp_path):
     """A checkpoint without the layout-version sentinel (pre-channels-last
     era) must be refused by default — shapes match across the flip, so a

@@ -12,6 +12,8 @@ import weakref
 import numpy as np
 
 from distmlip_tpu import geometry
+from distmlip_tpu.calculators import Atoms, DistPotential
+from distmlip_tpu.models import PairConfig, PairPotential
 from distmlip_tpu.neighbors import neighbor_list_numpy
 from distmlip_tpu.parallel import graph_mesh, make_potential_fn
 from distmlip_tpu.partition import CapacityPolicy, build_plan, build_partitioned_graph
@@ -24,6 +26,23 @@ def make_crystal(rng, reps=(4, 4, 4), a=4.0, noise=0.05, n_species=2):
     cart = geometry.frac_to_cart(frac, lattice) + rng.normal(0, noise, (len(frac), 3))
     species = rng.integers(0, n_species, len(frac)).astype(np.int32)
     return cart, lattice, species
+
+
+def make_atoms(rng, reps=(3, 3, 3), a=3.8, noise=0.03):
+    """Perturbed fcc Si supercell as Atoms (the calculators' tests)."""
+    unit = np.array([[0, 0, 0], [0.5, 0.5, 0], [0.5, 0, 0.5], [0, 0.5, 0.5]])
+    frac, lattice = geometry.make_supercell(unit, np.eye(3) * a, reps)
+    cart = geometry.frac_to_cart(frac, lattice) + rng.normal(0, noise, (len(frac), 3))
+    return Atoms(numbers=np.full(len(cart), 14), positions=cart, cell=lattice)
+
+
+def lj_potential():
+    """Two-partition Lennard-Jones DistPotential, eps scaled to 0.1 (the
+    MD and relaxer tests' module-scoped ``potential`` fixtures)."""
+    model = PairPotential(PairConfig(cutoff=3.5, kind="lj"))
+    params = model.init()
+    params = {"eps": params["eps"] * 0.1, "sigma": params["sigma"]}
+    return DistPotential(model, params, num_partitions=2, compute_stress=True)
 
 
 _SHARED_CAPS = CapacityPolicy()

@@ -639,18 +639,6 @@ def main(argv=None) -> int:
             # represent ruff failures as one error so the exit gate fires
             report["lint"]["errors"] += 1
             all_findings.append(_ruff_finding(ruff_report))
-        # chained perf_gate schema self-test: a malformed
-        # PERF_BASELINE.json edit fails here at lint time, not at the
-        # next bench round (tools/perf_gate.py --check-schema)
-        gate_report = _run_perf_gate_check()
-        if gate_report is not None:
-            report["lint"]["perf_gate"] = gate_report
-            if gate_report["returncode"] != 0:
-                report["lint"]["errors"] += 1
-                all_findings.append(_perf_gate_finding(gate_report))
-            if not args.json and gate_report["returncode"] != 0:
-                print("perf_gate --check-schema:")
-                print(gate_report["stdout"])
 
     n_err = error_count(all_findings)
     n_warn = warning_count(all_findings)
@@ -671,29 +659,6 @@ def _ruff_finding(ruff_report):
     return Finding(pass_name="lint", severity=Severity.ERROR,
                    message="ruff check failed:\n" + ruff_report["stdout"],
                    rule="ruff")
-
-
-def _run_perf_gate_check():
-    """perf_gate --check-schema as a subprocess (same chaining pattern as
-    ruff): validates PERF_BASELINE.json + the comparator's own exit-3
-    classification. None when the tool is absent (partial checkouts)."""
-    gate = os.path.join(REPO, "tools", "perf_gate.py")
-    if not os.path.exists(gate):
-        return None
-    proc = subprocess.run(
-        [sys.executable, gate, "--check-schema"], cwd=REPO,
-        capture_output=True, text=True)
-    return {"returncode": proc.returncode,
-            "stdout": (proc.stdout + proc.stderr).strip()}
-
-
-def _perf_gate_finding(gate_report):
-    from distmlip_tpu.analysis import Finding, Severity
-
-    return Finding(pass_name="lint", severity=Severity.ERROR,
-                   message="perf_gate --check-schema failed:\n"
-                           + gate_report["stdout"],
-                   rule="perf_gate")
 
 
 if __name__ == "__main__":

@@ -5,12 +5,12 @@
         [--nparts 2] [--reps 4,2,2] [--batch B] [--mesh B,S]
         [--per-scope] [--json]
 
-Builds a small test system, traces the jitted potential under BOTH halo
-modes (plus the fused-aux and legacy site-readout programs when the model
-has a sitewise head), and prints collective counts straight from the
-jaxprs — the chip-free view of what the overlap-aware halo pipeline
-(ISSUE 2) saves per MD step. ``--per-scope`` additionally groups ppermutes
-by ``jax.named_scope`` name stack so the per-layer structure is visible.
+Builds a small test system, traces the jitted potential (plus the
+fused-aux program when the model has a sitewise head), and prints
+collective counts straight from the jaxprs — the chip-free view of what the
+halo exchange costs per MD step. ``--per-scope`` additionally groups
+ppermutes by ``jax.named_scope`` name stack so the per-layer structure is
+visible.
 
 ``--batch B`` additionally packs B jittered copies of the system into a
 block-diagonal batched graph (partition.pack_structures) and traces the
@@ -129,8 +129,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_platforms", "cpu")
 
     from distmlip_tpu.neighbors import neighbor_list_numpy
-    from distmlip_tpu.parallel import (graph_mesh, make_potential_fn,
-                                       make_site_fn)
+    from distmlip_tpu.parallel import graph_mesh, make_potential_fn
     from distmlip_tpu.parallel.audit import (count_collectives,
                                              ppermutes_by_scope)
     from distmlip_tpu.partition import build_partitioned_graph, build_plan
@@ -143,16 +142,10 @@ def main(argv=None) -> int:
     graph, _host = build_partitioned_graph(plan, nl, species, lattice)
     mesh = graph_mesh(args.nparts) if args.nparts > 1 else None
 
-    programs = {}
-    for mode in ("coalesced", "legacy"):
-        programs[f"potential[{mode}]"] = make_potential_fn(
-            model.energy_fn, mesh, halo_mode=mode)
+    programs = {"potential": make_potential_fn(model.energy_fn, mesh)}
     if hasattr(model, "energy_and_aux_fn"):
-        programs["potential+aux[coalesced]"] = make_potential_fn(
-            model.energy_and_aux_fn, mesh, halo_mode="coalesced", aux=True)
-    if hasattr(model, "magmom_fn"):
-        programs["site_fn[legacy]"] = make_site_fn(
-            model.magmom_fn, mesh, halo_mode="legacy")
+        programs["potential+aux"] = make_potential_fn(
+            model.energy_and_aux_fn, mesh, aux=True)
 
     report = {"model": args.model, "nparts": args.nparts,
               "n_atoms": len(cart), "e_split": graph.e_split,
@@ -310,10 +303,6 @@ def main(argv=None) -> int:
                   + " ".join(f"{k}={v}" for k, v in cnt.items()))
         for scope, n in entry.get("ppermutes_by_scope", {}).items():
             print(f"      {n:3d}x {scope}")
-    pot_c = report["programs"].get("potential[coalesced]", {}).get("total", 0)
-    pot_l = report["programs"].get("potential[legacy]", {}).get("total", 0)
-    if pot_c and pot_l:
-        print(f"  coalesced/legacy collective ratio: {pot_c / pot_l:.2f}x")
     if args.batch > 0:
         verdict = "independent of B" if batch_ok else "DEPEND ON B (bug!)"
         print(f"  batched collective counts: {verdict}")

@@ -2,7 +2,7 @@
 """Load-test the ServeEngine: closed+open-loop traffic, latency percentiles.
 
 Drives a stream of mixed-size structures through an in-process
-``ServeEngine`` and prints ONE JSON line per mode (bench.py-style) with
+``ServeEngine`` and prints ONE JSON line per mode with
 p50/p95/p99 latency, structures/sec, batch/bucket occupancy and engine
 counters — so serving throughput joins the perf trajectory. With
 ``--jsonl`` the engine's per-batch StepRecords (and the batched

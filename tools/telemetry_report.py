@@ -5,9 +5,8 @@
         [--trace-dir traces/] [--stall-factor 5]
         [--occupancy-floor 0.35] [--imbalance-factor 2]
 
-Reads StepRecord JSONL (produced by distmlip_tpu.telemetry.JsonlSink — see
-bench.py's BENCH_TELEMETRY_JSONL, or any DistPotential/DeviceMD run with a
-JsonlSink attached), prints the per-phase total/mean/p50/p90/p99/max table
+Reads StepRecord JSONL (produced by distmlip_tpu.telemetry.JsonlSink: any
+DistPotential/DeviceMD run with a JsonlSink attached), prints the per-phase total/mean/p50/p90/p99/max table
 and run counters, and flags anomalies: wedge-style stalls, padding-occupancy
 collapse, and halo-volume imbalance. ``--trace-dir`` additionally loads
 exported Perfetto trace JSON (distmlip_tpu.obs / load_test --trace-out)

@@ -339,15 +339,21 @@ def _kernel_cases(seed: int):
     """One published-width call per Pallas kernel: ``(op, build)`` where
     ``build(dtype)`` returns ``(kernel_fn, xla_fn, args)``; the raw kernel
     entry points compile for the backend jax runs on."""
+    import jax
     import jax.numpy as jnp
 
     from distmlip_tpu.kernels.segment import (pallas_edge_aggregate,
                                               pallas_segment_sum)
     from distmlip_tpu.kernels.so3 import (packed_m_layout, so2_conv_pallas,
-                                          so2_conv_reference)
+                                          so2_conv_reference, wigner_cols,
+                                          wigner_dcols_pallas,
+                                          wigner_rotate_pallas,
+                                          wigner_rotate_reference)
     from distmlip_tpu.models import ESCN, ESCNConfig
     from distmlip_tpu.ops.nn import gated_mlp
     from distmlip_tpu.ops.segment import masked_segment_sum
+    from distmlip_tpu.ops.so3_e3nn import (CoeffLayout,
+                                           wigner_blocks_from_edges)
 
     rng = np.random.default_rng(seed)
 
@@ -416,8 +422,45 @@ def _kernel_cases(seed: int):
                 lambda h_, *ws: so2_conv_reference(h_, list(ws), segments, c),
                 (h, *weights))
 
+    def wigner_rotate(dtype):
+        # UMA-S's rotations on one scan chunk: sender and receiver rows
+        # into the edge frame, the SO(2) output back, and the cotangent of
+        # the 35 block columns (scaled to the rows' size), side by side
+        e, c, lay = 32768, 128, CoeffLayout(2, 2)
+        ms = tuple(lay.signed_ms)
+        kw = dict(l_max=2, m_max=2, ms=ms, channels=c)
+        rhat = rng.normal(size=(e, 3))
+        rhat /= np.linalg.norm(rhat, axis=1, keepdims=True)
+        cols = wigner_cols(wigner_blocks_from_edges(
+            2, jnp.asarray(rhat, jnp.float32)))
+        labs = [normal((e, 9 * c), dtype) for _ in range(2)]
+        pieces = [normal((e, lay.m_size(abs(m)) * c), dtype) for m in ms]
+
+        def side_by_side(fr, out, dcols):
+            return jnp.concatenate(
+                [*fr, *out, (dcols / (2 * c)).astype(out[0].dtype)], axis=1)
+
+        def kernel(cols_, xs, xd, *ys):
+            fr = wigner_rotate_pallas(cols_, [xs, xd], n_ops=2, to_edge=True,
+                                      **kw)
+            return side_by_side(
+                fr, wigner_rotate_pallas(cols_, ys, n_ops=1, to_edge=False,
+                                         **kw),
+                wigner_dcols_pallas([xs, xd], fr, **kw))
+
+        def xla(cols_, xs, xd, *ys):
+            to_edge = lambda c_: wigner_rotate_reference(
+                c_, [xs, xd], n_ops=2, to_edge=True, **kw)
+            fr, vjp = jax.vjp(to_edge, cols_)
+            return side_by_side(
+                fr, wigner_rotate_reference(cols_, ys, n_ops=1,
+                                            to_edge=False, **kw),
+                vjp(fr)[0])
+
+        return kernel, xla, (cols, *labs, *pieces)
+
     return (("segment_sum", segment_sum), ("edge_aggregate", edge_aggregate),
-            ("so2_conv", so2_conv))
+            ("so2_conv", so2_conv), ("wigner_rotate", wigner_rotate))
 
 
 def phase_kernels(bands: dict, seed: int = 0) -> list:

@@ -34,6 +34,7 @@ import os
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any
 
 import jax
@@ -42,7 +43,14 @@ import jax.numpy as jnp
 from ..ops.segment import masked_segment_sum
 from ..telemetry import scope
 from .segment import pallas_edge_aggregate, pallas_segment_sum
-from .so3 import packed_m_layout, so2_conv_pallas, so2_conv_reference
+from .so3 import (
+    packed_m_layout,
+    so2_conv_pallas,
+    so2_conv_reference,
+    wigner_dcols_pallas,
+    wigner_rotate_pallas,
+    wigner_rotate_reference,
+)
 
 # node arrays larger than this are pre-gathered by XLA instead of riding
 # VMEM into the kernel for the in-kernel gather
@@ -78,6 +86,10 @@ TPU_DEFAULT_MODE = {
     # block over a 32768-edge chunk; max rel err 2.3e-7 (float32), 2.0e-3
     # (bfloat16) — chip run, PR 21
     "so2_conv": "pallas",
+    # compiled on a TPU v5 lite at eSCN-MD's (E_c=32768, 9 * 128) rows with
+    # the 35 block entries as float32 columns, both directions and the
+    # columns' cotangent; agreement and timings: PERF.md section 6, PR 31
+    "wigner_rotate": "pallas",
 }
 
 
@@ -698,3 +710,91 @@ def fused_so2_conv(h, weights, m_idx: dict, channels: int, kernels=None,
 
     f.defvjp(f_fwd, f_bwd)
     return f(h, *weights)
+
+
+# ---------------------------------------------------------------------------
+# Wigner rotation: lab rows <-> the edge frame's per-m pieces (eSCN-MD)
+# ---------------------------------------------------------------------------
+
+def fused_wigner_rotate(cols, ins, lay, *, to_edge: bool, kernels=None):
+    """Rotation by per-edge Wigner blocks given as columns, dispatched.
+
+    ``cols``: ``(E, n_cols)`` float32, the blocks' entries
+    (``so3.wigner_cols``). ``to_edge``: ``ins`` is a tuple of lab operands
+    ``(E, S * c)`` and the result the dict ``{m: (E, nl_m * len(ins) * c)}``
+    of ``lay.signed_ms`` pieces, a degree's lanes running through ``ins`` in
+    order. Otherwise ``ins`` is a dict of pieces (``c`` lanes a degree;
+    absent pieces are skipped, not multiplied as zeros) and the result one
+    lab operand ``(E, S * c)``. ``lay``: the model's ``CoeffLayout``.
+
+    The kernel path is taken when the mode resolves to Pallas and ``c`` is
+    a multiple of the 128 lanes; its backward is the same kernels (the
+    rows' cotangent is the opposite rotation, the columns' a third pass).
+    Otherwise the batched per-l products of ``so3.wigner_rotate_reference``.
+    """
+    if to_edge:
+        ms, n_ops, arrays = tuple(lay.signed_ms), len(ins), tuple(ins)
+        c = arrays[0].shape[1] // (lay.l_max + 1) ** 2
+    else:
+        ms, n_ops = tuple(m for m in lay.signed_ms if m in ins), 1
+        arrays = tuple(ins[m] for m in ms)
+        c = ins[0].shape[1] // lay.m_size(0)
+    statics = dict(l_max=lay.l_max, m_max=lay.m_max, ms=ms, channels=c,
+                   n_ops=n_ops)
+    mode = resolve_kernel_mode(kernels, op="wigner_rotate")
+    use = mode != "xla" and c % 128 == 0 and cols.shape[0] > 0
+    _count("wigner_rotate", use)
+    if use:
+        rot_edge, rot_lab, _ = _wigner_vjps(mode == "interpret", **statics)
+        out = (rot_edge if to_edge else rot_lab)(cols, *arrays)
+    else:
+        out = wigner_rotate_reference(cols, arrays, to_edge=to_edge,
+                                      **statics)
+    return dict(zip(ms, out)) if to_edge else out[0]
+
+
+def _wigner_vjps(interpret: bool, *, n_ops: int, **statics):
+    """``(to_edge, to_lab, dcols)`` over the Pallas kernels, each a
+    ``custom_vjp`` whose backward is the other two, so any order of
+    derivative stays on the kernels. Every traced operand is an explicit
+    argument (the calls sit inside scanned, checkpointed bodies); the
+    statics ride this closure."""
+    rotate = partial(wigner_rotate_pallas, n_ops=n_ops, interpret=interpret,
+                     **statics)
+
+    @jax.custom_vjp
+    def to_edge(cols, *labs):
+        return rotate(cols, labs, to_edge=True)
+
+    @jax.custom_vjp
+    def to_lab(cols, *pieces):
+        return rotate(cols, pieces, to_edge=False)
+
+    @jax.custom_vjp
+    def dcols(labs, pieces):
+        return wigner_dcols_pallas(labs, pieces, interpret=interpret,
+                                   **statics)
+
+    def fwd(f):
+        return lambda cols, *ins: (f(cols, *ins), (cols, ins))
+
+    def to_edge_bwd(res, g):
+        cols, labs = res
+        return (dcols(labs, tuple(g)), *to_lab(cols, *g))
+
+    def to_lab_bwd(res, g):
+        cols, pieces = res
+        return (dcols(tuple(g), pieces), *to_edge(cols, *g))
+
+    def dcols_bwd(res, g):
+        labs, pieces = res
+        return (tuple(x.astype(a.dtype) for x, a in
+                      zip(to_lab(g, *pieces), labs)),
+                tuple(x.astype(a.dtype) for x, a in
+                      zip(to_edge(g, *labs), pieces)))
+
+    to_edge.defvjp(fwd(to_edge), to_edge_bwd)
+    to_lab.defvjp(fwd(to_lab), to_lab_bwd)
+    dcols.defvjp(lambda labs, pieces: (dcols(labs, pieces), (labs, pieces)),
+                 dcols_bwd)
+    return to_edge, to_lab, dcols

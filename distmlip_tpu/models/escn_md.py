@@ -50,6 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..geometry import COORD_PRECISION
+from ..kernels.dispatch import fused_wigner_rotate
+from ..kernels.so3 import wigner_cols
 from ..ops import radial
 from ..ops.nn import cast_params_subtrees
 from ..ops.so3_e3nn import CoeffLayout, wigner_blocks_from_edges
@@ -241,45 +243,16 @@ class ESCNMD:
                             axis=0)
         return x * w_full
 
-    # Between ``_rotate_in`` and ``_rotate_out`` the edge-frame coefficients
-    # travel as PIECES: a dict ``{m: (E_c, nl_m * c)}`` over the signed m of
-    # ``lay.signed_ms`` (0, +1, -1, ..), each the l = |m|..lmax coefficients
-    # of that m side by side on the lane axis, l-major, c channels a degree
-    # (``lay.piece_rows``). That is the operand the SO(2) weights multiply, so
-    # every step in between is a matrix product, an elementwise product or a
-    # static lane slice: no (E_c, S, c) array, no index list, no scatter.
-
-    def _rotate_in(self, hvecs, D):
-        """Lab ``(E_c, S_full, c_k)`` arrays ``hvecs`` -> the edge frame's
-        pieces: per degree the transposed block times the features, of which
-        only the center 2*min(l,mmax)+1 rows are computed. A piece's lanes
-        run l-major and within a degree through ``hvecs`` in order (source
-        channels, then target channels), ``sum(c_k)`` lanes a degree."""
-        lay = self.lay
-        parts = [[jnp.einsum("epn,epc->enc",
-                             D[l][:, :, lay.block_rows(l)].astype(h.dtype),
-                             h[:, l * l:(l + 1) ** 2, :]) for h in hvecs]
-                 for l in range(lay.l_max + 1)]
-        return {m: jnp.concatenate(
-            [part[:, row, :] for l, row in lay.piece_rows(m)
-             for part in parts[l]], axis=-1) for m in lay.signed_ms}
-
-    def _rotate_out(self, y, D):
-        """Pieces ``y`` (c lanes a degree) -> lab ``(E_c, S_full, c)``: block
-        l times the rows its degree has among the pieces, each row a static
-        lane slice. Pieces that are absent (the edge-degree embedding has
-        m = 0 only) are skipped, not multiplied as zeros."""
-        lay = self.lay
-        c = y[0].shape[1] // lay.m_size(0)
-        blocks = []
-        for l in range(lay.l_max + 1):
-            # a contiguous run: every |m| <= min(l, mmax), or 0 alone
-            ms = [m for m in range(-l, l + 1) if m in y]
-            rows = jnp.stack([y[m][:, (l - abs(m)) * c:(l + 1 - abs(m)) * c]
-                              for m in ms], axis=1)
-            Dl = D[l][:, :, l + ms[0]:l + ms[-1] + 1].astype(rows.dtype)
-            blocks.append(jnp.einsum("epn,enc->epc", Dl, rows))
-        return jnp.concatenate(blocks, axis=1)
+    # Between the two rotations (``kernels/dispatch.fused_wigner_rotate``)
+    # the edge-frame coefficients travel as PIECES: a dict ``{m: (E_c, nl_m *
+    # c)}`` over the signed m of ``lay.signed_ms`` (0, +1, -1, ..), each the
+    # l = |m|..lmax coefficients of that m side by side on the lane axis,
+    # l-major, c channels a degree (``lay.piece_rows``; into the edge frame
+    # the sender's channels, then the receiver's). That is the operand the
+    # SO(2) weights multiply, and lab rows are flat ``(E_c, S * c)`` too, so
+    # every step of a chunk is a matrix product, an elementwise product, a
+    # static lane slice or the rotation's one pass: no (E_c, S, c) array, no
+    # index list, no scatter.
 
     def _so2_conv(self, p, fr, rad_scale, c_out):
         """SO(2) convolution on pieces ``fr``; returns ``(pieces, extra)``
@@ -364,12 +337,13 @@ class ESCNMD:
     # ---- forward ---------------------------------------------------------
     def energy_fn(self, params, lg, positions):
         """Which precision runs where (``cfg.dtype = "bfloat16"``): edge
-        geometry, Wigner blocks (cast to bfloat16 at every use), the MOLE
+        geometry, Wigner blocks (float32 columns into the rotation kernel,
+        which multiplies and sums in float32 and rounds its result once; off
+        the kernel path they are cast to bfloat16 at every use), the MOLE
         gate with its softmax and the expert collapse, the energy head and
-        ``species_ref`` stay float32; rotations (the blocks cast per use),
-        radial functions, SO(2) convolutions, gate activation, norms and
-        feed-forward run in the compute dtype. Every line sits in a stage
-        scope (telemetry/stages.py)."""
+        ``species_ref`` stay float32; radial functions, SO(2) convolutions,
+        gate activation, norms and feed-forward run in the compute dtype.
+        Every line sits in a stage scope (telemetry/stages.py)."""
         cfg = self.cfg
         C, H, S = cfg.sphere_channels, cfg.hidden_channels, cfg.sphere_dim
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else positions.dtype
@@ -459,22 +433,31 @@ class ESCNMD:
         edge_xs = lg.edge_chunks(cfg.edge_chunk, rhat, gauss, env)
 
         # per-l lab-from-edge blocks; ops/so3_e3nn builds them at >= fp32
-        # with pole-safe angles, downcast per-use in rotate_in/rotate_out:
-        # COORD_PRECISION products only where float32 blocks are used
+        # with pole-safe angles: COORD_PRECISION products only in a float32
+        # model (bfloat16 rows lose those digits anyway)
         wigner_blocks = partial(
             wigner_blocks_from_edges, cfg.lmax,
             precision=None if dtype == jnp.bfloat16 else COORD_PRECISION)
 
         def edge_scan(per_chunk):
-            """Chunked edge sum of ``per_chunk(srcc, dstc, maskc, D, gaussc,
-            envc) -> (E_c, S, C)``; the blocks are rebuilt per chunk."""
+            """Chunked edge sum ``(n_cap, S, C)`` of ``per_chunk(srcc, dstc,
+            maskc, cols, cols_env, gaussc) -> (E_c, S * C)`` rows; the blocks
+            are rebuilt per chunk, as the rotation's float32 columns
+            ``cols`` and, for the way back to the lab frame, ``cols_env``:
+            the columns times the envelope, in which a rotation is linear."""
             def with_blocks(srcc, dstc, maskc, rhatc, gaussc, envc):
                 with scope("edge_rotation"):
-                    D = wigner_blocks(rhatc)
-                return per_chunk(srcc, dstc, maskc, D, gaussc, envc)
+                    cols = wigner_cols(wigner_blocks(rhatc))
+                    cols_env = cols * envc.astype(cols.dtype)[:, None]
+                return per_chunk(srcc, dstc, maskc, cols, cols_env, gaussc)
 
-            return lg.scan_edges(with_blocks, edge_xs, (S, C), dtype,
+            rows = lg.scan_edges(with_blocks, edge_xs, (S * C,), dtype,
                                  remat=cfg.remat)
+            with scope("edge_aggregate"):
+                return rows.reshape(-1, S, C)
+
+        rotate = partial(fused_wigner_rotate, lay=self.lay,
+                         kernels=lg.kernels)
 
         def radial_of(p, srcc, dstc, gaussc):
             """Radial function of [gaussians | source | target species]."""
@@ -488,11 +471,11 @@ class ESCNMD:
         # --- edge-degree embedding (escn_md.py:221-247): radial weights
         # placed in the edge frame's m=0 slots, rotated to the lab frame,
         # degree-summed onto the receiver, / avg_degree
-        def deg_chunk(srcc, dstc, maskc, D, gaussc, envc):
+        def deg_chunk(srcc, dstc, maskc, cols, cols_env, gaussc):
             w = radial_of(params["edge_deg_rad"], srcc, dstc, gaussc)
             with scope("edge_rotation"):
                 # w is the m = 0 piece as the radial function leaves it
-                return self._rotate_out({0: w}, D) * envc[:, None, None]
+                return rotate(cols_env, {0: w}, to_edge=False)
 
         inv_deg = jnp.asarray(1.0 / cfg.avg_degree, dtype=dtype)
         with scope("embedding"):
@@ -503,20 +486,21 @@ class ESCNMD:
 
         for t, blk in enumerate(params["blocks"]):
 
-            def so2_chunk(srcc, dstc, maskc, D, gaussc, envc, blk=blk):
+            def so2_chunk(srcc, dstc, maskc, cols, cols_env, gaussc,
+                          blk=blk):
                 # per-coefficient scales
                 rad = radial_of(blk["so2_1"]["rad"], srcc, dstc, gaussc)
                 with scope("edge_message"):
-                    xn_src = hn[srcc]
-                    xn_dst = hn[dstc]
+                    xn_src = hn_rows[srcc]
+                    xn_dst = hn_rows[dstc]
                 with scope("edge_rotation"):
-                    fr = self._rotate_in((xn_src, xn_dst), D)
+                    fr = rotate(cols, (xn_src, xn_dst), to_edge=True)
                 with scope("edge_message"):
                     y, gates = self._so2_conv(blk["so2_1"], fr, rad, H)
                     y = self._gate_act(y, gates)
                     y, _ = self._so2_conv(blk["so2_2"], y, None, C)
                 with scope("edge_rotation"):
-                    return self._rotate_out(y, D) * envc[:, None, None]
+                    return rotate(cols_env, y, to_edge=False)
 
             with scope(f"layer{t}"):
                 # message path reads the NORMALIZED features (with the
@@ -525,6 +509,8 @@ class ESCNMD:
                 with scope("node_tensor"):
                     hn = self._rms_norm_sh(blk["norm1"]["w"], h)
                     hn = hn.at[:, 0, :].add(csd[None, :])
+                    # flat rows: the edge side holds no 9-long tile axis
+                    hn_rows = hn.reshape(-1, S * C)
                 msg = edge_scan(so2_chunk)
                 with scope("node_tensor"):
                     h = h + msg * inv_deg

@@ -120,6 +120,25 @@ def test_serve_phase_at_toy_width():
     assert out["failed"] == 1 and out["poisoned_failed_with"] == "ValueError"
 
 
+def test_kernels_phase_has_a_row_for_every_entry_of_the_default_table():
+    """The KERNELS phase re-takes the measurement behind every entry of
+    ``TPU_DEFAULT_MODE``; a row's kernel and XLA functions give one array
+    of one shape (what the phase compares), traced here without running:
+    the shapes are the published ones."""
+    import jax.numpy as jnp
+
+    from distmlip_tpu.kernels.dispatch import TPU_DEFAULT_MODE
+
+    cases = dict(chip_smoke._kernel_cases(0))
+    assert sorted(cases) == sorted(TPU_DEFAULT_MODE)
+    kernel, xla, args = cases["wigner_rotate"](jnp.bfloat16)
+    assert args[0].shape == (32768, 35) and args[0].dtype == jnp.float32
+    got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
+    # five pieces of two operands, nine lab rows, the columns' cotangent
+    assert got.shape == want.shape == (32768, (2 * 9 + 9) * 128 + 35)
+    assert got.dtype == want.dtype == jnp.bfloat16
+
+
 def test_chip_bands_carry_their_measurement():
     """Every band asserted on the chip has the measured value beside it,
     and the band is not tighter than what was measured."""

@@ -310,13 +310,15 @@ def _definition(lay, cfg, blk, hs, hd, D, rad):
 
 @pytest.mark.parametrize("lmax, mmax", [(2, 2), (2, 1), (3, 2)])
 def test_pieces_match_the_l_major_definition(lmax, mmax):
-    """Every stage between ``_rotate_in`` and ``_rotate_out``, piece by
-    piece, equals the l-major stack read through ``plus_idx`` /
+    """Every stage between the two rotations (``fused_wigner_rotate``: off
+    the TPU the batched per-l products), piece by piece, equals the l-major stack read through ``plus_idx`` /
     ``minus_idx``; so does the gradient with respect to the node features
     (against central differences of the definition), and the edge-degree
     embedding's m = 0 piece rotated out alone."""
     import jax.numpy as jnp
 
+    from distmlip_tpu.kernels.dispatch import fused_wigner_rotate
+    from distmlip_tpu.kernels.so3 import wigner_cols
     from distmlip_tpu.ops.so3_e3nn import wigner_blocks_from_edges
 
     cfg = ESCNMDConfig(**{**CFG.__dict__, "lmax": lmax, "mmax": mmax,
@@ -346,13 +348,21 @@ def test_pieces_match_the_l_major_definition(lmax, mmax):
         assert Dn[0].dtype == np.float64
         ref = _definition(lay, cfg, blk, hs, hd, Dn, rad)
 
+        cols = wigner_cols(D)
+
+        def rotate_out(y):
+            return fused_wigner_rotate(cols, y, lay, to_edge=False).reshape(
+                E, cfg.sphere_dim, C)
+
         def chain(hs, hd):
-            fr = model._rotate_in((hs, hd), D)
+            fr = fused_wigner_rotate(
+                cols, (hs.reshape(E, -1), hd.reshape(E, -1)), lay,
+                to_edge=True)
             y1, gates = model._so2_conv(blk["so2_1"], fr, rad,
                                         cfg.hidden_channels)
             y2 = model._gate_act(y1, gates)
             y3, none = model._so2_conv(blk["so2_2"], y2, None, C)
-            return fr, y1, gates, y2, y3, none, model._rotate_out(y3, D)
+            return fr, y1, gates, y2, y3, none, rotate_out(y3)
 
         fr, y1, gates, y2, y3, none, out = chain(hs, hd)
         for pieces, stack in zip((fr, y1, y2, y3),
@@ -377,8 +387,7 @@ def test_pieces_match_the_l_major_definition(lmax, mmax):
         deg = np.concatenate([
             Dn[l][:, :, l, None] * w[:, None, l * C:(l + 1) * C]
             for l in range(lmax + 1)], axis=1)
-        np.testing.assert_allclose(model._rotate_out({0: w}, D), deg,
-                                   atol=1e-12)
+        np.testing.assert_allclose(rotate_out({0: w}), deg, atol=1e-12)
 
 
 # energy (eV) and forces (eV/A) of atoms 0, 5 and 17 as PR 28's tree (the

@@ -17,6 +17,7 @@ from distmlip_tpu.analysis.ir import iter_sites
 from distmlip_tpu.calculators import Atoms, DistPotential
 from distmlip_tpu.geometry import (COORD_PRECISION, frac_to_cart,
                                    make_supercell)
+from distmlip_tpu.kernels.so3 import wigner_n_cols
 from distmlip_tpu.models import ESCNMD, ESCNMDConfig
 from distmlip_tpu.ops.so3_e3nn import wigner_blocks_from_edges
 from distmlip_tpu.telemetry import STAGES
@@ -102,11 +103,11 @@ def test_every_equation_of_the_model_carries_a_stage(nparts, kernels):
 @pytest.mark.parametrize("nparts", [1, 2])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_edge_message_addresses_rows_by_index_only_on_nodes(dtype, nparts):
-    """Between ``_rotate_in`` and ``_rotate_out`` the coefficients are flat
-    per-m pieces cut by static slices: under ``edge_message`` nothing is
-    written by index (no ``scatter``, no ``scatter-mul``) and the only rows
-    read by index are the model's own ``hn[src]`` / ``hn[dst]``, with the
-    scatter-adds onto nodes that transpose them. The l-major ``(E, 9, c)``
+    """Between the two rotations the coefficients are flat per-m pieces cut
+    by static slices: under ``edge_message`` nothing is written by index
+    (no ``scatter``, no ``scatter-mul``) and the only rows read by index are
+    the model's own ``hn[src]`` / ``hn[dst]`` (flat ``S * C`` rows of the
+    node array), with the scatter-adds onto nodes that transpose them. The l-major ``(E, 9, c)``
     layout addressed through index lists counted 48 ``scatter``, 12
     ``scatter-mul``, 66 ``scatter-add`` and 117 rank-3 ``gather`` here
     (PR 28's tree, this config), each a loop of whole-array updates on the
@@ -116,15 +117,62 @@ def test_edge_message_addresses_rows_by_index_only_on_nodes(dtype, nparts):
     msg = [s for s in sites if stage_of(s.stack) == "edge_message"]
     assert any(s.primitive == "dot_general" for s in msg)
     assert not [s for s in msg if s.primitive in ("scatter", "scatter-mul")]
-    node_shape = (graph.n_cap, cfg.sphere_dim, cfg.sphere_channels)
+    node_shape = (graph.n_cap, cfg.sphere_dim * cfg.sphere_channels)
     adds = [s.eqn.outvars[0].aval.shape for s in msg
             if s.primitive == "scatter-add"]
     gathers = [s.eqn.invars[0].aval.shape for s in msg
-               if s.primitive == "gather"
-               and len(s.eqn.invars[0].aval.shape) == 3]
+               if s.primitive == "gather"]
     # two a layer; forward and recompute read, the backward adds
     assert adds == [node_shape] * (2 * cfg.num_layers), adds
     assert gathers == [node_shape] * (4 * cfg.num_layers), gathers
+
+
+def test_rotation_rows_pass_through_the_kernels_alone():
+    """At a whole lane tile of channels with the kernel path forced, every
+    equation under ``edge_rotation`` inside the scans that touches a row of
+    channels is a rotation kernel: no ``concatenate``, ``gather``,
+    ``scatter``, product or elementwise pass over an ``(E_c, .., c)``
+    operand (the envelope multiplies the ``(E_c, 35)`` block columns).
+    What else sits there builds the blocks and hands them over, on arrays
+    of at most 35 lanes. Every equation still carries a stage."""
+    cfg = config(dtype="bfloat16", sphere_channels=128, hidden_channels=128,
+                 num_layers=1, num_experts=1)
+    sites = step_sites(cfg, kernels="interpret")
+    model = [s for s in sites if "model_energy" in s.stack]
+    assert not [s.primitive for s in model if stage_of(s.stack) is None]
+    rot = [s for s in model if stage_of(s.stack) == "edge_rotation"
+           and any(p in LOOPS for p in s.path)
+           and "pallas_call" not in s.path]     # not the kernels' bodies
+    n_cols = wigner_n_cols(cfg.lmax)
+
+    def rows(s):
+        """Shapes of the rank >= 2 operands and results a whole lane tile
+        wide or wider: rows of channels."""
+        return [v.aval.shape for v in [*s.eqn.invars, *s.eqn.outvars]
+                if len(getattr(v.aval, "shape", ())) >= 2
+                and v.aval.shape[-1] >= cfg.sphere_channels]
+
+    wide = [s for s in rot if rows(s)]
+    wrappers = {"pallas_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+                "checkpoint", "remat", "pjit", "jit", "closed_call"}
+    # besides the kernels: the columns' cotangent leaves its kernel as one
+    # float32 lane tile, of which the 35 columns are cut
+    cuts = [s for s in wide if s.primitive not in wrappers]
+    assert {s.primitive for s in cuts} <= {"slice"}, cuts
+    assert all(rows(s) == [(cfg.edge_chunk, 128)]
+               and s.eqn.outvars[0].aval.shape == (cfg.edge_chunk, n_cols)
+               for s in cuts)
+    assert "pallas_call" in {s.primitive for s in wide}
+    calls = [s for s in rot if s.primitive == "pallas_call"]
+    # the edge-degree scan: out, and backward the columns' cotangent (no
+    # cotangent reaches its radial rows' producer but through the rows);
+    # a layer: in + out forward and recomputed, four passes backward
+    assert len(calls) >= 2 + 8 * cfg.num_layers, len(calls)
+    small = {s.primitive for s in rot if not rows(s)}
+    assert "dot_general" in small    # the blocks' own X J X J products
+    pallas = [s for s in model if s.primitive == "pallas_call"]
+    assert {stage_of(s.stack) for s in pallas} == {"edge_rotation",
+                                                   "edge_aggregate"}
 
 
 def block_products(sites):

@@ -351,7 +351,8 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
                          mask=None, indices_are_sorted: bool = True,
                          kernels=None, diff_params: bool = True,
                          vmem_budget: int | None = None,
-                         bwd_chunk: int | None = None):
+                         bwd_chunk: int | None = None,
+                         stages: tuple = ("edge_message", "edge_aggregate")):
     """Fused gather + per-edge compute + dst-sorted segment sum.
 
     ``inputs``: per-edge arrays ``(E, ...)`` and/or :class:`Gather`
@@ -370,8 +371,14 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
     add for them (a custom_vjp marks every primal perturbed; without
     this knob the kernel path would ship weight-gradient bytes over the
     mesh on every force call that plain XLA AD never ships).
+
+    ``stages``: the two scopes (telemetry/stages.py) this call opens,
+    (message, aggregate). They are innermost, so a caller's own scope
+    around the call loses to them: a call over another graph than the
+    atom graph (CHGNet's line list) names its own here.
     """
     inputs = list(inputs)
+    msg_stage, agg_stage = stages
     mode = resolve_kernel_mode(kernels, op="edge_aggregate")
     e = int(segment_ids.shape[0])
     # float (inexact) masks would need a mask cotangent the chunked
@@ -387,9 +394,9 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
         # per-edge compute are the message (edge_fn's own scopes, e.g. a
         # radial MLP inside it, are innermost and win), the sum is the
         # aggregate; the fused kernel below is one operation: aggregate
-        with scope("edge_message"):
+        with scope(msg_stage):
             msg = edge_fn(*[_rows_of(i) for i in inputs])
-        with scope("edge_aggregate"):
+        with scope(agg_stage):
             return masked_segment_sum(msg, segment_ids, num_segments, mask,
                                       indices_are_sorted=indices_are_sorted)
 
@@ -509,7 +516,7 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
 
             return rowwise
 
-        with scope("edge_aggregate"):
+        with scope(agg_stage):
             in_cts, const_cts = _edge_aggregate_bwd(
                 make_rowwise, prep, arrs, dconsts, idxs_,
                 ids_, m_, g, chunk, diff_params)
@@ -527,7 +534,7 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
     # enclosing transpose needs only some, the rest (including their
     # scatter-adds) are dead and XLA DCEs them:
     # contract: allow(dead_compute)
-    with scope("edge_aggregate"):
+    with scope(agg_stage):
         return f(*diff)
 
 

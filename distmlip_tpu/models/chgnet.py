@@ -52,7 +52,9 @@ import jax.numpy as jnp
 from ..kernels.dispatch import Gather, fused_edge_aggregate
 from ..ops import radial
 from ..ops.nn import (cast_params_subtrees, embedding, gated_mlp,
-                      gated_mlp_init, linear, linear_init, mlp, mlp_init)
+                      gated_mlp_init, gather_rows, linear, linear_init, mlp,
+                      mlp_init)
+from ..telemetry import scope
 
 
 @dataclass(frozen=True)
@@ -152,9 +154,13 @@ class CHGNet:
     # ---- forward ----
     def energy_fn(self, params, lg, positions):
         v, _ = self._trunk(params, lg, positions)
-        e_atom = mlp(params["final"], v)[:, 0]
-        e_ref = params["species_ref"]["w"][lg.species, 0]
-        return params["data_std"] * e_atom + e_ref
+        return self._site_energy(params, lg, v)
+
+    def _site_energy(self, params, lg, v):
+        with scope("readout"):
+            e_atom = mlp(params["final"], v)[:, 0]
+            e_ref = params["species_ref"]["w"][lg.species, 0]
+            return params["data_std"] * e_atom + e_ref
 
     def energy_and_aux_fn(self, params, lg, positions):
         """Fused readout: per-atom energies plus the sitewise outputs
@@ -162,10 +168,9 @@ class CHGNet:
         contract: magmom-every-step MD pays no second forward (parity
         against ``magmom_fn``: tests/test_halo_overlap.py)."""
         v, site = self._trunk(params, lg, positions)
-        e_atom = mlp(params["final"], v)[:, 0]
-        e_ref = params["species_ref"]["w"][lg.species, 0]
-        energy = params["data_std"] * e_atom + e_ref
-        return energy, {"magmoms": jnp.abs(site[:, 0])}
+        energy = self._site_energy(params, lg, v)
+        with scope("readout"):
+            return energy, {"magmoms": jnp.abs(site[:, 0])}
 
     def magmom_fn(self, params, lg, positions):
         """Site-wise magnetic moments (absolute value), CHGNet's charge proxy.
@@ -189,7 +194,13 @@ class CHGNet:
 
     def _trunk(self, params, lg, positions):
         """Returns (atom features after the LAST conv, sitewise readout taken
-        BEFORE it — matgl's ordering, reference chgnet.py:391-419)."""
+        BEFORE it — matgl's ordering, reference chgnet.py:391-419).
+
+        Every operation sits under a stage scope (telemetry/stages.py): the
+        atom graph under the stages every family has, the bond graph under
+        ``line_geometry`` / ``line_message`` / ``angle_update`` /
+        ``bond_map`` (the last opened by ``lg.edge_to_bond`` /
+        ``lg.bond_to_edge`` themselves)."""
         cfg = self.cfg
         C = cfg.units
         # features/GEMMs in the compute dtype; geometry, basis frequencies
@@ -197,79 +208,70 @@ class CHGNet:
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else positions.dtype
         fp = params
         if cfg.dtype == "bfloat16":
-            params = cast_params_subtrees(
-                params, dtype,
-                keep_fp32=("freq_bond", "freq_three", "freq_angle",
-                           "sitewise", "final", "species_ref", "data_std"))
+            with scope("node_linear"):
+                params = cast_params_subtrees(
+                    params, dtype,
+                    keep_fp32=("freq_bond", "freq_three", "freq_angle",
+                               "sitewise", "final", "species_ref",
+                               "data_std"))
 
         # --- geometry + bases ---
-        vec = lg.edge_vectors(positions)
-        d = jnp.linalg.norm(jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
-        # matgl's graph simply has no edges beyond the cutoff; our neighbor
-        # list may carry skin-shell edges (cutoff < d <= cutoff+skin) for MD
-        # reuse, and the learnable bessel basis does not vanish out there —
-        # so in-cutoff membership is enforced explicitly, both on the basis
-        # (-> shared weights, embeddings) and on the message masks below.
-        # At d = cutoff this matches matgl exactly (its basis is ~0 there
-        # for near-n*pi frequencies; the hard edge-set boundary is matgl's).
-        in_r = lg.edge_mask & (d <= cfg.cutoff)
-        rbf = (self._expansion(d, fp["freq_bond"], cfg.cutoff)
-               * in_r[:, None]).astype(dtype)
+        with scope("edge_geometry"):
+            vec = lg.edge_vectors(positions)
+            d = jnp.linalg.norm(
+                jnp.where(lg.edge_mask[:, None], vec, 1.0), axis=-1)
+            # matgl's graph simply has no edges beyond the cutoff; our
+            # neighbor list may carry skin-shell edges (cutoff < d <=
+            # cutoff+skin) for MD reuse, and the learnable bessel basis does
+            # not vanish out there — so in-cutoff membership is enforced
+            # explicitly, both on the basis (-> shared weights, embeddings)
+            # and on the message masks below. At d = cutoff this matches
+            # matgl exactly (its basis is ~0 there for near-n*pi
+            # frequencies; the hard edge-set boundary is matgl's).
+            in_r = lg.edge_mask & (d <= cfg.cutoff)
+            rbf = (self._expansion(d, fp["freq_bond"], cfg.cutoff)
+                   * in_r[:, None]).astype(dtype)
 
         # --- feature init ---
         # v: pre-exchange view (owned rows authoritative); vx: post-exchange
         # view. Interior edges (both endpoints owned) read v so their
         # compute is data-independent of the in-flight ppermute producing
         # vx — the interior/frontier overlap scheduling (parallel/halo.py).
-        v = embedding(params["atom_emb"], lg.species)     # (N, C)
-        e = mlp(params["bond_emb"], rbf)                  # (E, C)
-
-        # shared rbf message weights (reference chgnet.py:267-294)
-        abw = linear(params["atom_bond_w"], rbf) if "atom_bond_w" in params else None
-        bbw = linear(params["bond_bond_w"], rbf) if "bond_bond_w" in params else None
+        with scope("node_linear"):
+            v = embedding(params["atom_emb"], lg.species)     # (N, C)
+        with scope("radial_mlp"):
+            e = mlp(params["bond_emb"], rbf)                  # (E, C)
+            # shared rbf message weights (reference chgnet.py:267-294)
+            abw = (linear(params["atom_bond_w"], rbf)
+                   if "atom_bond_w" in params else None)
+            bbw = (linear(params["bond_bond_w"], rbf)
+                   if "bond_bond_w" in params else None)
 
         use_bg = cfg.use_bond_graph and lg.has_bond_graph and params["bond_blocks"]
         if use_bg:
-            # bond-node geometry: seed owned from edges, exchange halo rows
-            # (reference bond_transfer of bond_dist/bond_vec, chgnet.py:
-            # 126-164) — COALESCED with the atom-feature init exchange: both
-            # refreshes ride one ppermute per ring shift
-            bgeo = jnp.zeros((lg.b_cap, 4), dtype=positions.dtype)
-            edge_geo = jnp.concatenate([vec, d[:, None]], axis=-1)
-            bgeo = lg.edge_to_bond(edge_geo, bgeo)
-            (vx,), (bgeo,) = lg.exchange_all((v,), (bgeo,))
-            b_vec, b_d = bgeo[:, :3], bgeo[:, 3]
-            # padded bond rows have d=0; skin-shell bonds (d > bond_cutoff)
-            # are excluded like skin-shell edges above
-            b_real = (b_d > 1e-6) & (b_d <= cfg.bond_cutoff)
-            rbf3 = (self._expansion(
-                jnp.where(b_d > 1e-6, b_d, 1.0), fp["freq_three"],
-                cfg.bond_cutoff) * b_real[:, None]).astype(dtype)
-            tbw = (linear(params["three_bond_w"], rbf3)
-                   if "three_bond_w" in params else None)
+            with scope("line_geometry"):
+                b_vec, b_d, vx = self._bond_geometry(lg, vec, d, v)
+                # padded bond rows have d=0; skin-shell bonds (d >
+                # bond_cutoff) are excluded like skin-shell edges above
+                b_real = (b_d > 1e-6) & (b_d <= cfg.bond_cutoff)
+                rbf3 = (self._expansion(
+                    jnp.where(b_d > 1e-6, b_d, 1.0), fp["freq_three"],
+                    cfg.bond_cutoff) * b_real[:, None]).astype(dtype)
+                tbw = (linear(params["three_bond_w"], rbf3)
+                       if "three_bond_w" in params else None)
 
-            # line edges are live only when BOTH bonds are real and within
-            # the threebody cutoff (matgl's line graph contains only such
-            # pairs; skin-shell bonds must contribute nothing)
-            line_ok = lg.line_mask & b_real[lg.line_src] & b_real[lg.line_dst]
+                # line edges are live only when BOTH bonds are real and
+                # within the threebody cutoff (matgl's line graph contains
+                # only such pairs; skin-shell bonds must contribute nothing)
+                line_ok = (lg.line_mask & b_real[lg.line_src]
+                           & b_real[lg.line_dst])
+                a = self._angle_features(params, fp, lg, b_vec, b_d, dtype)
 
-            # angle features on line-graph edges (theta at the center atom;
-            # reference src_bond_sign=-1 + compute_theta, chgnet.py:184-197)
-            v1 = b_vec[lg.line_src]
-            v2 = b_vec[lg.line_dst]
-            d1 = jnp.maximum(b_d[lg.line_src], 1e-6)
-            d2 = jnp.maximum(b_d[lg.line_dst], 1e-6)
-            cos_t = -jnp.sum(v1 * v2, axis=-1) / (d1 * d2)
-            cos_t = jnp.clip(cos_t, -1.0 + 1e-6, 1.0 - 1e-6)
-            theta = jnp.arccos(cos_t)
-            a = mlp(params["angle_emb"],
-                    radial.matgl_fourier_expansion(
-                        theta, fp["freq_angle"]).astype(dtype))  # (L, C)
-
-            # bond-node features are (re-)seeded from edge features at the
-            # top of every block (reference dist_forward re-seeds the same
-            # way, :253-264, :315-321), so no separate init pass is needed
-            b = jnp.zeros((lg.b_cap, C), dtype=e.dtype)
+                # bond-node features are (re-)seeded from edge features at
+                # the top of every block (reference dist_forward re-seeds
+                # the same way, :253-264, :315-321), so no separate init
+                # pass is needed
+                b = jnp.zeros((lg.b_cap, C), dtype=e.dtype)
         else:
             vx = lg.halo_exchange(v)
 
@@ -298,7 +300,8 @@ class CHGNet:
         # sitewise readout BEFORE the last atom conv (reference :391-398);
         # owned rows of v and vx are identical — vx keeps halo-row parity
         # with the historical post-exchange readout
-        site = linear(fp["sitewise"], vx.astype(positions.dtype))
+        with scope("readout"):
+            site = linear(fp["sitewise"], vx.astype(positions.dtype))
 
         # final atom conv (reference :400-419). No trailing halo exchange:
         # the energy/site readouts only consume owned rows (owned_sum /
@@ -306,7 +309,38 @@ class CHGNet:
         # last conv was dead communication.
         v, e = self._atom_conv(params["atom_blocks"][-1], lg, v, vx, e, abw,
                                bbw, in_r)
-        return v.astype(positions.dtype), site
+        with scope("readout"):
+            return v.astype(positions.dtype), site
+
+    def _bond_geometry(self, lg, vec, d, v):
+        """(vector, length) of every bond node, and the exchanged atom
+        features: owned rows seeded from their edges, halo rows (whose
+        endpoints may not be local) by the bond halo exchange (reference
+        bond_transfer of bond_dist/bond_vec, chgnet.py:126-164) —
+        COALESCED with the atom-feature init exchange: both refreshes ride
+        one ppermute per ring shift."""
+        bgeo = jnp.zeros((lg.b_cap, 4), dtype=vec.dtype)
+        edge_geo = jnp.concatenate([vec, d[:, None]], axis=-1)
+        bgeo = lg.edge_to_bond(edge_geo, bgeo)
+        (vx,), (bgeo,) = lg.exchange_all((v,), (bgeo,))
+        return bgeo[:, :3], bgeo[:, 3], vx
+
+    def _angle_features(self, params, fp, lg, b_vec, b_d, dtype):
+        """Embedded Fourier basis of theta on every line (L, C): theta at
+        the center atom (reference src_bond_sign=-1 + compute_theta,
+        chgnet.py:184-197). Coordinates, theta and the basis are float32:
+        fcc has collinear bond pairs, where arccos has slope
+        1 / sqrt(1 - cos^2) (about 50 at a 0.04 A perturbation)."""
+        v1 = b_vec[lg.line_src]
+        v2 = b_vec[lg.line_dst]
+        d1 = jnp.maximum(b_d[lg.line_src], 1e-6)
+        d2 = jnp.maximum(b_d[lg.line_dst], 1e-6)
+        cos_t = -jnp.sum(v1 * v2, axis=-1) / (d1 * d2)
+        cos_t = jnp.clip(cos_t, -1.0 + 1e-6, 1.0 - 1e-6)
+        theta = jnp.arccos(cos_t)
+        return mlp(params["angle_emb"],
+                   radial.matgl_fourier_expansion(
+                       theta, fp["freq_angle"]).astype(dtype))
 
     # ---- layers ----
     def _atom_conv(self, blk, lg, v, vx, e, abw, bbw, in_r):
@@ -322,12 +356,14 @@ class CHGNet:
         if "edge_update" in blk:
             # per-edge output (no dst aggregation): full edge list on the
             # post-exchange view, no overlap structure
-            feats = jnp.concatenate([vx[lg.edge_src], vx[lg.edge_dst], e],
-                                    axis=-1)
-            m = linear(blk["edge_out"], gated_mlp(blk["edge_update"], feats))
-            if bbw is not None:
-                m = m * bbw
-            e = e + m * in_r[:, None].astype(m.dtype)
+            with scope("edge_message"):
+                feats = jnp.concatenate(
+                    [vx[lg.edge_src], vx[lg.edge_dst], e], axis=-1)
+                m = linear(blk["edge_out"],
+                           gated_mlp(blk["edge_update"], feats))
+                if bbw is not None:
+                    m = m * bbw
+                e = e + m * in_r[:, None].astype(m.dtype)
 
         def node_msg(vs, vd, e_sl, *w_sl):
             m = gated_mlp(blk["node_update"],
@@ -335,8 +371,14 @@ class CHGNet:
             return m * w_sl[0] if w_sl else m
 
         edge_data = (e,) if abw is None else (e, abw)
-        agg = lg.overlapped_edge_sum(node_msg, v, vx, edge_data, mask=in_r)
-        v = vx + linear(blk["node_out"], agg)
+        # the dispatcher scopes gathers and messages itself (innermost
+        # wins); the segments' slices and the sum of their parts are the
+        # aggregate
+        with scope("edge_aggregate"):
+            agg = lg.overlapped_edge_sum(node_msg, v, vx, edge_data,
+                                         mask=in_r)
+        with scope("node_linear"):
+            v = vx + linear(blk["node_out"], agg)
         return v, e
 
     def _bond_node_conv(self, blk, lg, v, b, a, tbw, line_ok):
@@ -350,29 +392,36 @@ class CHGNet:
         The line-graph message (gathers + gated MLP + dst-sorted sum) goes
         through the kernel dispatcher: on the Pallas path it fuses per dst
         tile and the (L, 4C) concat / (L, C) message intermediates never
-        materialize; the XLA path is the historical program."""
+        materialize; the XLA path is the historical program. The
+        dispatcher's own scopes are innermost, so it is told this call's
+        stage: without that the three-body work reads as atom-graph work."""
 
         def line_msg(b_src, b_dst, a_row, v_ctr):
             return gated_mlp(blk["node_update"], jnp.concatenate(
                 [b_src, b_dst, a_row, v_ctr], axis=-1))
 
-        agg = fused_edge_aggregate(
-            line_msg,
-            [Gather(b, lg.line_src), Gather(b, lg.line_dst), a,
-             Gather(v, lg.line_center)],
-            lg.line_dst, lg.b_cap, line_ok, indices_are_sorted=True,
-            kernels=lg.kernels, diff_params=lg.kernels_diff_params)
-        upd = linear(blk["node_out"], agg)
-        if tbw is not None:
-            upd = upd * tbw
-        return b + upd
+        with scope("line_message"):
+            agg = fused_edge_aggregate(
+                line_msg,
+                [Gather(b, lg.line_src), Gather(b, lg.line_dst), a,
+                 Gather(v, lg.line_center)],
+                lg.line_dst, lg.b_cap, line_ok, indices_are_sorted=True,
+                kernels=lg.kernels, diff_params=lg.kernels_diff_params,
+                stages=("line_message", "line_message"))
+            upd = linear(blk["node_out"], agg)
+            if tbw is not None:
+                upd = upd * tbw
+            return b + upd
 
     def _angle_conv(self, blk, lg, v, b, a, line_ok):
         """Line-graph edge phase (angle update from the refreshed bond
         features, reference chgnet_layers.py:109-118): gated update on
-        [b_src|b_dst|angle|v_center], residual, no weights."""
-        feats = jnp.concatenate(
-            [b[lg.line_src], b[lg.line_dst], a, v[lg.line_center]], axis=-1
-        )
-        m = gated_mlp(blk["angle_update"], feats)
-        return a + m * line_ok[:, None].astype(m.dtype)
+        [b_src|b_dst|angle|v_center], residual, no weights. Rows gather
+        through ``gather_rows``: each bond is read by some twenty lines,
+        and their cotangents add up in float32."""
+        with scope("angle_update"):
+            feats = jnp.concatenate(
+                [gather_rows(b, lg.line_src), gather_rows(b, lg.line_dst), a,
+                 gather_rows(v, lg.line_center)], axis=-1)
+            m = gated_mlp(blk["angle_update"], feats)
+            return a + m * line_ok[:, None].astype(m.dtype)

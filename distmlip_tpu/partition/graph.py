@@ -560,7 +560,14 @@ def graph_build_stats(graph: PartitionedGraph) -> dict:
     if graph.has_bond_graph:
         bsend = np.asarray(graph.bond_halo_send_mask).sum(axis=(0, 2))
         stats["bond_halo_send_per_part"] = [int(x) for x in bsend]
+        # real rows of the bond graph beside n_edges_per_part: the bond
+        # nodes a partition computes (those mapped from one of its edges;
+        # halo bond rows arrive by exchange) and its live lines
+        lines = np.asarray(graph.line_mask).sum(axis=1)
+        stats["n_bonds_per_part"] = [
+            int(x) for x in np.asarray(graph.bond_map_mask).sum(axis=1)]
+        stats["n_lines_per_part"] = [int(x) for x in lines]
         # total live line-graph edges (angle terms) — the FLOP model's
         # third graph dimension
-        stats["n_lines"] = int(np.asarray(graph.line_mask).sum())
+        stats["n_lines"] = int(lines.sum())
     return stats

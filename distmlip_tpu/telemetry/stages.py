@@ -29,11 +29,19 @@ STAGES = (
     "readout",          # per-atom energies, scale and shift
     "pair_repulsion",   # ZBL
     "halo",             # exchange between partitions (parallel/halo.py)
+    # the bond (line) graph of a three-body model: bonds are its nodes,
+    # ordered pairs of bonds that share a centre atom its edges (lines)
+    "line_geometry",    # bond-node geometry, three-body basis, theta, Fourier
+    "line_message",     # three gathers, gated MLP, sum onto the dst bond
+    "angle_update",     # the angle feature of every line
+    "bond_map",         # edge rows onto bond nodes and back
 )
-# scopes parallel/halo.py has carried since PR 1; they ARE the halo stage
+# scopes parallel/halo.py has carried since PR 1; they ARE the halo stage,
+# and its two index remaps the bond_map stage
 _STAGE_OF_SCOPE = {
     **{s: s for s in STAGES}, "halo_exchange": "halo",
-    "bond_halo_exchange": "halo", "halo_exchange_all": "halo"}
+    "bond_halo_exchange": "halo", "halo_exchange_all": "halo",
+    "edge_to_bond": "bond_map", "bond_to_edge": "bond_map"}
 
 # jit(name) is a function's name, not a scope; jvp( transpose( vmap( ... wrap
 # scope stacks and may close many components later

@@ -343,7 +343,8 @@ def _kernel_cases(seed: int):
     import jax.numpy as jnp
 
     from distmlip_tpu.kernels.segment import (pallas_edge_aggregate,
-                                              pallas_segment_sum)
+                                              pallas_segment_sum,
+                                              pallas_segment_sum_into)
     from distmlip_tpu.kernels.so3 import (packed_m_layout, so2_conv_pallas,
                                           so2_conv_reference, wigner_cols,
                                           wigner_dcols_pallas,
@@ -459,8 +460,32 @@ def _kernel_cases(seed: int):
 
         return kernel, xla, (cols, *labs, *pieces)
 
+    def segment_sum_into(dtype):
+        # one scan chunk added into the carried accumulator, both shapes
+        # side by side: MACE's (E_c, 40, 128) messages into the flat
+        # (n_cap, 5120) carry of mace-md-1c, UMA-S's (E_c, 1152) rows into
+        # uma-md-1c's. A chunk of 78 built edges an atom lands in about 420
+        # rows: here rows 5000 to 5419, across four dst tiles
+        e = 32768
+        ids, mask = sorted_ids(e, 420)
+        ids = ids + 5000
+        shapes = ((29568, (40, 128)), (9856, (1152,)))
+        accs = [normal((n, int(np.prod(tr))), dtype) for n, tr in shapes]
+        rows = [normal((e,) + tr, dtype) for _, tr in shapes]
+
+        def both(add_into):
+            return lambda a0, a1, d0, d1: jnp.concatenate(
+                [add_into(a, d).reshape(-1) for a, d in ((a0, d0), (a1, d1))])
+
+        return (both(lambda a, d: pallas_segment_sum_into(a, d, ids, mask)),
+                both(lambda a, d: a + masked_segment_sum(
+                    d, ids, a.shape[0], mask,
+                    indices_are_sorted=True).reshape(a.shape)),
+                (*accs, *rows))
+
     return (("segment_sum", segment_sum), ("edge_aggregate", edge_aggregate),
-            ("so2_conv", so2_conv), ("wigner_rotate", wigner_rotate))
+            ("so2_conv", so2_conv), ("wigner_rotate", wigner_rotate),
+            ("segment_sum_into", segment_sum_into))
 
 
 def phase_kernels(bands: dict, seed: int = 0) -> list:

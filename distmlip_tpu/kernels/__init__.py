@@ -13,7 +13,8 @@ CONTIGUOUS slice of the edge array, computable with one on-device
 Layout:
 
 - :mod:`segment` — the fused gather+scatter segment kernels
-  (``pallas_segment_sum``, ``pallas_edge_aggregate``) and the XLA
+  (``pallas_segment_sum``, its in-place form ``pallas_segment_sum_into``
+  for the chunks of an edge scan, ``pallas_edge_aggregate``) and the XLA
   reference implementations they are tested against.
 - :mod:`so3` — the fused SO(2)/channel-mixing kernel for the MACE/eSCN
   equivariant inner loop (per-|m| complex-pair GEMMs batched into one
@@ -32,8 +33,15 @@ from .dispatch import (  # noqa: F401
     force_kernel_mode,
     fused_edge_aggregate,
     fused_segment_sum,
+    fused_segment_sum_into,
     fused_so2_conv,
     resolve_kernel_mode,
+    segment_sum_carry,
+    segment_sum_result,
 )
-from .segment import pallas_edge_aggregate, pallas_segment_sum  # noqa: F401
+from .segment import (  # noqa: F401
+    pallas_edge_aggregate,
+    pallas_segment_sum,
+    pallas_segment_sum_into,
+)
 from .so3 import so2_conv_reference  # noqa: F401

@@ -15,7 +15,8 @@ never through :mod:`segment`/:mod:`so3` directly. The dispatcher owns
   fails to lower fails the trace: nothing here catches a lowering error.
 - **autodiff**: ``pallas_call`` has no transpose rule, so each fused op
   carries a custom VJP. ``fused_segment_sum``'s backward is the sorted
-  gather ``g[segment_ids] * mask``; ``fused_edge_aggregate``'s backward
+  gather ``g[segment_ids] * mask`` (``fused_segment_sum_into``'s too, the
+  carry's cotangent passing through); ``fused_edge_aggregate``'s backward
   re-runs the per-edge compute in bounded chunks (a ``lax.scan``) so the
   backward pass ALSO never materializes the ``(E, width)`` message
   cotangent; ``fused_so2_conv``'s backward is the VJP of the XLA
@@ -30,6 +31,7 @@ never through :mod:`segment`/:mod:`so3` directly. The dispatcher owns
 
 from __future__ import annotations
 
+import math
 import os
 import threading
 from contextlib import contextmanager
@@ -42,7 +44,11 @@ import jax.numpy as jnp
 
 from ..ops.segment import masked_segment_sum
 from ..telemetry import scope
-from .segment import pallas_edge_aggregate, pallas_segment_sum
+from .segment import (
+    pallas_edge_aggregate,
+    pallas_segment_sum,
+    pallas_segment_sum_into,
+)
 from .so3 import (
     packed_m_layout,
     so2_conv_pallas,
@@ -90,6 +96,11 @@ TPU_DEFAULT_MODE = {
     # the 35 block entries as float32 columns, both directions and the
     # columns' cotangent; agreement and timings: PERF.md section 6, PR 31
     "wigner_rotate": "pallas",
+    # compiled on a TPU v5 lite at MACE's (E_c=32768, nQ=40, 128) chunk into
+    # a flat (29568, 5120) carry and UMA's (32768, 1152) rows into (9856,
+    # 1152); max rel err 4.1e-7 (float32), 2.8e-3 (bfloat16) — chip run,
+    # PR 33; timings: PERF.md section 6
+    "segment_sum_into": "pallas",
 }
 
 
@@ -273,12 +284,102 @@ def _segment_sum_vjp(num_segments: int, interpret: bool, dtype):
         return f(d, ids, m), (ids, m)
 
     def bwd(res, g):
-        ids, m = res
-        # transpose of a masked segment sum: the sorted per-edge gather
+        return _segment_sum_bwd(*res, g, dtype)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def _segment_sum_bwd(ids, m, g, dtype):
+    """Cotangents ``(rows, ids, mask)`` of a masked segment sum: its
+    transpose is the sorted per-edge gather; ids and mask get float0."""
+    with scope("edge_aggregate"):
+        gd = jnp.take(g, ids, axis=0)
+        m_ct = None if m is None else _int_zero(m)
+        return (_mask_mul(gd, m).astype(dtype), _int_zero(ids), m_ct)
+
+
+def segment_sum_carry(num_segments: int, out_shape, dtype, kernels=None):
+    """The zero carry of a scan of :func:`fused_segment_sum_into`, read
+    back with :func:`segment_sum_result`.
+
+    On the XLA path the ``(num_segments,) + out_shape`` array itself. On
+    the Pallas path a pair: the accumulator as the kernel's flat
+    ``(num_segments, W)`` rows (a ``(.., 40, 128)`` bfloat16 array pads 40
+    sublanes to 48, so handing it to the kernel as rows would copy it
+    every chunk), and a zero *shadow* in the result's own shape that the
+    forward never reads. The shadow exists for its cotangent: the result's
+    own (:func:`segment_sum_result`), which so reaches every chunk's
+    backward in the shape the rows' gather reads, where the flat cotangent
+    would be laid out anew chunk by chunk.
+    """
+    shape = (num_segments,) + tuple(out_shape)
+    if resolve_kernel_mode(kernels, op="segment_sum_into") == "xla":
+        return jnp.zeros(shape, dtype=dtype)
+    return (jnp.zeros((num_segments, math.prod(shape[1:])), dtype=dtype),
+            jnp.zeros(shape, dtype=dtype))
+
+
+def segment_sum_result(carry):
+    """The ``(num_segments,) + out_shape`` sum a carry holds."""
+    if not isinstance(carry, tuple):
+        return carry
+    acc, shadow = carry
+    return _shadowed(acc.reshape(shadow.shape), shadow)
+
+
+@jax.custom_vjp
+def _shadowed(x, shadow):
+    """``x``; the shadow gets ``x``'s cotangent too."""
+    return x
+
+
+_shadowed.defvjp(lambda x, shadow: (x, None), lambda _, g: (g, g))
+
+
+def fused_segment_sum_into(carry, data, segment_ids, mask=None, kernels=None):
+    """The carry (:func:`segment_sum_carry`) with one dst-sorted chunk of
+    an edge scan (``LocalGraph.scan_edges``) added: ``acc +
+    masked_segment_sum(data, segment_ids, num_segments, mask)``, ``mask``
+    boolean.
+
+    On the Pallas path the sum is added into the flat accumulator in
+    place, over the dst tiles between the chunk's first and last id only
+    (:func:`pallas_segment_sum_into`): no ``(num_segments, W)`` kernel
+    result, no whole-array add per chunk. The XLA path is the expression
+    above as written.
+    """
+    if not isinstance(carry, tuple):
+        _count("segment_sum_into", False)
         with scope("edge_aggregate"):
-            gd = jnp.take(g, ids, axis=0)
-            m_ct = None if m is None else _int_zero(m)
-            return (_mask_mul(gd, m).astype(dtype), _int_zero(ids), m_ct)
+            return carry + masked_segment_sum(
+                data, segment_ids, carry.shape[0], mask,
+                indices_are_sorted=True)
+    mode = resolve_kernel_mode(kernels, op="segment_sum_into")
+    _count("segment_sum_into", True)
+    # every traced operand explicit, statics in the closure: as in
+    # fused_segment_sum. Under remat the replayed forward of this call is
+    # dead (the bwd needs only ids/mask, the carry's cotangents pass
+    # through), so the carry is no residual of the scan:
+    # contract: allow(dead_compute)
+    with scope("edge_aggregate"):
+        return _segment_sum_into_vjp(mode == "interpret",
+                                     jnp.result_type(data))(
+            *carry, data, segment_ids, mask)
+
+
+def _segment_sum_into_vjp(interpret: bool, dtype):
+    @jax.custom_vjp
+    def f(acc, shadow, d, ids, m):
+        return pallas_segment_sum_into(acc, d, ids, mask=m,
+                                       interpret=interpret), shadow
+
+    def fwd(acc, shadow, d, ids, m):
+        return f(acc, shadow, d, ids, m), (ids, m)
+
+    def bwd(res, g):
+        g_acc, g_shadow = g
+        return (g_acc, g_shadow, *_segment_sum_bwd(*res, g_shadow, dtype))
 
     f.defvjp(fwd, bwd)
     return f

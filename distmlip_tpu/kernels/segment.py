@@ -15,6 +15,12 @@ caller-supplied per-edge compute, and accumulates into the tile's
 ``(TILE_N, W)`` VMEM accumulator with a one-hot MXU matmul — the classic
 TPU segment-sum idiom. The ``(E, width)`` message tensor never exists:
 messages live one ``(BLK, width)`` block at a time in VMEM.
+:func:`pallas_segment_sum` and :func:`pallas_edge_aggregate` visit every
+dst tile and write a whole ``(num_segments, W)`` result.
+:func:`pallas_segment_sum_into` adds into a carried array in place and
+visits only the tiles between the first and the last id of its edges (one
+chunk of an edge scan lands in a few tiles): grid step ``j`` owns tile
+``t0 + j`` while ``j < nt`` and does nothing after.
 
 Mosaic layout rules shape the streaming. A DMA may start at any index of
 an untiled (leading) dimension but only at tile-aligned offsets of the
@@ -222,6 +228,100 @@ def _segment_sum_kernel(offs_ref, ids_ref, data_ref, out_ref,
 
     jax.lax.fori_loop(b0, b1, body, None)
     out_ref[...] = acc_s[...].astype(out_ref.dtype)
+
+
+def pallas_segment_sum_into(acc, data, segment_ids, mask=None, *,
+                            tile_n: int | None = None,
+                            edge_blk: int | None = None,
+                            interpret: bool = False):
+    """``acc + pallas_segment_sum(data, segment_ids, len(acc), mask)``,
+    added in place over the dst tiles the edges touch.
+
+    ``acc``, ``(num_segments, W)`` rows (or ``data``'s trailing shape, at
+    the price of a relayout where that pads), is aliased to the result.
+    ``segment_ids`` is nondecreasing, masked and pad rows included, so its
+    first and last entries span the touched tiles ``[t0, t0 + nt)``: those
+    are loaded, summed in float32 in VMEM and written back once (one
+    rounding to ``acc.dtype``); every other row of ``acc`` is neither read
+    nor written. The grid still has one step a tile, so any span is right
+    (one edge a node touches them all); a step past the span keeps the
+    last tile's block index, which Pallas neither fetches nor writes again.
+    """
+    num_segments, e = acc.shape[0], data.shape[0]
+    if e == 0 or num_segments == 0:
+        return acc
+    flat = _flatten_width(data)
+    w = flat.shape[1]
+    tn, eb = _pick_tiles(e, num_segments, tile_n, edge_blk)
+    ntile = -(-num_segments // tn)
+    acc_flat = acc.reshape(num_segments, w)
+    # a ragged last tile (toy graphs; capacities are multiples of TILE_N)
+    # pays a padded copy to keep every block whole
+    ragged = ntile * tn - num_segments
+    if ragged:
+        acc_flat = jnp.pad(acc_flat, ((0, ragged), (0, 0)))
+    offs = dst_tile_offsets(segment_ids, num_segments, tn)
+    ids_b, (data_b,) = _prepare_edges(segment_ids, mask, [flat], eb)
+    first, last = (jnp.clip(segment_ids[k].astype(jnp.int32) // jnp.int32(tn),
+                            0, ntile - 1) for k in (0, -1))
+    span = jnp.stack([first, last - first + 1])
+
+    def tile(j, offs, span):
+        return span[0] + jnp.minimum(j, span[1] - 1), 0
+
+    item, acc_item = flat.dtype.itemsize, acc.dtype.itemsize
+    # data block + fp32 accumulator and dot result + double-buffered acc
+    # blocks in and out + one-hot
+    vmem = eb * w * item + tn * w * (8 + 4 * acc_item) + tn * eb * item
+    kernel = functools.partial(_segment_sum_into_kernel, tile_n=tn,
+                               edge_blk=eb)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(ntile,),
+        in_specs=[
+            pl.BlockSpec((tn, w), tile),         # acc
+            pl.BlockSpec(memory_space=pl.ANY),   # ids
+            pl.BlockSpec(memory_space=pl.ANY),   # data
+        ],
+        out_specs=pl.BlockSpec((tn, w), tile),
+        scratch_shapes=[
+            pltpu.VMEM((1, eb), jnp.int32),
+            pltpu.VMEM((eb, w), flat.dtype),
+            pltpu.VMEM((tn, w), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(acc_flat.shape, acc.dtype),
+        # operand 2 (after the two prefetched scalars) is the carried array
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem)),
+        interpret=interpret,
+    )(offs, span, acc_flat, ids_b, data_b)
+    return (out[:num_segments] if ragged else out).reshape(acc.shape)
+
+
+def _segment_sum_into_kernel(offs_ref, span_ref, acc_ref, ids_ref, data_ref,
+                             out_ref, ids_s, data_s, acc_s, sems, *,
+                             tile_n: int, edge_blk: int):
+    j = pl.program_id(0)
+
+    @pl.when(j < span_ref[1])
+    def _():
+        i = span_ref[0] + j
+        b0, b1 = _block_range(offs_ref, i, edge_blk)
+        acc_s[...] = acc_ref[...].astype(jnp.float32)
+
+        def body(b, carry):
+            _copy_blocks(b, (ids_ref, data_ref), (ids_s, data_s), sems)
+            _onehot_accumulate(acc_s, data_s[...], ids_s[...], i * tile_n,
+                               tile_n)
+            return carry
+
+        jax.lax.fori_loop(b0, b1, body, None)
+        out_ref[...] = acc_s[...].astype(out_ref.dtype)
 
 
 # ---------------------------------------------------------------------------

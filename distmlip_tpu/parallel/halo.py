@@ -29,7 +29,8 @@ from jax import lax
 
 from ..geometry import COORD_PRECISION
 from ..kernels.dispatch import (Gather, fused_edge_aggregate,
-                                fused_segment_sum)
+                                fused_segment_sum, fused_segment_sum_into,
+                                segment_sum_carry, segment_sum_result)
 from ..ops.chunk import chunk_layout, chunked, scan_accumulate, take_rows
 from ..telemetry import scope
 
@@ -300,26 +301,25 @@ class LocalGraph:
         their dst nodes and accumulated over the chunks, so per-edge
         memory is O(chunk). Each chunk's dst is sorted by the layout's
         construction, so the sum keeps the ``indices_are_sorted`` fast
-        path and dispatches to the dst-tiled Pallas scatter on TPU
-        (kernels/dispatch). ``remat`` (bool or policy name,
+        path and on TPU the dst-tiled Pallas scatter adds it into the
+        carried accumulator in place, over the chunk's own dst tiles
+        (``kernels/dispatch.fused_segment_sum_into``); elsewhere it is
+        ``acc + masked_segment_sum(...)``. ``remat`` (bool or policy name,
         ``ops/chunk.remat_wrap``) checkpoints the chunk body.
         """
         def body(acc, xs):
             srcc, dstc, maskc, *rows = xs
             msg = per_chunk(srcc, dstc, maskc, *rows)
-            with scope("edge_aggregate"):
-                return (
-                    acc + fused_segment_sum(
-                        msg, dstc, self.n_cap, maskc,
-                        indices_are_sorted=True, kernels=self.kernels),
-                    None,
-                )
+            return fused_segment_sum_into(
+                acc, msg, dstc, maskc, kernels=self.kernels), None
 
         # the scan's own slicing of the chunked rows (and, transposed, the
         # stacking of their cotangents) continues the layout's data path
         with scope("edge_gather"):
-            acc0 = jnp.zeros((self.n_cap,) + tuple(out_shape), dtype=dtype)
-            return scan_accumulate(body, acc0, edge_xs, remat=remat)
+            acc0 = segment_sum_carry(self.n_cap, out_shape, dtype,
+                                     self.kernels)
+            return segment_sum_result(
+                scan_accumulate(body, acc0, edge_xs, remat=remat))
 
     def overlapped_edge_sum(self, msg_fn, v_pre, v_post, edge_data=(),
                             mask=None):

@@ -94,7 +94,8 @@ def test_md_phases_at_toy_width():
     assert md1["executables_after_step_1"] == 0
     assert md1["rebuilds_after_step_1"] == 0
     assert len(md1["step_ms"]) == 3
-    assert md1["kernel_ops"]["segment_sum"][0] > 0   # Pallas call sites
+    # Pallas call sites: MACE's two edge scans, none left on XLA
+    assert md1["kernel_ops"]["segment_sum_into"] == [2, 0]
     md4 = chip_smoke.phase_md4(model, params, atoms, md1["result"], watch,
                                bands=TOY_BANDS, kernels="interpret")
     assert len(md4["devices"]) == 4
@@ -136,6 +137,13 @@ def test_kernels_phase_has_a_row_for_every_entry_of_the_default_table():
     got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
     # five pieces of two operands, nine lab rows, the columns' cotangent
     assert got.shape == want.shape == (32768, (2 * 9 + 9) * 128 + 35)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    # MACE's chunk into mace-md-1c's flat carry, UMA-S's into uma-md-1c's
+    kernel, xla, args = cases["segment_sum_into"](jnp.bfloat16)
+    assert [a.shape for a in args] == [(29568, 5120), (9856, 1152),
+                                       (32768, 40, 128), (32768, 1152)]
+    got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
+    assert got.shape == want.shape == (29568 * 5120 + 9856 * 1152,)
     assert got.dtype == want.dtype == jnp.bfloat16
 
 

@@ -4,6 +4,7 @@ from .chgnet import CHGNet, CHGNetConfig
 from .mace import MACE, MACEConfig
 from .escn import ESCN, ESCNConfig
 from .escn_md import ESCNMD, ESCNMDConfig
+from .nequip import NequIP, NequIPConfig
 
 __all__ = [
     "PairPotential", "PairConfig",
@@ -12,4 +13,5 @@ __all__ = [
     "MACE", "MACEConfig",
     "ESCN", "ESCNConfig",
     "ESCNMD", "ESCNMDConfig",
+    "NequIP", "NequIPConfig",
 ]

@@ -6,6 +6,7 @@ The bases used across the model zoo:
   - FourierExpansion      (CHGNet angle features)
   - polynomial_cutoff     (MACE/CHGNet smooth envelope)
   - cosine_cutoff         (Behler-style envelope)
+  - xplor_cutoff          (NequIP/SevenNet switching envelope)
 
 All functions are smooth at the cutoff so forces stay continuous.
 """
@@ -99,3 +100,14 @@ def polynomial_cutoff(d, cutoff: float, p: int = 6):
 def cosine_cutoff(d, cutoff: float):
     """0.5 (cos(pi d / rc) + 1), zero beyond the cutoff."""
     return jnp.where(d < cutoff, 0.5 * (jnp.cos(jnp.pi * d / cutoff) + 1.0), 0.0)
+
+
+def xplor_cutoff(d, cutoff: float, cutoff_on: float):
+    """XPLOR switching envelope: 1 below ``cutoff_on``, then
+    (rc^2 - d^2)^2 (rc^2 + 2 d^2 - 3 r_on^2) / (rc^2 - r_on^2)^3 down to 0
+    at ``cutoff`` with zero slope at both ends, 0 beyond."""
+    rc2, on2 = cutoff * cutoff, cutoff_on * cutoff_on
+    d2 = d * d
+    switch = ((rc2 - d2) ** 2 * (rc2 + 2.0 * d2 - 3.0 * on2)
+              / (rc2 - on2) ** 3)
+    return jnp.where(d < cutoff_on, 1.0, jnp.where(d < cutoff, switch, 0.0))

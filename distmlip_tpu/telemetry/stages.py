@@ -26,6 +26,7 @@ STAGES = (
     "expert_mix",       # per-system gate, softmax, expert weights merged
     "node_linear",      # channel-mixing linears on nodes
     "node_tensor",      # symmetric contraction / rank-2 node products
+    "node_gate",        # equivariant gate: scalars activated, l > 0 scaled
     "readout",          # per-atom energies, scale and shift
     "pair_repulsion",   # ZBL
     "halo",             # exchange between partitions (parallel/halo.py)

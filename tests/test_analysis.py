@@ -478,7 +478,7 @@ def test_clean_run_fast_models(model_name):
         assert exit_code(findings) == 0
 
 
-@pytest.mark.parametrize("model_name", ["mace", "escn"])
+@pytest.mark.parametrize("model_name", ["mace", "escn", "nequip"])
 def test_clean_run_equivariant_models(model_name):
     for prog in _clean_model_programs(model_name):
         findings = run_passes(prog)
@@ -488,7 +488,7 @@ def test_clean_run_equivariant_models(model_name):
 
 @pytest.mark.slow
 def test_contract_check_cli_full_clean():
-    """The full CLI — four models x three placements + DeviceMD + packed
+    """The full CLI — five models x three placements + DeviceMD + packed
     batch, every registered pass — exits 0 on the clean tree."""
     import tools.contract_check as cc
 

@@ -18,9 +18,9 @@ from distmlip_tpu.telemetry.stages import stage_of
 
 BOND_GRAPH = ("line_geometry", "line_message", "angle_update", "bond_map")
 # what CHGNet has no code for: a chunked scan, Wigner blocks, experts,
-# rank-2 node products, ZBL
+# rank-2 node products, an equivariant gate, ZBL
 NOT_CHGNET = {"edge_gather", "edge_rotation", "expert_mix", "node_tensor",
-              "pair_repulsion"}
+              "node_gate", "pair_repulsion"}
 
 
 def config(**kw):

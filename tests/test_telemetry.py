@@ -407,11 +407,13 @@ TOY = {
 # what a family has no code for (the table of telemetry/stages.py)
 # edge_rotation and expert_mix are eSCN-MD's (tests/test_escn_md_stages.py)
 # the bond graph's four are CHGNet's (tests/test_chgnet_stages.py)
+# node_gate is NequIP's (tests/test_nequip_stages.py)
 ESCN_ONLY = {"edge_rotation", "expert_mix"}
 BOND_GRAPH = {"line_geometry", "line_message", "angle_update", "bond_map"}
-NOT_IN = {"mace": ESCN_ONLY | BOND_GRAPH,
-          "tensornet": ESCN_ONLY | BOND_GRAPH | {"edge_gather",
-                                                 "pair_repulsion"}}
+NEQUIP_ONLY = {"node_gate"}
+NOT_IN = {"mace": ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY,
+          "tensornet": ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY | {
+              "edge_gather", "pair_repulsion"}}
 
 
 def toy_potential(family, rng, nparts=1, **kw):
@@ -544,7 +546,8 @@ def test_stage_table_of_a_compiled_step_outlives_the_potential(
     by_stage = {s: [r for r in rows if r["stage"] == s] for s in STAGES}
     # one partition: the halo has no work
     assert all(by_stage[s] for s in STAGES
-               if s != "halo" and s not in ESCN_ONLY | BOND_GRAPH)
+               if s != "halo"
+               and s not in ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY)
     passes = {r["pass"] for r in rows}
     assert passes == {"forward", "backward", "recompute"}
     assert any(r["pass"] != "forward" for r in by_stage["edge_aggregate"])

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Static program-contract checker: trace, run passes, gate CI.
 
-    python tools/contract_check.py [--models chgnet,tensornet,mace,escn]
+    python tools/contract_check.py [--models chgnet,tensornet,mace,escn,nequip]
         [--programs SUBSTR] [--passes p1,p2] [--kernels {auto,on,off}]
         [--hbm-budget-gb G] [--lint] [--only-lint] [--list-passes]
         [--json] [--verbose]
@@ -62,7 +62,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " " + _flag).strip()
 
-ALL_MODELS = ("chgnet", "tensornet", "mace", "escn")
+ALL_MODELS = ("chgnet", "tensornet", "mace", "escn", "nequip")
 
 
 def build_system(reps, seed=0, a=3.5, n_species=2):
@@ -80,7 +80,7 @@ def build_system(reps, seed=0, a=3.5, n_species=2):
 
 
 def make_model(name):
-    """Small-config instance of one of the four real models (plus the LJ
+    """Small-config instance of one of the five real models (plus the LJ
     pair toy used by the DeviceMD program)."""
     import jax
 
@@ -111,6 +111,14 @@ def make_model(name):
         model = ESCN(ESCNConfig(
             num_species=4, channels=16, l_max=2, num_layers=2, num_bessel=6,
             num_experts=4, cutoff=3.2, avg_num_neighbors=12.0))
+        use_bg, bond_r = False, 0.0
+    elif name == "nequip":
+        from distmlip_tpu.models import NequIP, NequIPConfig
+
+        model = NequIP(NequIPConfig(
+            num_species=4, irreps=((16, 8, 4),) * 2 + ((16,),), num_bessel=6,
+            radial_hidden=(16, 16), cutoff=3.2, cutoff_on=2.8,
+            avg_num_neighbors=12.0))
         use_bg, bond_r = False, 0.0
     elif name == "pair":
         from distmlip_tpu.models.pair import PairConfig, PairPotential
@@ -513,7 +521,7 @@ def main(argv=None) -> int:
         prog="contract_check", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--models", default=",".join(ALL_MODELS),
-                    help="comma list from {chgnet,tensornet,mace,escn}")
+                    help="comma list from {chgnet,tensornet,mace,escn,nequip}")
     ap.add_argument("--programs", default=None,
                     help="only check programs whose name contains SUBSTR")
     ap.add_argument("--passes", default=None,

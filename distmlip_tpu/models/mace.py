@@ -85,10 +85,10 @@ class MACEConfig:
                               # learned potential (ref mace/models.py:121-128)
     atomic_numbers: tuple | None = None  # species index -> Z (for ZBL);
                                          # default: index + 1
-    remat: bool | str = True  # rematerialize in the backward pass: True
-                              # (full), False, or a checkpoint-policy name
-                              # ("dots": keep GEMM outputs, recompute glue
-                              # — ops/chunk.remat_wrap)
+    remat: bool | str = True  # rematerialize the scans' chunk bodies (and
+                              # nothing else) in the backward pass: True
+                              # (full), False, or a policy name ("dots":
+                              # keep GEMM outputs — ops/chunk.remat_wrap)
     edge_chunk: int = 32768  # process edges in chunks of this size inside a
                              # lax.scan: bounds the per-edge path-tensor and
                              # radial-weight memory regardless of system size
@@ -380,11 +380,6 @@ class MACE:
         for t, inter in enumerate(params["interactions"]):
             body = partial(self._interaction, lg=lg, edge_xs=edge_xs,
                            z=z, t=t)
-            if cfg.remat is True:
-                # full-remat mode only: with a policy, the inner edge/node
-                # scans carry the policy themselves and double-wrapping
-                # would discard their saved dots
-                body = jax.checkpoint(body)
             with scope(f"interaction{t}"):
                 h = body(inter, h)
             with scope("halo"):
@@ -431,10 +426,10 @@ class MACE:
 
     def _interaction(self, inter, h, *, lg, edge_xs, z, t):
         """One MACE interaction: density projection + symmetric contraction +
-        linear update. Rematerialized under grad when cfg.remat (the per-edge
-        per-path tensors dominate activation memory). ``edge_xs`` is
-        energy_fn's chunk-ordered ``(src, dst, mask, Y, bessel)``, each
-        ``(K, chunk, ...)``."""
+        linear update. ``cfg.remat`` checkpoints the chunk bodies of its edge
+        and node scans (the per-edge per-path tensors live there), not the
+        interaction. ``edge_xs`` is energy_fn's chunk-ordered
+        ``(src, dst, mask, Y, bessel)``, each ``(K, chunk, ...)``."""
         cfg = self.cfg
         C = cfg.channels
         chunk = edge_xs[0].shape[1]

@@ -76,7 +76,7 @@ class NequIPConfig:
     cutoff: float = 5.0
     cutoff_on: float = 4.5    # the XPLOR envelope is 1 below this
     avg_num_neighbors: float = 42.0
-    remat: bool | str = True  # as MACEConfig.remat
+    remat: bool | str = True  # as MACEConfig.remat: the scan's chunk body
     edge_chunk: int = 32768   # as MACEConfig.edge_chunk
     dtype: str = "float32"
 
@@ -257,13 +257,8 @@ class NequIP:
         last = len(params["layers"]) - 1
         for t, layer in enumerate(params["layers"]):
             body = partial(self._convolution, lg=lg, edge_xs=edge_xs, t=t)
-            if cfg.remat is True:
-                # full-remat mode only, as MACE: with a policy the edge
-                # scan carries the policy itself
-                body = jax.checkpoint(body)
-            # as MACE's interaction{t}, no stage: the checkpoint's own
-            # equations (the call, the sums of the chunk rows' cotangents
-            # over the convolutions) read as unattributed
+            # as MACE's interaction{t}, no stage: every equation inside
+            # sits under a stage of its own
             with scope(f"convolution{t}"):
                 h = body(layer, h)
             if t < last:
@@ -280,9 +275,9 @@ class NequIP:
 
     def _convolution(self, layer, h, *, lg, edge_xs, t):
         """One gated convolution on the flat node rows ``h``; returns the
-        next layer's. Rematerialized under grad when ``cfg.remat``.
-        ``edge_xs`` is energy_fn's chunk-ordered ``(src, dst, mask, Y,
-        bessel)``."""
+        next layer's. ``cfg.remat`` checkpoints the chunk body of its edge
+        scan, not the convolution. ``edge_xs`` is energy_fn's chunk-ordered
+        ``(src, dst, mask, Y, bessel)``."""
         cfg, tb = self.cfg, self.tables[t]
         dtype = h.dtype
         mul_in, mul_out = self.mul_in[t], self.mul_out[t]

@@ -96,9 +96,10 @@ def remat_wrap(body, remat):
     runs again), or the name of a jax checkpoint policy — most usefully
     ``"dots"`` (``dots_with_no_batch_dims_saveable``: keep GEMM outputs
     resident, recompute only the cheap elementwise/gather glue, for a
-    bounded activation-memory increase). The benchmark's MACE cells run
-    ``remat=True``; what the recompute costs there is the ``recompute``
-    pass of the stage tables (PERF.md section 5, ROADMAP S5).
+    bounded activation-memory increase). Scan bodies are all the models
+    checkpoint, so a chunk's forward runs twice a step; the benchmark's
+    scanning cells run ``remat=True`` and the second run is the
+    ``recompute`` pass of the stage tables (PERF.md section 5, ROADMAP S5).
     """
     if remat is False:
         return body

@@ -188,20 +188,34 @@ def test_multihead_readout(rng):
     assert abs(e0 - e0b) < 1e-6
 
 
-def test_edge_node_chunking_matches_unchunked(rng, params):
+CHUNKED = dict(edge_chunk=96, node_chunk=17)
+
+
+@pytest.mark.parametrize("nparts, reps, other", [
+    (1, (3, 3, 3), dict(edge_chunk=0, node_chunk=0)),
+    (1, (3, 3, 3), dict(CHUNKED, remat=False)),
+    (4, (7, 4, 4), dict(CHUNKED, remat=False)),
+], ids=["unchunked", "no-remat", "no-remat-4-parts"])
+def test_edge_node_chunking_matches_unchunked(rng, params, nparts, reps,
+                                              other):
     """K>1 edge-chunked density projection AND node-chunked symmetric
     contraction (remat scan paths) must reproduce the unchunked forward
     exactly — guards the per-chunk padding, the T-factorized projection,
-    and the scan accumulation."""
+    and the scan accumulation. Against the same chunks at ``remat=False``,
+    on one part and four: the chunk bodies are the only checkpoints
+    (PR 35), and energy, forces and stress still come through the scans,
+    the halo exchange and the node scan to float32 round-off."""
     import dataclasses
 
-    cart, lattice, species = make_crystal(rng, reps=(3, 3, 3))
-    m_un = MACE(dataclasses.replace(CFG, edge_chunk=0, node_chunk=0))
-    m_ch = MACE(dataclasses.replace(CFG, edge_chunk=96, node_chunk=17))
+    cart, lattice, species = make_crystal(rng, reps=reps)
+    m_un = MACE(dataclasses.replace(CFG, **other))
+    m_ch = MACE(dataclasses.replace(CFG, **CHUNKED))
+    assert m_ch.cfg.remat is True
     e0, f0, s0 = run_potential(m_un.energy_fn, params, cart, lattice, species,
-                               CFG.cutoff, 1)
+                               CFG.cutoff, nparts)
     e1, f1, s1 = run_potential(m_ch.energy_fn, params, cart, lattice, species,
-                               CFG.cutoff, 1)
+                               CFG.cutoff, nparts)
+    assert np.abs(f0).max() > 1e-3
     assert abs(e0 - e1) < 1e-5 * max(1.0, abs(e0))
     np.testing.assert_allclose(f0, f1, atol=1e-5)
     np.testing.assert_allclose(s0, s1, atol=1e-7)

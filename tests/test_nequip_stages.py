@@ -1,13 +1,16 @@
 """NequIP's energy-and-forces step at jaxpr level, the twin of
 ``tests/test_chgnet_stages.py``: every equation of the model carries a
 stage (what the benchmark's ``model.unattributed_share.md`` reads on the
-chip) but each convolution's checkpoint's own, as in MACE; the gate reads
-under its own stage ``node_gate``, ``radial_mlp`` holds
-the MLP alone, the partitions exchange the flat rows four times, and no other
-model's step knows the new stage.
+chip); the gate reads under its own stage ``node_gate``, ``radial_mlp``
+holds the MLP alone, the partitions exchange the flat rows four times, and
+no other model's step knows the new stage. Since PR 35 a scan's chunk body
+is the one thing NequIP and MACE checkpoint: no ``remat2`` lies inside
+another, and forces agree with the same model at ``remat=False``.
 """
 
+import dataclasses
 import re
+from collections import Counter
 
 import jax
 import numpy as np
@@ -72,15 +75,12 @@ def test_every_equation_of_the_model_carries_a_stage(nparts, kernels, dtype):
     assert len(model) > 200
     bare = sorted({(s.primitive, s.stack) for s in model
                    if stage_of(s.stack) is None})
-    # ``convolution{t}`` is no stage, as MACE's ``interaction{t}``: the
-    # backward's checkpoint call and the sums of the chunk rows' cotangents
-    # (harmonics, Bessel rows) over the five convolutions sit directly
-    # under it, and nothing else does
-    assert {p for p, _ in bare} == {"remat2", "add_any"}, bare[:10]
-    assert all(re.search(r"/convolution\d\)*$", stack)
-               for _, stack in bare), bare[:10]
-    assert len([s for s in model if s.primitive == "remat2"
-                and stage_of(s.stack) is None]) == 5
+    # ``convolution{t}`` is no stage, as MACE's ``interaction{t}``, and no
+    # equation sits directly under it: the convolution is not checkpointed
+    # (PR 35), so no checkpoint call of the backward is left there
+    assert bare == []
+    assert not [s.stack for s in model if s.primitive == "remat2"
+                and re.search(r"/convolution\d\)*$", s.stack)]
     seen = {stage_of(s.stack) for s in model} - {None}
     assert seen == NEQUIP - ({"halo"} if nparts == 1 else set())
     if kernels == "interpret":
@@ -134,6 +134,61 @@ OTHERS = {
         hidden_channels=8, edge_channels=8, num_distance_basis=8, cutoff=3.5,
         avg_degree=12.0, edge_chunk=256, num_experts=2)),
 }
+
+
+def scanning(family, **kw):
+    """A toy model whose layers scan chunks, its layer scope, its layers and
+    the stages of the scans in each (MACE: the edge scan and, with
+    ``node_chunk`` below the node count, the node scan)."""
+    if family == "mace":
+        cfg = dataclasses.replace(OTHERS["mace"]().cfg, node_chunk=16, **kw)
+        return MACE(cfg), "interaction", 2, ["edge_gather", "node_tensor"]
+    return NequIP(config(**kw)), "convolution", 5, ["edge_gather"]
+
+
+@pytest.mark.parametrize("nparts", [1, 4])
+@pytest.mark.parametrize("family", ["mace", "nequip"])
+def test_a_chunk_body_is_the_only_checkpoint(family, nparts):
+    """One checkpoint level (PR 35): under ``remat=True`` no ``remat2``
+    equation of the step lies inside another's body, and each edge scan and
+    MACE's node scan has exactly one, the chunk body in its backward scan;
+    so a chunk's forward runs twice a step, not three times."""
+    model, layer, n_layers, scan_stages = scanning(family, remat=True)
+    sites = step_sites(model, nparts)
+    scans = [s for s in sites if s.primitive == "scan"]
+    remats = [s for s in sites if s.primitive == "remat2"]
+    assert not [s.stack for s in remats if "remat2" in s.path]
+    # forward and backward of every scan; the checkpoint calls are the
+    # backward scans' bodies, one each
+    assert len(scans) == 2 * n_layers * len(scan_stages)
+    body = lambda jaxpr: id(getattr(jaxpr, "jaxpr", jaxpr))
+    backward = [body(s.eqn.params["jaxpr"]) for s in scans
+                if "transpose(" in s.stack]
+    assert sorted(body(s.jaxpr) for s in remats) == sorted(backward)
+    where = Counter((re.search(layer + r"\d", s.stack).group(),
+                     stage_of(s.stack)) for s in remats)
+    assert where == {(f"{layer}{t}", stage): 1
+                     for t in range(n_layers) for stage in scan_stages}
+
+
+@pytest.mark.parametrize("nparts", [1, 4])
+def test_forces_agree_with_the_model_that_keeps_everything(nparts):
+    """The single checkpoint level still differentiates through the scans
+    and the halo exchange: chunked (K > 1) float32 NequIP at ``remat=True``
+    against the same model at ``remat=False``, to round-off."""
+    atoms, out = atoms_of(nparts), {}
+    for remat in (True, False):
+        model = NequIP(config(remat=remat))
+        pot = DistPotential(model, model.init(jax.random.PRNGKey(0)),
+                            num_partitions=nparts, skin=0.3)
+        out[remat] = pot.calculate(atoms)
+        assert min(pot.last_stats["n_edges_per_part"]) > 2 * 256
+    scale = np.abs(out[False]["forces"]).max()
+    assert scale > 1e-3
+    assert abs(out[True]["energy"] - out[False]["energy"]) < \
+        1e-6 * abs(out[False]["energy"])
+    np.testing.assert_allclose(out[True]["forces"], out[False]["forces"],
+                               atol=2e-6 * scale)
 
 
 @pytest.mark.parametrize("name", sorted(OTHERS))

@@ -602,6 +602,10 @@ def main() -> int:
     reference = md1.pop("result")
     phases["MD-1"] = md1
     log(f"MD-1 {md1}")
+    # where MD-1's set-up went, as the benchmark's set-up metrics split it
+    from distmlip_tpu.telemetry.trace import jax_cache_counts, phase_totals
+
+    log(f"phases {json.dumps({**phase_totals(), **jax_cache_counts()})}")
 
     if identity["count"] >= 4:
         phases["MD-4"] = phase_md4(model, params, atoms, reference, watch,

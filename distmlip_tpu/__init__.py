@@ -15,7 +15,11 @@ dtype additionally supports bfloat16 for the matmul-heavy paths.
 
 from __future__ import annotations
 
-import numpy as np
+import time
+
+_T_IMPORT = time.perf_counter()  # the phase `distmlip/import` starts here
+
+import numpy as np  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -65,3 +69,5 @@ def compute_dtype():
 
 from . import geometry  # noqa: E402,F401
 from . import telemetry  # noqa: E402,F401
+
+telemetry.log_phase("distmlip/import", _T_IMPORT, time.perf_counter())

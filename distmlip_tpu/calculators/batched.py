@@ -32,7 +32,8 @@ from ..obs import profiling as _profiling
 from ..obs import runtime as obsrt
 from ..parallel import make_batched_potential_fn
 from ..partition import BucketPolicy, pack_structures
-from ..telemetry import StepRecord, annotate, note_dispatch
+from ..telemetry import StepRecord, annotate, note_dispatch, phase
+from ..telemetry.trace import compile_in, listen_to_jax, log_first_call
 from ..telemetry.trace import tracing_enabled
 from .atoms import (AMU_A2_FS2_TO_EV, EV_A3_TO_GPA, KB, map_species,
                     max_displacement)
@@ -131,11 +132,13 @@ class BatchedPotential:
         # Pallas fused-kernel routing (kernels/dispatch): None = backend
         # default, False = pure XLA, "interpret" = interpreter-mode kernels
         self.kernels = kernels
-        self._potential = make_batched_potential_fn(
-            model.energy_and_aux_fn if self.compute_magmom
-            else model.energy_fn,
-            compute_stress=self.compute_stress, aux=self.compute_magmom,
-            mesh=self.mesh, kernels=kernels)
+        listen_to_jax()
+        with phase("distmlip/runtime_build"):
+            self._potential = make_batched_potential_fn(
+                model.energy_and_aux_fn if self.compute_magmom
+                else model.energy_fn,
+                compute_stress=self.compute_stress, aux=self.compute_magmom,
+                mesh=self.mesh, kernels=kernels)
         # last OBSERVED kernel-dispatch tally: jit traces once per shape
         # bucket, so the counter fills on compile steps and stays empty on
         # cache hits — the last nonzero tally describes the executable
@@ -460,8 +463,10 @@ class BatchedPotential:
                 # (host-side abstract trace; once per bucket)
                 if self.memory_model:
                     self._calibrate_memory(graph, positions, structures)
+            t_dispatched = time.perf_counter()
             with annotate("distmlip/wait"):
                 out["energies"].block_until_ready()
+            t_waited = time.perf_counter()
             with annotate("distmlip/results_to_host"):
                 # flat shard-major slots -> input structure order (identity
                 # for the single-shard pack)
@@ -523,16 +528,24 @@ class BatchedPotential:
         # models without fused-dispatch sites count zero on fresh traces)
         self._last_compile_s = 0.0
         self._last_compile_kind = ""
+        compiled = self.compile_count > cc0
+        if compiled:
+            # a new bucket's call is a phase in four parts (a pack alone
+            # is not: in serving every batch packs)
+            log_first_call(t0, t2, t_dispatched, t_waited, t3)
         if getattr(self._potential, "_records_compiles", False):
             self._last_compile_s = float(getattr(
                 self._potential, "last_dispatch_compile_s", 0.0))
             self._last_compile_kind = str(getattr(
                 self._potential, "last_dispatch_kind", ""))
-        elif self.compile_count > cc0:
-            self._last_compile_s = t3 - t2
-            self._last_compile_kind = _profiling.KIND_FRESH
+        elif compiled:
+            # what jax spent on the executable is inside the dispatch;
+            # the first run is not
+            self._last_compile_s, from_cache = compile_in(t2, t_dispatched)
+            self._last_compile_kind = (_profiling.KIND_CACHE if from_cache
+                                       else _profiling.KIND_FRESH)
             _profiling.record_compile(
-                site="batched_bucket", kind=_profiling.KIND_FRESH,
+                site="batched_bucket", kind=self._last_compile_kind,
                 wall_s=self._last_compile_s,
                 bucket_key=self.last_bucket_key)
         # bucket-cached peak estimate (cache hits reuse the compile-time

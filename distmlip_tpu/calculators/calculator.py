@@ -41,7 +41,8 @@ import numpy as np
 from ..neighbors import neighbor_list
 from ..parallel import graph_mesh, make_potential_fn
 from ..partition import CapacityPolicy, build_partitioned_graph, build_plan
-from ..telemetry import StepRecord, annotate, note_dispatch
+from ..telemetry import StepRecord, annotate, note_dispatch, phase
+from ..telemetry.trace import compile_in, listen_to_jax, log_first_call
 from .atoms import EV_A3_TO_GPA, Atoms, map_species, max_displacement
 
 
@@ -236,11 +237,13 @@ class DistPotential:
         self.last_build_fresh = False  # _prepare built at current positions
         # telemetry hub (distmlip_tpu.telemetry.Telemetry) or None; when
         # unset (the default) no per-step record is ever constructed — the
-        # only residual instrumentation is `annotate()`, which returns a
-        # shared null context unless tracing is explicitly enabled
+        # residual instrumentation is `annotate()`, which returns a shared
+        # null context unless tracing is explicitly enabled, and the two
+        # clock reads and one test that find a call worth a phase
         self.telemetry = telemetry
         self._step_counter = 0
         self._prepare_flags = {}  # cache-hit/rebuild/adoption of last _prepare
+        self._last_cache_sizes: dict[str, int] = {}  # see _cache_grew
 
     def attach_telemetry(self, telemetry) -> None:
         """Attach a telemetry hub unless one is already installed (the
@@ -251,26 +254,18 @@ class DistPotential:
             self.telemetry = telemetry
 
     def _init_runtime(self):
-        t0 = time.perf_counter()
-        self.mesh = (
-            graph_mesh(self.num_partitions, self._devices)
-            if self.num_partitions > 1 else None
-        )
-        self._potential = make_potential_fn(
-            self.model.energy_and_aux_fn if self.compute_magmom
-            else self.model.energy_fn,
-            self.mesh, compute_stress=self.compute_stress,
-            aux=self.compute_magmom, kernels=self.kernels,
-        )
-        # compile telemetry: a runtime (re)build means the next dispatch
-        # re-traces — record the build itself so rebuild storms show up
-        # in the compile log even before the first dispatch
-        from ..obs import profiling as _profiling
-
-        _profiling.record_compile(
-            site="dist_build", kind=_profiling.KIND_FRESH,
-            wall_s=time.perf_counter() - t0,
-            bucket_key=f"P={self.num_partitions}")
+        listen_to_jax()
+        with phase("distmlip/runtime_build"):
+            self.mesh = (
+                graph_mesh(self.num_partitions, self._devices)
+                if self.num_partitions > 1 else None
+            )
+            self._potential = make_potential_fn(
+                self.model.energy_and_aux_fn if self.compute_magmom
+                else self.model.energy_fn,
+                self.mesh, compute_stress=self.compute_stress,
+                aux=self.compute_magmom, kernels=self.kernels,
+            )
 
     def _auto_partition_count(self, atoms: Atoms) -> int:
         """All devices, clamped so the planner's slab width stays above 2x
@@ -368,12 +363,12 @@ class DistPotential:
         self.ensure_runtime(atoms)
         r_build = self.cutoff + self.skin
         b_build = (self.bond_cutoff + self.skin) if self.use_bond_graph else 0.0
-        with annotate("distmlip/neighbor_build"):
+        with phase("distmlip/neighbor_build"):
             nl = neighbor_list(
                 atoms.positions, atoms.cell, atoms.pbc, r_build,
                 bond_r=b_build, num_threads=self.num_threads,
             )
-        with annotate("distmlip/partition"):
+        with phase("distmlip/partition"):
             plan = build_plan(
                 nl, atoms.cell, atoms.pbc, self.num_partitions, r_build,
                 b_build, self.use_bond_graph, grid=self.partition_grid,
@@ -382,7 +377,7 @@ class DistPotential:
                 plan, nl, self._species(atoms.numbers), atoms.cell,
                 caps=self.caps, system=self._system(atoms),
             )
-        with annotate("distmlip/graph_upload"):
+        with phase("distmlip/graph_upload"):
             graph = jax.device_put(graph, self._graph_shardings(graph))
         if self._device_refresh_eligible():
             # spec for the on-device refresh of THIS graph's capacity
@@ -605,7 +600,7 @@ class DistPotential:
         from ..partition.graph import device_refresh_graph
 
         static, arrays = self._nbr_spec
-        with annotate("distmlip/device_rebuild"):
+        with phase("distmlip/device_rebuild"):
             graph2, n_edges, overflow = device_refresh_graph(
                 static, arrays, graph, positions)
             overflow = bool(overflow)  # one scalar sync gates correctness
@@ -726,8 +721,10 @@ class DistPotential:
                 self._kernel_mode = kc.mode
                 self._kernel_coverage = kc.coverage
                 self._kernel_ops = kc.ops
+            t_dispatched = time.perf_counter()
             with annotate("distmlip/wait"):
                 out["energy"].block_until_ready()
+            t_waited = time.perf_counter()
             with annotate("distmlip/results_to_host"):
                 energy = float(out["energy"])
                 forces = host.gather_owned(np.asarray(out["forces"]),
@@ -745,7 +742,13 @@ class DistPotential:
                     # as an aux output — no second forward pass
                     m = np.asarray(out["aux"]["magmoms"])
                     result["magmoms"] = host.gather_owned(m, len(atoms))
-        self.last_timings["device_s"] = time.perf_counter() - t2
+        t_done = time.perf_counter()
+        self.last_timings["device_s"] = t_done - t2
+        # a call that built an executable or a graph is a phase in four
+        # parts; a steady step logs nothing
+        cache = self._cache_grew("calculate")
+        if cache[1] or self._prepare_flags.get("rebuild"):
+            log_first_call(t_start, t2, t_dispatched, t_waited, t_done)
         self.last_stats = dict(getattr(host, "stats", None) or {})
         self.last_stats.update(
             rebuild_count=int(self._prepare_flags.get("rebuild", False)),
@@ -757,43 +760,51 @@ class DistPotential:
             kernel_ops=self._kernel_ops,
         )
         self._emit_record("calculate", host,
-                          total_s=time.perf_counter() - t_start)
+                          total_s=time.perf_counter() - t_start, cache=cache)
         return result
+
+    def _cache_grew(self, kind: str, size_fn=None) -> tuple[int, bool]:
+        """``(executables in the jitted program's cache, whether that grew
+        since this was last asked for ``kind``)``: the call just made
+        traced and compiled, or loaded from the persistent cache."""
+        size_fn = size_fn or getattr(self._potential, "_cache_size", None)
+        if size_fn is None:
+            return 0, False
+        size = int(size_fn())
+        grew = size > self._last_cache_sizes.get(kind, 0)
+        self._last_cache_sizes[kind] = size
+        return size, grew
 
     def _emit_record(self, kind: str, host, total_s: float,
                      extra_timings: dict | None = None,
-                     cache_size_fn=None, **extra) -> None:
+                     cache_size_fn=None, cache=None, **extra) -> None:
         """Build and emit a StepRecord; a no-op (no record constructed)
         unless a telemetry hub with sinks is attached. ``cache_size_fn``
         lets a caller that dispatches its own jitted program (DeviceMD's
         chunk stepper) attribute compiles to THAT program instead of the
-        potential; deltas are tracked per kind so the two never conflate."""
+        potential; deltas are tracked per kind so the two never conflate.
+        ``cache`` is what :meth:`_cache_grew` said, where the caller has
+        asked already."""
         self._step_counter += 1
         tel = self.telemetry
         if tel is None or not tel.wants_records():
             return
-        cache_size = 0
-        compiled = False
-        size_fn = cache_size_fn or getattr(self._potential, "_cache_size", None)
-        if size_fn is not None:
-            cache_size = int(size_fn())
-            last = getattr(self, "_last_cache_sizes", None)
-            if last is None:
-                last = self._last_cache_sizes = {}
-            compiled = cache_size > last.get(kind, 0)
-            last[kind] = cache_size
+        t_now = time.perf_counter()
+        cache_size, compiled = cache or self._cache_grew(kind, cache_size_fn)
         timings = {**self.last_timings, "total_s": total_s,
                    **(extra_timings or {})}
-        # compile telemetry: the dispatch that grew this kind's executable
-        # cache carried the trace+lower+compile inside its device_s —
-        # stamp the record and feed the process compile log (obs plane)
+        # compile telemetry: the call that grew this kind's executable
+        # cache held jax's trace, lowering and compile (or load from the
+        # persistent cache), which the `jax/*` phases timed: stamp the
+        # record and feed the process compile log (obs plane)
         compile_s = 0.0
         compile_kind = ""
         if compiled:
             from ..obs import profiling as _profiling
 
-            compile_kind = _profiling.KIND_FRESH
-            compile_s = float(timings.get("device_s", 0.0))
+            compile_s, from_cache = compile_in(t_now - total_s, t_now)
+            compile_kind = (_profiling.KIND_CACHE if from_cache
+                            else _profiling.KIND_FRESH)
             _profiling.record_compile(
                 site="dist_potential", kind=compile_kind,
                 wall_s=compile_s, bucket_key=kind)

@@ -1,12 +1,15 @@
 """Compile telemetry: one event per compile, fresh vs AOT-rehydrated.
 
 The fourth observability plane's front door. Every compile point in the
-stack — ``BatchedPotential`` bucket compiles, ``DistPotential`` runtime
-builds, AOT rehydrates in ``fleet/aot.py``, train-step compiles in
+stack — ``BatchedPotential`` bucket compiles, ``DistPotential`` first
+calls, AOT rehydrates in ``fleet/aot.py``, train-step compiles in
 ``train/loop.py`` — calls :func:`record_compile` with the measured wall
 time, the bucket key that triggered it, and the compile ``kind``:
 
 - ``"fresh"`` — a real trace+lower+compile (XLA did the work now);
+- ``"cache"`` — traced and lowered now, every executable loaded from
+  jax's persistent compile cache (``telemetry.trace.compile_in`` reads
+  jax's own events; ``wall_s`` of both kinds leaves the first run out);
 - ``"aot"``   — a ``jax.export`` rehydrate from the fleet AOT cache
   (deserialization cost only; the restart gate's whole point is that
   these are NOT compiles in the ``compile_count == 0`` sense).
@@ -44,6 +47,7 @@ __all__ = [
 COMPILE_BUCKETS = tuple(1e-3 * 2**i for i in range(21))
 
 KIND_FRESH = "fresh"
+KIND_CACHE = "cache"
 KIND_AOT = "aot"
 
 
@@ -51,8 +55,8 @@ KIND_AOT = "aot"
 class CompileEvent:
     """One compile (or AOT rehydrate) observed anywhere in the process."""
 
-    site: str            # "batched_bucket" | "dist_build" | "aot_dispatch" | "train_step" | ...
-    kind: str            # "fresh" | "aot"
+    site: str            # "batched_bucket" | "dist_potential" | "aot_dispatch" | "train_step" | ...
+    kind: str            # "fresh" | "cache" | "aot"
     wall_s: float        # measured trace+lower+compile (or rehydrate) wall time
     bucket_key: str = ""
     executable_bytes: int = 0   # serialized executable size when known (AOT path)
@@ -116,13 +120,13 @@ def record_compile(site: str, kind: str, wall_s: float, bucket_key: str = "",
         try:
             reg.histogram(
                 "distmlip_compile_seconds",
-                "Wall time of compiles by site and kind (fresh|aot)",
+                "Wall time of compiles by site and kind (fresh|cache|aot)",
                 labels=("site", "kind"),
                 buckets=COMPILE_BUCKETS).labels(
                     site=site, kind=kind).observe(ev.wall_s)
             reg.counter(
                 "distmlip_compiles_total",
-                "Compile events by site and kind (fresh|aot)",
+                "Compile events by site and kind (fresh|cache|aot)",
                 labels=("site", "kind")).labels(
                     site=site, kind=kind).inc()
         except Exception:  # noqa: BLE001 - metrics must not break compiles
@@ -136,7 +140,7 @@ def compile_events() -> list[CompileEvent]:
 
 
 def compile_counts() -> dict[str, int]:
-    """{kind: count} over the retained window — the fresh-vs-aot split."""
+    """{kind: count} over the retained window — the fresh / cache / aot split."""
     return _LOG.counts()
 
 

@@ -10,7 +10,8 @@ One pipeline replaces the ad-hoc timing that used to live in
 - ``annotate``/``scope``/``device_trace`` — xprof timeline names on the
   host and jit hot paths; ``STAGES`` names the stages the models scope,
   ``stage_tables()`` says after a tracing session which compiled
-  instruction belongs to which;
+  instruction belongs to which; ``phase``/``log_phase``/``phases`` — the
+  always-on log of what happens once (import, graph build, compile);
 - ``report`` — offline aggregation of a JSONL run
   (``tools/telemetry_report.py``).
 
@@ -29,8 +30,9 @@ from .record import PHASE_KEYS, StepRecord, TrainRecord
 from .sinks import (AggregatingSink, JsonlSink, StderrSummarySink, Telemetry,
                     TelemetrySink)
 from .stages import STAGES
-from .trace import (annotate, device_trace, note_dispatch, scope, set_tracing,
-                    stage_tables, tracing_enabled)
+from .trace import (annotate, device_trace, log_phase, note_dispatch, phase,
+                    phases, reset_phases, scope, set_tracing, stage_tables,
+                    tracing_enabled)
 
 __all__ = [
     "PHASE_KEYS",
@@ -48,5 +50,9 @@ __all__ = [
     "tracing_enabled",
     "note_dispatch",
     "stage_tables",
+    "phase",
+    "log_phase",
+    "phases",
+    "reset_phases",
     "STAGES",
 ]

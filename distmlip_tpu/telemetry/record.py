@@ -135,8 +135,10 @@ class StepRecord:
     compiled: bool = False           # this step triggered an XLA compile
     # --- compile telemetry (obs/profiling.py; meaningful when compiled
     #     or aot_rehydrated — 0.0/"" on warm steps and in old JSONL) ---
-    compile_s: float = 0.0           # trace+lower+compile (or rehydrate) wall
-    compile_kind: str = ""           # "fresh" | "aot" | "" (no compile)
+    compile_s: float = 0.0           # trace+lower+compile (or load, or
+    #                                  rehydrate) wall, without the first run
+    compile_kind: str = ""           # "fresh" | "cache" (persistent cache
+    #                                  served every executable) | "aot" | ""
 
     # --- static HBM plan (analysis/memory.py; 0 = no estimate observed) ---
     # estimated per-device peak live bytes of the step's traced program

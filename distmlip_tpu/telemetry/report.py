@@ -89,6 +89,7 @@ class Report:
             out.append(
                 f"compile: fresh={c.get('compiles_fresh', 0)} "
                 f"aot_rehydrate={c.get('compiles_aot', 0)} "
+                f"cache_load={c.get('compiles_cache', 0)} "
                 f"wall={c.get('compile_time_s', 0.0):.3f}s")
         if "min_node_occupancy" in c:
             out.append(
@@ -320,6 +321,7 @@ def aggregate(
     if any(kinds):
         c["compiles_fresh"] = sum(k == "fresh" for k in kinds)
         c["compiles_aot"] = sum(k == "aot" for k in kinds)
+        c["compiles_cache"] = sum(k == "cache" for k in kinds)
         c["compile_time_s"] = sum(
             float(getattr(r, "compile_s", 0.0) or 0.0) for r in records)
     node_occ = [r.node_occupancy for r in records if r.node_occupancy > 0]

@@ -112,7 +112,12 @@ def stage_table(hlo_text: str) -> list[dict]:
     where the first has none; failing that, of the ``while`` whose body or
     condition it sits in (a scatter over a few indices becomes a loop of
     whole-array updates whose body has no metadata); and says so
-    (``inherited``)."""
+    (``inherited``). Whatever still reads ``forward`` inside a ``while``
+    that reads ``backward`` or ``recompute`` runs when that loop runs: its
+    pass is ``recompute`` (``pass_inherited``). A checkpointed scan body's
+    writes of its pieces into the chunk's array carry no
+    ``rematted_computation`` of their own (116.5 ms a step of MACE's,
+    PERF.md section 5, PR 35)."""
     computations: dict[str, list] = {}  # name -> [(head, opcode, ...)]
     fused_bodies = set()
     loops = {}  # a while's body or condition -> (its op_name, where it is)
@@ -168,10 +173,22 @@ def stage_table(hlo_text: str) -> list[dict]:
                 return stage, pass_of(op_name)
         return None
 
+    def loop_pass(name: str):
+        """The pass of the nearest enclosing ``while`` that does not read
+        ``forward``, or None."""
+        seen = set()
+        while name in loops and name not in seen:
+            seen.add(name)
+            op_name, name = loops[name]
+            if pass_of(op_name) != "forward":
+                return pass_of(op_name)
+        return None
+
     rows = []
     for name, instructions in computations.items():
         if name in fused_bodies:
             continue
+        runs_in = loop_pass(name)
         made = {}  # instruction name -> its row, in the computation's order
         for head, opcode, op_name, called, own, operands in instructions:
             row = {"head": head, "stage": stage_of(op_name),
@@ -195,6 +212,8 @@ def stage_table(hlo_text: str) -> list[dict]:
                 if label is not None:
                     row.update(stage=label[0], inherited=True)
                     row["pass"] = label[1]
+            if runs_in is not None and row["pass"] == "forward":
+                row.update({"pass": "recompute", "pass_inherited": True})
             made[own] = row
             if opcode not in _SILENT:
                 rows.append(row)

@@ -22,15 +22,32 @@ each noted executable's compiled text is read into a stage table
 (``stages.stage_table``): which instruction belongs to which stage of the
 model and to which pass. ``stage_tables()`` returns the tables of the last
 closed session: plain data that outlives the potential.
+
+A PHASE is a span of work that happens once per process, per graph build
+or per compile, never once per step: the package's import, the runtime
+build, a graph build's three parts, the four parts of a call that built a
+graph or compiled, and jax's own stages of a compile (``listen_to_jax``).
+``phase(name)`` / ``log_phase(name, t0, t1)`` record it on
+``time.perf_counter()`` whether or not a session is open; inside one
+``phase`` is also the ``TraceAnnotation`` of that name, so a rebuild in a
+traced window sits on the device trace's clock. ``phases()`` is plain
+data, like ``stage_tables()``. ``annotate`` stays the per-step primitive.
 """
 
 from __future__ import annotations
 
 import contextlib
+import threading
+import time
+from collections import deque
 
 _tracing = False
 _noted: dict = {}         # (id(fn), argument shapes) -> (fn, args), session
 _stage_tables: list = []  # of the last closed session
+# (name, t0, t1, thread id) on perf_counter, oldest first; a set-up logs
+# some dozens, a compile four, a graph build three
+_phases: deque = deque(maxlen=4096)
+_phase_lock = threading.Lock()  # phases are rare: never on a steady step
 
 
 def set_tracing(on: bool) -> None:
@@ -70,8 +87,6 @@ def _close_session() -> None:
     text is read and dropped. An executable whose text cannot be had keeps
     an empty table with the ``error``: its device time then reads as
     unattributed."""
-    import time
-
     from .stages import stage_table
 
     _stage_tables.clear()
@@ -101,7 +116,8 @@ def _close_session() -> None:
 
 def stage_tables() -> list:
     """``[{"executable", "instructions": [{"head", "stage", "pass",
-    "stages"?, "inherited"?}, ...], "build_s", "error"?}, ...]`` of the
+    "stages"?, "inherited"?, "pass_inherited"?}, ...], "build_s",
+    "error"?}, ...]`` of the
     last closed session; empty while tracing was never on."""
     return _stage_tables
 
@@ -126,6 +142,150 @@ def annotate(name: str):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+class phase:
+    """Context manager: logs ``(name, t0, t1, thread id)`` when it closes,
+    tracing on or off, and inside a session holds the ``TraceAnnotation``
+    of the same name open meanwhile."""
+
+    __slots__ = ("name", "t0", "annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        self.annotation = annotate(self.name)
+        self.annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return None
+
+    def __exit__(self, *exc):
+        log_phase(self.name, self.t0, time.perf_counter())
+        return self.annotation.__exit__(*exc)
+
+
+def log_phase(name: str, t0: float, t1: float) -> None:
+    """A phase whose kind is known only afterwards (a call's four parts
+    once it is seen to have compiled; a jax event that reports its
+    duration as it ends)."""
+    with _phase_lock:
+        _phases.append((name, t0, t1, threading.get_ident()))
+
+
+def phases() -> list:
+    """``[(name, t0, t1, thread id), ...]`` of this process, oldest first,
+    ``t0`` / ``t1`` on ``time.perf_counter()``."""
+    with _phase_lock:
+        return list(_phases)
+
+
+def phase_totals() -> dict:
+    """``{name: [summed seconds, count]}`` of the log: the one line an
+    operator's tool prints after its set-up. A sum, not a union: a nested
+    phase counts in its own name and in its caller's."""
+    out: dict = {}
+    for name, t0, t1, _ in phases():
+        row = out.setdefault(name, [0.0, 0])
+        row[0] += t1 - t0
+        row[1] += 1
+    return {name: [round(s, 6), n] for name, (s, n) in out.items()}
+
+
+def reset_phases() -> None:
+    """Tests."""
+    with _phase_lock:
+        _phases.clear()
+
+
+# jax's duration events -> phase names. The backend event spans
+# ``compile_or_get_cached``: the compile, or the load from the persistent
+# cache, in which case a ``jax/cache_retrieval`` lies inside it.
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax/lower",
+    "/jax/core/compile/backend_compile_duration": "jax/backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax/cache_retrieval",
+}
+_JAX_COUNTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+               "/jax/compilation_cache/cache_misses": "cache_misses"}
+_COMPILE = frozenset(_JAX_PHASES.values())
+_jax_counts = dict.fromkeys(_JAX_COUNTS.values(), 0)
+_listening = False
+
+
+def _on_jax_duration(event: str, seconds: float, **_) -> None:
+    name = _JAX_PHASES.get(event)
+    if name is None:
+        return
+    now = time.perf_counter()
+    t0, me = now - seconds, threading.get_ident()
+    with _phase_lock:
+        # every jit a program calls reports its own trace as it ends,
+        # hundreds to a model, each inside its caller's trace or inside the
+        # lowering that traces it again: the log keeps the outermost
+        while _phases and _phases[-1][0] == "jax/trace" \
+                and _phases[-1][3] == me and _phases[-1][1] >= t0:
+            _phases.pop()
+        _phases.append((name, t0, now, me))
+
+
+def _on_jax_event(event: str, **_) -> None:
+    name = _JAX_COUNTS.get(event)
+    if name is not None:
+        _jax_counts[name] += 1
+
+
+def listen_to_jax() -> None:
+    """Turn jax's own timing of a compile's stages into phases ending
+    now, and count the persistent cache's hits and misses. The events fire
+    at compiles only. Registered once a process: by an entry point's
+    ``enable_compile_cache()`` before its first jit, else by the first
+    potential that builds its runtime (never at import)."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def jax_cache_counts() -> dict:
+    """``{"cache_hits", "cache_misses"}`` of jax's persistent compile
+    cache since :func:`listen_to_jax`."""
+    return dict(_jax_counts)
+
+
+def compile_in(t0: float, t1: float) -> tuple[float, bool]:
+    """What jax did on this thread to build executables inside the call
+    ``[t0, t1]``: ``(seconds, from_cache)``. The seconds are the union of
+    the ``jax/trace``, ``jax/lower`` and ``jax/backend_compile`` phases (a
+    nested jit's trace lies inside its caller's), so they hold neither the
+    first run nor the wait; ``from_cache`` says every backend phase held a
+    retrieval from the persistent cache (and there was one)."""
+    me = threading.get_ident()
+    inside = [(a, b, name) for name, a, b, tid in phases()
+              if tid == me and a >= t0 and b <= t1 and name in _COMPILE]
+    backend = sum(name == "jax/backend_compile" for _, _, name in inside)
+    loads = sum(name == "jax/cache_retrieval" for _, _, name in inside)
+    seconds, end = 0.0, t0
+    for a, b, name in sorted(inside):
+        if name != "jax/cache_retrieval" and b > end:
+            seconds += b - max(a, end)
+            end = b
+    return seconds, backend > 0 and loads >= backend
+
+
+def log_first_call(t_start: float, t_prepared: float, t_dispatched: float,
+                   t_waited: float, t_done: float) -> None:
+    """The four parts of a potential's call that built a graph or an
+    executable, from the five stamps the call took anyway."""
+    log_phase("distmlip/first_call.prepare", t_start, t_prepared)
+    log_phase("distmlip/first_call.dispatch", t_prepared, t_dispatched)
+    log_phase("distmlip/first_call.wait", t_dispatched, t_waited)
+    log_phase("distmlip/first_call.results_to_host", t_waited, t_done)
 
 
 def scope(name: str):

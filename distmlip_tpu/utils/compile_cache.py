@@ -25,6 +25,11 @@ def enable_compile_cache() -> str:
     """
     import jax
 
+    from ..telemetry.trace import listen_to_jax
+
+    # an entry point's own jits (weights, tables) compile or load before
+    # the first potential exists: the phase log hears them from here on
+    listen_to_jax()
     # An executable loaded from the cache keeps the metadata of the code
     # that compiled it, and jax leaves metadata out of the cache's key. The
     # stage tables of a tracing session (telemetry/trace.py) read each

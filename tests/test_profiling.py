@@ -115,7 +115,8 @@ def test_batched_bucket_compile_records_fresh(pair):
     pot = BatchedPotential(model, params)
     pot.calculate([make_atoms(seed=1)])
     counts = profiling.compile_counts()
-    assert counts.get("fresh", 0) >= 1
+    # "cache": the same build served by jax's persistent compile cache
+    assert counts.get("fresh", 0) + counts.get("cache", 0) >= 1
     assert not counts.get("aot", 0)
     # warm repeat (same bucket): no new events
     n0 = len(profiling.compile_events())

@@ -519,6 +519,94 @@ def test_stage_table_reads_fusions_by_their_root():
     assert rows["%copy.4 = f32[8]{0} copy"]["stage"] is None
 
 
+REMAT_LOOP_HLO = """HloModule jit_f
+
+%fused_piece (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %m = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(f)/jvp(model/edge_message)/mul"}
+}
+
+%fused_back (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %n = f32[8]{0} negate(%p), metadata={op_name="jit(f)/transpose(jvp(model))/edge_message/neg"}
+}
+
+%inner_body (s: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %s = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%s), index=0
+  %v = f32[8]{0} get-tuple-element(%s), index=1
+  %deep = f32[8]{0} add(%v, %v), metadata={op_name="jit(f)/jvp(model/node_tensor)/add"}
+  ROOT %t = (s32[], f32[8]{0}) tuple(%i, %deep)
+}
+
+%inner_cond (s: (s32[], f32[8])) -> pred[] {
+  %s = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%s), index=0
+  ROOT %lt = pred[] compare(%i, %i), direction=LT
+}
+
+%body (s: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %s = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%s), index=0
+  %v = f32[8]{0} get-tuple-element(%s), index=1
+  %piece = f32[8]{0} fusion(%v), kind=kLoop, calls=%fused_piece, metadata={op_name="jit(f)/jvp(model/edge_message)/mul"}
+  %again = f32[8]{0} multiply(%piece, %v), metadata={op_name="jit(f)/transpose(jvp(model))/checkpoint/rematted_computation/edge_message/mul"}
+  %back = f32[8]{0} fusion(%again), kind=kLoop, calls=%fused_back, metadata={op_name="jit(f)/transpose(jvp(model))/edge_message/neg"}
+  %st = (s32[], f32[8]{0}) tuple(%i, %back)
+  %nested = (s32[], f32[8]{0}) while(%st), condition=%inner_cond, body=%inner_body, metadata={op_name="jit(f)/jvp(model/node_tensor)/while"}
+  ROOT %t = (s32[], f32[8]{0}) tuple(%i, %back)
+}
+
+%cond (s: (s32[], f32[8])) -> pred[] {
+  %s = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%s), index=0
+  ROOT %lt = pred[] compare(%i, %i), direction=LT
+}
+
+%fwd_body (s: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %s = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%s), index=0
+  %v = f32[8]{0} get-tuple-element(%s), index=1
+  %first = f32[8]{0} fusion(%v), kind=kLoop, calls=%fused_piece, metadata={op_name="jit(f)/jvp(model/edge_message)/mul"}
+  ROOT %t = (s32[], f32[8]{0}) tuple(%i, %first)
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %z = s32[] constant(0)
+  %st = (s32[], f32[8]{0}) tuple(%z, %x)
+  %scan = (s32[], f32[8]{0}) while(%st), condition=%cond, body=%fwd_body, metadata={op_name="jit(f)/jvp(model/edge_gather)/while"}
+  %loop = (s32[], f32[8]{0}) while(%scan), condition=%cond, body=%body, metadata={op_name="jit(f)/transpose(jvp(model))/edge_gather/while"}
+  ROOT %out = f32[8]{0} get-tuple-element(%loop), index=1
+}
+"""
+
+
+def test_an_instruction_inherits_the_pass_of_the_loop_it_runs_in():
+    """A checkpointed scan body's pieces keep the forward's ``op_name`` (no
+    ``rematted_computation``) in the backward scan's ``while``: they run
+    when that loop runs, so their pass is ``recompute``. ``mace-md-1c``
+    read 116.5 ms a step of them as ``forward`` (PERF.md section 5, PR 35)."""
+    rows = {r["head"].split(" = ")[0]: r for r in stage_table(REMAT_LOOP_HLO)}
+    piece = rows["%piece"]
+    assert (piece["stage"], piece["pass"], piece["pass_inherited"]) == (
+        "edge_message", "recompute", True)
+    # what says its pass itself keeps it, unmarked
+    assert rows["%again"]["pass"] == "recompute"
+    assert rows["%back"]["pass"] == "backward"
+    assert "pass_inherited" not in rows["%again"]
+    assert "pass_inherited" not in rows["%back"]
+    # a loop within the loop, and its body, run there too
+    assert (rows["%nested"]["pass"], rows["%deep"]["pass"],
+            rows["%deep"]["pass_inherited"]) == ("recompute", "recompute",
+                                                 True)
+    # the same fusion in the forward scan stays forward, as do the loops
+    assert rows["%first"]["pass"] == "forward"
+    assert "pass_inherited" not in rows["%first"]
+    assert rows["%scan"]["pass"] == "forward"
+    assert rows["%loop"]["pass"] == "backward"
+
+
 @pytest.fixture
 def fresh_session(monkeypatch):
     """The module's session state, emptied for one test."""

@@ -1,10 +1,12 @@
 """Scatter-hint pass: hot-path segment sums must declare sorted indices.
 
-The ENTIRE data layout exists to serve one hint: partition/graph.py and
-partition/batch.py emit every edge/line array dst-sorted (globally
-nondecreasing ``edge_dst``, repeat-last-real padding) precisely so every
+The edge layout exists to serve one hint: partition/graph.py and
+partition/batch.py emit every edge array dst-sorted (nondecreasing
+``edge_dst`` per segment, repeat-last-real padding) precisely so every
 ``segment_sum``/scatter-add on the hot path can pass
-``indices_are_sorted=True`` and take the TPU scatter fast path. A call
+``indices_are_sorted=True`` and take the TPU scatter fast path. (CHGNet's
+lines need no hint: their sum onto bonds is a sum over the slabs of the
+in-line table, ``ops/segment.slab_sum``, not a scatter.) A call
 site that forgets the hint silently falls back to the general scatter —
 correct results, order-of-magnitude slower — which no numeric test will
 ever catch. This pass makes the hint a statically checked contract.

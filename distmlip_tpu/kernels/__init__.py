@@ -29,6 +29,7 @@ Layout:
 from .dispatch import (  # noqa: F401
     Gather,
     KernelCounter,
+    Repeat,
     counting,
     force_kernel_mode,
     fused_edge_aggregate,

@@ -42,7 +42,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..ops.segment import masked_segment_sum
+from ..ops.segment import masked_segment_sum, slab_repeat, slab_sum
 from ..telemetry import scope
 from .segment import (
     pallas_edge_aggregate,
@@ -142,6 +142,15 @@ class Gather:
     idx: Any
     # populated by dispatch: node flattened trailing shape restored in rows
     trailing: tuple = field(default_factory=tuple)
+
+
+@dataclass
+class Repeat:
+    """A bond-row input to :func:`fused_edge_aggregate` in its table form:
+    the rows of ``node`` ``(num_segments, ...)`` at every slot of a
+    slot-major table, i.e. ``node[segment_ids]`` without the index array."""
+
+    node: Any
 
 
 def force_kernel_mode(mode: str | None):
@@ -430,7 +439,7 @@ def _match_consts(raw_fwd, raw_bwd):
     return perm
 
 
-def _rows_of(item):
+def _rows_of(item, slabs: int = 0):
     """Materialize one input's per-edge rows (XLA path / backward).
 
     Half-precision node arrays gather through an fp32 view: the gather's
@@ -439,6 +448,8 @@ def _rows_of(item):
     precision with one rounding at the end (the dtype_discipline
     contract) — the forward rows are bit-identical (upcast/downcast of
     the same values) and the convert fuses into the gather."""
+    if isinstance(item, Repeat):
+        return slab_repeat(jnp.asarray(item.node), slabs)  # same rule
     if isinstance(item, Gather):
         node = jnp.asarray(item.node)
         if str(node.dtype) in ("bfloat16", "float16"):
@@ -453,7 +464,8 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
                          kernels=None, diff_params: bool = True,
                          vmem_budget: int | None = None,
                          bwd_chunk: int | None = None,
-                         stages: tuple = ("edge_message", "edge_aggregate")):
+                         stages: tuple = ("edge_message", "edge_aggregate"),
+                         slabs: int = 0):
     """Fused gather + per-edge compute + dst-sorted segment sum.
 
     ``inputs``: per-edge arrays ``(E, ...)`` and/or :class:`Gather`
@@ -476,12 +488,21 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
     ``stages``: the two scopes (telemetry/stages.py) this call opens,
     (message, aggregate). They are innermost, so a caller's own scope
     around the call loses to them: a call over another graph than the
-    atom graph (CHGNet's line list) names its own here.
+    atom graph (CHGNet's lines) names its own here.
+
+    Table form (``slabs > 0``, ``segment_ids`` None): the rows are a
+    slot-major table of ``slabs * num_segments`` slots, row
+    ``k * num_segments + n`` the k-th of segment ``n`` (CHGNet's in-line
+    table, ``partition/graph.line_table``). ``inputs`` may then hold
+    :class:`Repeat` markers, rows addressed by the segment itself. On the
+    XLA path those are repeats and the sum is a sum over the slabs, no index
+    traffic; the kernel tiles a dst-sorted list, which the table becomes by
+    a static transposition (slot-major to dst-major).
     """
     inputs = list(inputs)
     msg_stage, agg_stage = stages
     mode = resolve_kernel_mode(kernels, op="edge_aggregate")
-    e = int(segment_ids.shape[0])
+    e = slabs * num_segments if slabs else int(segment_ids.shape[0])
     # float (inexact) masks would need a mask cotangent the chunked
     # backward doesn't produce — every mask in this repo is boolean; a
     # float mask routes to the XLA path where plain AD handles it
@@ -496,10 +517,27 @@ def fused_edge_aggregate(edge_fn, inputs, segment_ids, num_segments: int,
         # radial MLP inside it, are innermost and win), the sum is the
         # aggregate; the fused kernel below is one operation: aggregate
         with scope(msg_stage):
-            msg = edge_fn(*[_rows_of(i) for i in inputs])
+            msg = edge_fn(*[_rows_of(i, slabs) for i in inputs])
         with scope(agg_stage):
+            if slabs:
+                return slab_sum(msg, num_segments, mask)
             return masked_segment_sum(msg, segment_ids, num_segments, mask,
                                       indices_are_sorted=indices_are_sorted)
+
+    if slabs:
+        with scope(msg_stage):
+            def dst_major(x):
+                x = jnp.asarray(x)
+                return x.reshape((slabs, num_segments) + x.shape[1:]
+                                 ).swapaxes(0, 1).reshape(x.shape)
+
+            segment_ids = jnp.repeat(
+                jnp.arange(num_segments, dtype=jnp.int32), slabs)
+            inputs = [Gather(i.node, segment_ids) if isinstance(i, Repeat)
+                      else Gather(i.node, dst_major(i.idx))
+                      if isinstance(i, Gather) else dst_major(i)
+                      for i in inputs]
+            mask = None if mask is None else dst_major(mask)
 
     interpret = mode == "interpret"
     budget = DEFAULT_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)

@@ -49,7 +49,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ..kernels.dispatch import Gather, fused_edge_aggregate
+from ..kernels.dispatch import Gather, Repeat, fused_edge_aggregate
 from ..ops import radial
 from ..ops.nn import (cast_params_subtrees, embedding, gated_mlp,
                       gated_mlp_init, gather_rows, linear, linear_init, mlp,
@@ -250,22 +250,16 @@ class CHGNet:
         use_bg = cfg.use_bond_graph and lg.has_bond_graph and params["bond_blocks"]
         if use_bg:
             with scope("line_geometry"):
-                b_vec, b_d, vx = self._bond_geometry(lg, vec, d, v)
-                # padded bond rows have d=0; skin-shell bonds (d >
-                # bond_cutoff) are excluded like skin-shell edges above
-                b_real = (b_d > 1e-6) & (b_d <= cfg.bond_cutoff)
+                bgeo, vx = self._bond_geometry(lg, vec, d, v)
+                b_d = bgeo[:, 3]
+                b_real = self._is_bond(b_d)
                 rbf3 = (self._expansion(
                     jnp.where(b_d > 1e-6, b_d, 1.0), fp["freq_three"],
                     cfg.bond_cutoff) * b_real[:, None]).astype(dtype)
                 tbw = (linear(params["three_bond_w"], rbf3)
                        if "three_bond_w" in params else None)
 
-                # line edges are live only when BOTH bonds are real and
-                # within the threebody cutoff (matgl's line graph contains
-                # only such pairs; skin-shell bonds must contribute nothing)
-                line_ok = (lg.line_mask & b_real[lg.line_src]
-                           & b_real[lg.line_dst])
-                a = self._angle_features(params, fp, lg, b_vec, b_d, dtype)
+                a, line_ok = self._line_features(params, fp, lg, bgeo, dtype)
 
                 # bond-node features are (re-)seeded from edge features at
                 # the top of every block (reference dist_forward re-seeds
@@ -313,34 +307,47 @@ class CHGNet:
             return v.astype(positions.dtype), site
 
     def _bond_geometry(self, lg, vec, d, v):
-        """(vector, length) of every bond node, and the exchanged atom
-        features: owned rows seeded from their edges, halo rows (whose
-        endpoints may not be local) by the bond halo exchange (reference
-        bond_transfer of bond_dist/bond_vec, chgnet.py:126-164) —
-        COALESCED with the atom-feature init exchange: both refreshes ride
-        one ppermute per ring shift."""
+        """Rows ``[vector | length]`` ``(b_cap, 4)`` of every bond node, and
+        the exchanged atom features: owned rows seeded from their edges,
+        halo rows (whose endpoints may not be local) by the bond halo
+        exchange (reference bond_transfer of bond_dist/bond_vec,
+        chgnet.py:126-164) — COALESCED with the atom-feature init exchange:
+        both refreshes ride one ppermute per ring shift."""
         bgeo = jnp.zeros((lg.b_cap, 4), dtype=vec.dtype)
         edge_geo = jnp.concatenate([vec, d[:, None]], axis=-1)
         bgeo = lg.edge_to_bond(edge_geo, bgeo)
         (vx,), (bgeo,) = lg.exchange_all((v,), (bgeo,))
-        return bgeo[:, :3], bgeo[:, 3], vx
+        return bgeo, vx
 
-    def _angle_features(self, params, fp, lg, b_vec, b_d, dtype):
-        """Embedded Fourier basis of theta on every line (L, C): theta at
+    def _is_bond(self, d):
+        """Padded bond rows have d = 0; skin-shell bonds (d > bond_cutoff)
+        are excluded like skin-shell edges."""
+        return (d > 1e-6) & (d <= self.cfg.bond_cutoff)
+
+    def _line_features(self, params, fp, lg, bgeo, dtype):
+        """Per line: the embedded Fourier basis of theta (L, C), theta at
         the center atom (reference src_bond_sign=-1 + compute_theta,
-        chgnet.py:184-197). Coordinates, theta and the basis are float32:
-        fcc has collinear bond pairs, where arccos has slope
+        chgnet.py:184-197), and whether the line is live: only when BOTH
+        bonds are real and within the threebody cutoff (matgl's line graph
+        contains only such pairs; skin-shell bonds must contribute nothing).
+
+        The source bond's vector and length come by ONE gather of the
+        4-wide rows, and its membership from the gathered length: on the
+        chip a length or a mask gathered by itself costs four times the row
+        (9.6 and 9.0 ms against 2.0 over 1.1M lines; chip runs, PR 37).
+        The destination's are repeats. Coordinates, theta and the basis
+        are float32: fcc has collinear bond pairs, where arccos has slope
         1 / sqrt(1 - cos^2) (about 50 at a 0.04 A perturbation)."""
-        v1 = b_vec[lg.line_src]
-        v2 = b_vec[lg.line_dst]
-        d1 = jnp.maximum(b_d[lg.line_src], 1e-6)
-        d2 = jnp.maximum(b_d[lg.line_dst], 1e-6)
-        cos_t = -jnp.sum(v1 * v2, axis=-1) / (d1 * d2)
+        src, dst = bgeo[lg.line_src], lg.at_line_dst(bgeo)
+        d1, d2 = src[:, 3], dst[:, 3]
+        line_ok = lg.line_mask & self._is_bond(d1) & self._is_bond(d2)
+        cos_t = -jnp.sum(src[:, :3] * dst[:, :3], axis=-1) / (
+            jnp.maximum(d1, 1e-6) * jnp.maximum(d2, 1e-6))
         cos_t = jnp.clip(cos_t, -1.0 + 1e-6, 1.0 - 1e-6)
         theta = jnp.arccos(cos_t)
         return mlp(params["angle_emb"],
                    radial.matgl_fourier_expansion(
-                       theta, fp["freq_angle"]).astype(dtype))
+                       theta, fp["freq_angle"]).astype(dtype)), line_ok
 
     # ---- layers ----
     def _atom_conv(self, blk, lg, v, vx, e, abw, bbw, in_r):
@@ -381,6 +388,16 @@ class CHGNet:
             v = vx + linear(blk["node_out"], agg)
         return v, e
 
+    @staticmethod
+    def _center_rows(lg, v):
+        """Atom features at each bond row's centre atom, ``(b_cap, C)`` (a
+        line reads them through its destination bond: ``at_line_dst`` /
+        ``Repeat``). In float32 for half-precision ``v``: the lines'
+        cotangents then add up over the slabs and onto the atoms in float32
+        and round once (``gather_rows``'s rule); a line casts its row."""
+        return v.astype(jnp.promote_types(v.dtype, jnp.float32))[
+            lg.bond_center]
+
     def _bond_node_conv(self, blk, lg, v, b, a, tbw, line_ok):
         """Line-graph node phase (matgl CHGNetLineGraphConv node update,
         reference chgnet_layers.py:101-105): messages [b_src|b_dst|angle|
@@ -389,23 +406,25 @@ class CHGNet:
         receive in-lines (the partitioner's needs_in_line rule); halo bonds
         are refreshed by the surrounding exchanges.
 
-        The line-graph message (gathers + gated MLP + dst-sorted sum) goes
-        through the kernel dispatcher: on the Pallas path it fuses per dst
-        tile and the (L, 4C) concat / (L, C) message intermediates never
-        materialize; the XLA path is the historical program. The
+        The line-graph message (rows + gated MLP + sum onto the dst bond)
+        goes through the kernel dispatcher in its table form: the lines are
+        a slot-major in-line table (``LocalGraph``), so only ``b_src`` is a
+        gather; ``b_dst`` and ``v_center`` are repeats and the sum runs over
+        the slabs. On the Pallas path it fuses per dst tile and the (L, 4C)
+        concat / (L, C) message intermediates never materialize. The
         dispatcher's own scopes are innermost, so it is told this call's
         stage: without that the three-body work reads as atom-graph work."""
 
         def line_msg(b_src, b_dst, a_row, v_ctr):
             return gated_mlp(blk["node_update"], jnp.concatenate(
-                [b_src, b_dst, a_row, v_ctr], axis=-1))
+                [b_src, b_dst, a_row, v_ctr.astype(a_row.dtype)], axis=-1))
 
         with scope("line_message"):
             agg = fused_edge_aggregate(
                 line_msg,
-                [Gather(b, lg.line_src), Gather(b, lg.line_dst), a,
-                 Gather(v, lg.line_center)],
-                lg.line_dst, lg.b_cap, line_ok, indices_are_sorted=True,
+                [Gather(b, lg.line_src), Repeat(b), a,
+                 Repeat(self._center_rows(lg, v))],
+                None, lg.b_cap, line_ok, slabs=lg.line_slots,
                 kernels=lg.kernels, diff_params=lg.kernels_diff_params,
                 stages=("line_message", "line_message"))
             upd = linear(blk["node_out"], agg)
@@ -416,12 +435,14 @@ class CHGNet:
     def _angle_conv(self, blk, lg, v, b, a, line_ok):
         """Line-graph edge phase (angle update from the refreshed bond
         features, reference chgnet_layers.py:109-118): gated update on
-        [b_src|b_dst|angle|v_center], residual, no weights. Rows gather
-        through ``gather_rows``: each bond is read by some twenty lines,
-        and their cotangents add up in float32."""
+        [b_src|b_dst|angle|v_center], residual, no weights. Each bond is
+        read by some twenty lines, and their cotangents add up in float32:
+        the one gather through ``gather_rows``, the rows at the lines'
+        destination through ``at_line_dst``."""
         with scope("angle_update"):
             feats = jnp.concatenate(
-                [gather_rows(b, lg.line_src), gather_rows(b, lg.line_dst), a,
-                 gather_rows(v, lg.line_center)], axis=-1)
+                [gather_rows(b, lg.line_src), lg.at_line_dst(b), a,
+                 lg.at_line_dst(self._center_rows(lg, v)).astype(a.dtype)],
+                axis=-1)
             m = gated_mlp(blk["angle_update"], feats)
             return a + m * line_ok[:, None].astype(m.dtype)

@@ -32,6 +32,7 @@ from ..kernels.dispatch import (Gather, fused_edge_aggregate,
                                 fused_segment_sum, fused_segment_sum_into,
                                 segment_sum_carry, segment_sum_result)
 from ..ops.chunk import chunk_layout, chunked, scan_accumulate, take_rows
+from ..ops.segment import slab_repeat, slab_sum
 from ..telemetry import scope
 
 
@@ -96,8 +97,19 @@ class LocalGraph:
     (``indices_are_sorted`` segment sums per segment — use
     ``aggregate_edges``/``overlapped_edge_sum``/``scan_edges``, never a raw
     full-array sorted segment sum when ``has_frontier_split``). Interior
-    edges read only owned rows; frontier edges read halo src rows. Same
-    contract for ``line_dst`` (unsplit, globally sorted).
+    edges read only owned rows; frontier edges read halo src rows.
+
+    Line layout contract: the lines are a slot-major in-line table over the
+    ``b_cap`` bond rows (``partition/graph.line_table``): entry
+    ``k * b_cap + b`` of ``line_src`` / ``line_mask`` (and of every per-line
+    array a model makes) is the k-th line INTO bond row ``b``, with
+    ``line_slots`` slabs. A line's destination bond and its centre atom are
+    therefore its position: :meth:`at_line_dst` (a repeat) and
+    :meth:`sum_to_line_dst` (a sum over the slabs) are the only code that
+    knows the order, each the other's transpose; the centre is
+    ``bond_center`` (``(b_cap,)``, one atom a bond row) read through
+    ``at_line_dst``. Only ``line_src`` is an index array. Pad slots are
+    masked and in bounds; halo and padded bond rows have no live slot.
     """
 
     axis_name: str | None
@@ -110,18 +122,17 @@ class LocalGraph:
     owned_mask: Any
     edge_src: Any
     edge_dst: Any       # CONTRACT: nondecreasing within each edge segment
-    edge_offset: Any    # (see class docstring); line_dst globally sorted —
-    edge_mask: Any      # established by build_partitioned_graph
+    edge_offset: Any    # (see class docstring) — established by
+    edge_mask: Any      # build_partitioned_graph
     halo_send_idx: Any
     halo_send_mask: Any
     halo_recv_idx: Any
     lattice: Any
     # bond graph
     has_bond_graph: bool = False
-    line_src: Any = None
-    line_dst: Any = None
+    line_src: Any = None      # (line_slots * b_cap,) slot-major table
     line_mask: Any = None
-    line_center: Any = None
+    bond_center: Any = None   # (b_cap,)
     bond_map_edge: Any = None
     bond_map_bond: Any = None
     bond_map_mask: Any = None
@@ -153,6 +164,11 @@ class LocalGraph:
     @property
     def has_frontier_split(self) -> bool:
         return 0 <= self.e_split < self.e_cap
+
+    @property
+    def line_slots(self) -> int:
+        """Slabs of the in-line table: the lines a bond row has room for."""
+        return self.line_src.shape[0] // self.b_cap if self.b_cap else 0
 
     def _node_tables(self):
         return (self.halo_send_idx, self.halo_send_mask, self.halo_recv_idx)
@@ -365,6 +381,19 @@ class LocalGraph:
                 out = part if out is None else out + part
             return out
 
+    # ---- the in-line table's order (see the class docstring) ----
+    def at_line_dst(self, bond_rows):
+        """Rows of a ``(b_cap, ...)`` bond array at every line's destination
+        bond, ``(line_slots * b_cap, ...)``: the array once per slab, no
+        gather. Transposes to :meth:`sum_to_line_dst`."""
+        return slab_repeat(bond_rows, self.line_slots)
+
+    def sum_to_line_dst(self, line_rows, mask=None):
+        """Sum of a per-line array onto the lines' destination bonds,
+        ``(b_cap, ...)``: a sum over the slabs, no scatter; ``mask`` zeroes
+        rows first. Halo and padded bond rows read zero."""
+        return slab_sum(line_rows, self.b_cap, mask)
+
     # ---- bond-graph index remaps (reference dist.py:635-702 analogue) ----
     def edge_to_bond(self, edge_feats, bond_feats):
         """Seed owned bond-node rows from their atom-graph edge features.
@@ -468,9 +497,8 @@ def local_graph_from_stacked(
         lattice=g.lattice,
         has_bond_graph=g.has_bond_graph,
         line_src=sq(g.line_src),
-        line_dst=sq(g.line_dst),
         line_mask=sq(g.line_mask),
-        line_center=sq(g.line_center),
+        bond_center=sq(g.bond_center),
         bond_map_edge=sq(g.bond_map_edge),
         bond_map_bond=sq(g.bond_map_bond),
         bond_map_mask=sq(g.bond_map_mask),

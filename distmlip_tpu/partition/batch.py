@@ -12,7 +12,8 @@ one shared padding tail per array):
 
   nodes:  [ atoms_0 | atoms_1 | ... | pad ]            struct_id = b per row
   edges:  [ edges_0 | edges_1 | ... | pad ]            dst-sorted per block
-  bonds:  [ bonds_0 | ... | pad ]  lines: [ lines_0 | ... | pad ]
+  bonds:  [ bonds_0 | ... | pad ]
+  lines:  slot-major in-line table over the packed bond rows (below)
 
 The existing padding contract is preserved exactly, so all models run
 unchanged on the packed ``LocalGraph``:
@@ -20,7 +21,12 @@ unchanged on the packed ``LocalGraph``:
 - per-structure edge blocks are dst-sorted and node ids only grow with the
   structure offset, so the CONCATENATED ``edge_dst`` is globally
   nondecreasing — the ``indices_are_sorted=True`` segment-sum fast path
-  holds for the whole super-array (same for ``line_dst``);
+  holds for the whole super-array;
+- the lines of all blocks form ONE in-line table over the packed bond rows
+  (``partition/graph.line_table``): slot ``k * b_cap + b`` is the k-th line
+  into bond row ``b``, ``K`` the largest in-degree in the batch, so a line's
+  destination and centre are its position and only ``line_src`` is an index
+  array; ``bond_center`` holds each bond row's centre atom;
 - padded ``dst`` rows repeat the last real value (in-bounds, nondecreasing);
   padded rows are masked so they contribute 0;
 - ``e_split == e_cap``: the packed layout is unsplit (single partition has
@@ -45,9 +51,11 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..neighbors import neighbor_list
-from .capacity import BucketPolicy, FixedCaps
+from .capacity import (LINE_SLOTS, BucketPolicy, FixedCaps, freeze_caps,
+                       line_table_cap)
 from .graph import (PartitionedGraph, build_partitioned_graph,
-                    expand_shift_tables)
+                    expand_shift_tables, line_slots_needed, line_table,
+                    line_table_stats)
 from .partitioner import build_plan
 from .plan import PartitionPlan
 
@@ -237,12 +245,11 @@ def pack_structures(
             "vol": abs(np.linalg.det(cell)),
         }
         if use_bond_graph:
-            lperm = np.argsort(plan.line_dst[0], kind="stable")
             blk.update({
                 "nb": int(plan.bond_markers[0][-1]),
-                "line_src": plan.line_src[0][lperm],
-                "line_dst": plan.line_dst[0][lperm],
-                "line_center": plan.line_center_local[0][lperm],
+                "line_src": plan.line_src[0],
+                "line_dst": plan.line_dst[0],
+                "line_center": plan.line_center_local[0],
                 "bm_edge": inv[plan.bond_mapping_edge[0]],
                 "bm_bond": plan.bond_mapping_bond[0],
             })
@@ -286,40 +293,35 @@ def pack_structures(
     if use_bond_graph:
         bond_off = np.concatenate([[0], np.cumsum([b["nb"] for b in blocks])])
         b_tot = int(bond_off[-1])
-        l_tot = int(sum(len(b["line_src"]) for b in blocks))
         m_tot = int(sum(len(b["bm_edge"]) for b in blocks))
         b_cap = caps.get("bonds", b_tot)
-        l_cap = caps.get("lines", l_tot)
         m_cap = caps.get("bond_map", m_tot)
-        line_src = np.zeros((1, l_cap), dtype=np.int32)
-        line_dst = np.zeros((1, l_cap), dtype=np.int32)
-        line_mask = np.zeros((1, l_cap), dtype=bool)
-        line_center = np.zeros((1, l_cap), dtype=np.int32)
+        slabs = line_table_cap(
+            caps, line_slots_needed([b["line_dst"] for b in blocks]), m_tot,
+            b_cap) // max(b_cap, 1)
         bm_edge = np.zeros((1, m_cap), dtype=np.int32)
         bm_bond = np.zeros((1, m_cap), dtype=np.int32)
         bm_mask = np.zeros((1, m_cap), dtype=bool)
-        ni = ei = bi = li = mi = 0
-        for b, blk in enumerate(blocks):
-            nl_b = len(blk["line_src"])
+        ei = mi = 0
+        for blk, bi in zip(blocks, bond_off):
             nm = len(blk["bm_edge"])
-            line_src[0, li:li + nl_b] = blk["line_src"] + bi
-            line_dst[0, li:li + nl_b] = blk["line_dst"] + bi
-            line_center[0, li:li + nl_b] = blk["line_center"] + ni
-            line_mask[0, li:li + nl_b] = True
             bm_edge[0, mi:mi + nm] = blk["bm_edge"] + ei
             bm_bond[0, mi:mi + nm] = blk["bm_bond"] + bi
             bm_mask[0, mi:mi + nm] = True
-            ni += blk["n"]
             ei += len(blk["src"])
-            bi += blk["nb"]
-            li += nl_b
             mi += nm
-        line_dst[0, li:] = line_dst[0, li - 1] if li else 0
-        assert np.all(np.diff(line_dst[0]) >= 0), \
-            "packed line_dst must be sorted"
+        # one table over the packed bond rows (block offsets on the ids)
+        line_src, line_mask, bond_center = (x[None] for x in line_table(
+            np.concatenate([b["line_src"] + o
+                            for b, o in zip(blocks, bond_off)]),
+            np.concatenate([b["line_dst"] + o
+                            for b, o in zip(blocks, bond_off)]),
+            np.concatenate([b["line_center"] + o
+                            for b, o in zip(blocks, node_off)]),
+            b_cap, slabs))
     else:
         b_cap = 0
-        line_src = line_dst = line_center = np.zeros((1, 0), dtype=np.int32)
+        line_src = bond_center = np.zeros((1, 0), dtype=np.int32)
         line_mask = np.zeros((1, 0), dtype=bool)
         bm_edge = bm_bond = np.zeros((1, 0), dtype=np.int32)
         bm_mask = np.zeros((1, 0), dtype=bool)
@@ -350,9 +352,8 @@ def pack_structures(
         lattice=np.eye(3, dtype=dtype),
         n_total_nodes=np.int32(n_tot),
         line_src=line_src,
-        line_dst=line_dst,
         line_mask=line_mask,
-        line_center=line_center,
+        bond_center=bond_center,
         bond_map_edge=bm_edge,
         bond_map_bond=bm_bond,
         bond_map_mask=bm_mask,
@@ -747,14 +748,15 @@ def pack_structures_mesh(
             default=0))
         if use_bond_graph:
             _need("bonds", max(int(m[-1]) for m in mplan.bond_markers))
-            _need("lines", max(len(x) for x in mplan.line_src))
+            _need(LINE_SLOTS, line_slots_needed(mplan.line_dst))
             _need("bond_map", max(len(x) for x in mplan.bond_mapping_edge))
             _need("bond_halo", max(
                 (len(v) for d in mplan.bond_halo_send for v in d.values()),
                 default=0))
-    fixed = FixedCaps(
-        {name: (caps.get(name, need) if need else 0)
-         for name, need in needs.items()}, fallback=caps)
+    # ``lines``: the shards' in-line tables, the batch's largest in-degree
+    # over the bond rows a shard computes, in whole slabs of the frozen b_cap
+    slots = needs.pop(LINE_SLOTS, 0)
+    fixed = FixedCaps(freeze_caps(caps, needs, slots), fallback=caps)
 
     graphs = []
     for mplan, nl_shim, species, _slots, _lay in merged:
@@ -828,9 +830,8 @@ def pack_structures_mesh(
         lattice=np.eye(3, dtype=dtype),
         n_total_nodes=np.int32(sum(it["n"] for it in items)),
         line_src=cat0("line_src"),
-        line_dst=cat0("line_dst"),
         line_mask=cat0("line_mask"),
-        line_center=cat0("line_center"),
+        bond_center=cat0("bond_center"),
         bond_map_edge=cat0("bond_map_edge"),
         bond_map_bond=cat0("bond_map_bond"),
         bond_map_mask=cat0("bond_map_mask"),
@@ -979,4 +980,5 @@ def packed_stats(graph: PartitionedGraph, n_real_structures: int) -> dict:
         stats["halo_send_per_part"] = [int(x) for x in send]
     if graph.has_bond_graph:
         stats["n_lines"] = int(np.asarray(graph.line_mask).sum())
+        stats.update(line_table_stats(graph))
     return stats

@@ -58,6 +58,33 @@ def geometric_bucket(n: int, base: int = 128, growth: float = 2.0 ** 0.5,
     return ((int(math.ceil(rung)) + multiple - 1) // multiple) * multiple
 
 
+# key of a structure's largest in-degree in a dict of per-structure needs
+# (train/data.structure_needs): a census number beside the capacities' names,
+# not a capacity (the ``lines`` capacity is frozen from it)
+LINE_SLOTS = "line_slots"
+
+
+def line_table_cap(policy, slots_needed: int, bond_rows: int,
+                   b_cap: int) -> int:
+    """The capacity ``lines`` of a slot-major in-line table
+    (``partition/graph.line_table``), in whole slabs: the table is
+    ``cap // b_cap`` slabs of ``b_cap`` slots, and has to hold
+    ``slots_needed`` (the largest number of in-lines of one bond).
+
+    Asked for as ``slots_needed * bond_rows`` (the bond rows the partition
+    computes), which a mix that fixes both capacities holds to be enough;
+    where the policy's answer divides to fewer slabs (``b_cap`` rounded
+    further up than ``lines``) it is asked again for whole slabs. Every
+    policy here rounds ``bonds`` to a multiple of 128 rows: a slab is then
+    whole (8, 128) tiles and the ``(slabs, b_cap, C)`` view of a table's
+    rows is a bitcast on the chip. Keep ``multiple`` a multiple of 128.
+    """
+    cap = policy.get("lines", slots_needed * bond_rows)
+    if cap < slots_needed * b_cap:
+        cap = policy.get("lines", slots_needed * b_cap)
+    return cap - cap % b_cap if b_cap else 0
+
+
 class FixedCaps:
     """Capacity policy that returns PRECOMPUTED values, ignoring ``needed``.
 
@@ -125,13 +152,27 @@ def fixed_caps_for_batches(per_structure_needs, batch_size: int,
     names = set()
     for need in per_structure_needs:
         names.update(need)
-    caps = {}
-    for name in sorted(names):
+    worst = {}
+    for name in names - {LINE_SLOTS}:
         vals = sorted((int(n.get(name, 0)) for n in per_structure_needs),
                       reverse=True)
-        worst = sum(vals[:batch_size])
-        caps[name] = policy.get(name, worst) if worst else 0
-    return FixedCaps(caps, fallback=policy)
+        worst[name] = sum(vals[:batch_size])
+    slots = max(int(n.get(LINE_SLOTS, 0)) for n in per_structure_needs)
+    return FixedCaps(freeze_caps(policy, worst, slots), fallback=policy)
+
+
+def freeze_caps(policy, worst: dict, line_slots: int = 0) -> dict:
+    """Quantize the worst need of each capacity ONCE through ``policy``
+    (0 stays 0). With ``line_slots`` (the largest in-degree any pack can
+    hold) ``lines`` is the in-line table's capacity over the frozen
+    ``bonds`` (:func:`line_table_cap`), not the sum of live lines."""
+    caps = {name: (policy.get(name, need) if need else 0)
+            for name, need in sorted(worst.items())
+            if not (name == "lines" and line_slots)}
+    if line_slots:
+        caps["lines"] = line_table_cap(
+            policy, line_slots, worst.get("bond_map", 0), caps["bonds"])
+    return caps
 
 
 class CapacityPolicy:

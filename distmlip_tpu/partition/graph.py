@@ -17,7 +17,7 @@ from typing import Any
 import jax
 import numpy as np
 
-from .capacity import CapacityPolicy
+from .capacity import CapacityPolicy, line_table_cap
 from .plan import PartitionPlan
 
 _default_caps = CapacityPolicy()
@@ -39,9 +39,8 @@ _default_caps = CapacityPolicy()
         "halo_recv_idx",
         "lattice",
         "line_src",
-        "line_dst",
         "line_mask",
-        "line_center",
+        "bond_center",
         "bond_map_edge",
         "bond_map_bond",
         "bond_map_mask",
@@ -90,10 +89,15 @@ class PartitionedGraph:
     n_total_nodes: Any      # () int32 — true number of atoms in the system
 
     # --- bond graph (present iff has_bond_graph; else zero-size arrays) ---
-    line_src: Any           # (P, L_cap) int32 — bond-node local ids
-    line_dst: Any
-    line_mask: Any
-    line_center: Any        # (P, L_cap) int32 — atom local id of the angle center
+    # The lines are a slot-major IN-LINE TABLE (:func:`line_table`): slot
+    # ``k * b_cap + b`` holds the k-th line INTO bond row ``b``, so a line's
+    # destination bond and its centre atom are functions of its position
+    # (``LocalGraph.at_line_dst`` / ``sum_to_line_dst``) and only the source
+    # is an index array. ``K = line_src.shape[-1] // b_cap`` slabs.
+    line_src: Any           # (P, K * b_cap) int32 — bond-node local ids
+    line_mask: Any          # (P, K * b_cap) bool — pad slots False
+    bond_center: Any        # (P, b_cap) int32 — atom local id of each bond
+    #                         row's source atom, the centre of its in-lines
     bond_map_edge: Any      # (P, M_cap) int32 — local edge id per owned bond node
     bond_map_bond: Any      # (P, M_cap) int32
     bond_map_mask: Any
@@ -234,6 +238,47 @@ def expand_shift_tables(tbl, used_shifts, all_shifts, fill):
     return out
 
 
+def line_slots_needed(line_dst_lists) -> int:
+    """The largest number of in-lines of one bond over the given per-
+    partition ``line_dst`` lists: the slabs an in-line table needs."""
+    return max((int(np.bincount(np.asarray(x, np.int64)).max())
+                for x in line_dst_lists if len(x)), default=0)
+
+
+def line_table(line_src, line_dst, line_center, b_cap: int, slabs: int):
+    """One partition's lines as a slot-major in-line table.
+
+    From the line list ``(line_src, line_dst, line_center)`` in any order
+    (bond-node local ids, and the centre atom's local id): the lines into
+    bond row ``b`` take slots ``0 .. n_b - 1`` of that row in their stable
+    dst-sorted order, and slot ``k`` of row ``b`` is entry ``k * b_cap + b``.
+    Returns ``(line_src, line_mask, bond_center)``: ``(slabs * b_cap,)``
+    source ids and live-slot mask (a pad slot is masked and points at its
+    own row, which is in bounds), and the ``(b_cap,)`` centre atom of each
+    row's in-lines (a function of the destination bond alone: its source
+    atom; 0 for a row with none).
+    """
+    line_dst = np.asarray(line_dst, np.int64)
+    order = np.argsort(line_dst, kind="stable")
+    dst = line_dst[order]
+    rank = np.arange(len(dst)) - np.searchsorted(dst, dst, side="left")
+    if len(dst) and (int(rank.max()) >= slabs or int(dst[-1]) >= b_cap):
+        raise ValueError(
+            f"in-line table of {slabs} slabs x {b_cap} rows cannot hold "
+            f"{int(rank.max()) + 1} lines into one bond / bond row "
+            f"{int(dst[-1])}")
+    slot = rank * b_cap + dst
+    src = np.tile(np.arange(b_cap, dtype=np.int32), slabs)
+    mask = np.zeros(slabs * b_cap, dtype=bool)
+    src[slot] = np.asarray(line_src)[order]
+    mask[slot] = True
+    center = np.zeros(b_cap, dtype=np.int32)
+    center[dst] = np.asarray(line_center)[order]
+    assert np.array_equal(center[dst], np.asarray(line_center)[order]), \
+        "a bond's in-lines must share their centre atom"
+    return src, mask, center
+
+
 def build_partitioned_graph(
     plan: PartitionPlan,
     nl,
@@ -346,25 +391,23 @@ def build_partitioned_graph(
 
     if plan.has_bond_graph:
         b_cap = caps.get("bonds", max(int(m[-1]) for m in plan.bond_markers))
-        l_cap = caps.get("lines", max(len(x) for x in plan.line_src))
         m_cap = caps.get("bond_map", max(len(x) for x in plan.bond_mapping_edge))
-        line_src = np.zeros((P, l_cap), dtype=np.int32)
-        line_dst = np.zeros((P, l_cap), dtype=np.int32)
-        line_mask = np.zeros((P, l_cap), dtype=bool)
-        line_center = np.zeros((P, l_cap), dtype=np.int32)
+        # ``lines`` counts table slots: what the largest in-degree takes
+        # over the bond rows a partition computes
+        slabs = line_table_cap(
+            caps, line_slots_needed(plan.line_dst),
+            max(len(x) for x in plan.bond_mapping_edge), b_cap
+        ) // max(b_cap, 1)
+        line_src = np.zeros((P, slabs * b_cap), dtype=np.int32)
+        line_mask = np.zeros((P, slabs * b_cap), dtype=bool)
+        bond_center = np.zeros((P, b_cap), dtype=np.int32)
         bm_edge = np.zeros((P, m_cap), dtype=np.int32)
         bm_bond = np.zeros((P, m_cap), dtype=np.int32)
         bm_mask = np.zeros((P, m_cap), dtype=bool)
         for p in range(P):
-            # line edges sorted by dst bond node for sorted segment sums
-            lperm = np.argsort(plan.line_dst[p], kind="stable")
-            nl_p = len(plan.line_src[p])
-            line_src[p, :nl_p] = plan.line_src[p][lperm]
-            line_dst[p, :nl_p] = plan.line_dst[p][lperm]
-            line_dst[p, nl_p:] = plan.line_dst[p][lperm][-1] if nl_p else 0
-            line_center[p, :nl_p] = plan.line_center_local[p][lperm]
-            line_mask[p, :nl_p] = True
-            assert np.all(np.diff(line_dst[p]) >= 0), "line_dst must be sorted"
+            line_src[p], line_mask[p], bond_center[p] = line_table(
+                plan.line_src[p], plan.line_dst[p],
+                plan.line_center_local[p], b_cap, slabs)
             nm = len(plan.bond_mapping_edge[p])
             bm_edge[p, :nm] = edge_perm_inv[p][plan.bond_mapping_edge[p]]
             bm_bond[p, :nm] = plan.bond_mapping_bond[p]
@@ -377,7 +420,7 @@ def build_partitioned_graph(
         all_shifts = tuple(sorted(set(shifts) | set(b_shifts)))
     else:
         b_cap = 0
-        line_src = line_dst = line_center = np.zeros((P, 0), dtype=np.int32)
+        line_src = bond_center = np.zeros((P, 0), dtype=np.int32)
         line_mask = np.zeros((P, 0), dtype=bool)
         bm_edge = bm_bond = np.zeros((P, 0), dtype=np.int32)
         bm_mask = np.zeros((P, 0), dtype=bool)
@@ -416,9 +459,8 @@ def build_partitioned_graph(
         lattice=np.asarray(lattice, dtype=dtype),
         n_total_nodes=np.int32(len(plan.node_part)),
         line_src=line_src,
-        line_dst=line_dst,
         line_mask=line_mask,
-        line_center=line_center,
+        bond_center=bond_center,
         bond_map_edge=bm_edge,
         bond_map_bond=bm_bond,
         bond_map_mask=bm_mask,
@@ -522,6 +564,18 @@ def device_refresh_graph(static, arrays, graph, positions):
                                   positions)
 
 
+def line_table_stats(graph: PartitionedGraph) -> dict:
+    """How far the in-line table engages: ``line_slots`` (its slabs, K) and
+    ``line_table_fill``, live lines over K x the bond rows computed (1.0
+    where every bond has K in-lines; the rest is dense line work on pad
+    slots)."""
+    slabs = graph.line_src.shape[-1] // graph.b_cap if graph.b_cap else 0
+    rows = int(np.asarray(graph.bond_map_mask).sum())
+    live = int(np.asarray(graph.line_mask).sum())
+    return {"line_slots": slabs,
+            "line_table_fill": live / (slabs * rows) if slabs * rows else 0.0}
+
+
 def graph_build_stats(graph: PartitionedGraph) -> dict:
     """Shape/occupancy/halo-volume stats from a host-side (numpy) graph.
 
@@ -570,4 +624,5 @@ def graph_build_stats(graph: PartitionedGraph) -> dict:
         # total live line-graph edges (angle terms) — the FLOP model's
         # third graph dimension
         stats["n_lines"] = int(lines.sum())
+        stats.update(line_table_stats(graph))
     return stats

@@ -51,6 +51,7 @@ import numpy as np
 from ..neighbors import neighbor_list
 from ..partition import (BucketPolicy, bucket_key, fixed_caps_for_batches,
                          pack_structures)
+from ..partition.graph import line_slots_needed
 from ..partition.partitioner import build_plan
 from .packing import CostCensus, assign_tiers, plan_epoch, tier_caps
 
@@ -105,7 +106,10 @@ def structure_needs(atoms_list, cutoff: float, bond_cutoff: float = 0.0,
             need.update(
                 bonds=int(plan.bond_markers[0][-1]),
                 lines=len(plan.line_src[0]),
-                bond_map=len(plan.bond_mapping_edge[0]))
+                bond_map=len(plan.bond_mapping_edge[0]),
+                # the largest in-degree: what the pack's in-line table
+                # needs of slabs (partition/capacity.freeze_caps)
+                line_slots=line_slots_needed(plan.line_dst))
         needs.append(need)
     return needs
 

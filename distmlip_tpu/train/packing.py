@@ -42,6 +42,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..partition import BucketPolicy, FixedCaps, slot_waste_frac
+from ..partition.capacity import LINE_SLOTS, freeze_caps
 from ..utils.flops import model_flop_estimate
 
 # the padded dimensions whose slots carry per-row compute — identical to
@@ -268,18 +269,16 @@ def tier_caps(needs, tier_of, micro_batch_size: int, batch_parts: int = 1,
         v = costs[order]
         # first index of each equal-cost run (ties collapse upward)
         starts = np.searchsorted(-v, -v, side="left")
-        caps_t = {}
-        for name in sorted(names):
+        worst = {}
+        for name in sorted(names - {LINE_SLOTS}):
             vals = np.array([int(needs[i].get(name, 0)) for i in order],
                             dtype=np.int64)
-            if not vals.any():
-                caps_t[name] = 0
-                continue
             sm = np.maximum.accumulate(vals[::-1])[::-1]
             m_bound = sm[starts]
-            worst = int(sum(m_bound[min(r * n_bins, n_t - 1)]
-                            for r in range(per_shard)))
-            caps_t[name] = policy.get(name, worst)
+            worst[name] = int(sum(m_bound[min(r * n_bins, n_t - 1)]
+                                  for r in range(per_shard)))
+        caps_t = freeze_caps(policy, worst, max(
+            int(needs[i].get(LINE_SLOTS, 0)) for i in order))
         caps[t] = FixedCaps(caps_t, fallback=policy)
     return caps
 

@@ -104,13 +104,33 @@ def test_three_body_work_does_not_read_as_atom_graph_work(nparts):
     # would feed nothing), core and gate each
     per = lambda st: sum(stage_of(s.stack) == st for s in line)
     assert per("line_message") == 2 * per("angle_update") > 0
-    # segment sums onto bonds are line work, onto atoms aggregate work
+    # sums onto atoms are scatter-adds, aggregate work; the sum onto bonds is
+    # a sum over the slabs of the in-line table: line work, and no scatter
     # (the remaps' own scatters are bond_map's, the exchange's halo's)
-    adds = {stage_of(s.stack) for s in sites
-            if s.primitive in ("scatter-add", "scatter_add")
-            and "transpose" not in s.stack and "model_energy" in s.stack}
-    assert {"edge_aggregate", "line_message"} <= adds
-    assert adds <= {"edge_aggregate", "line_message", "bond_map", "halo"}
+    forward = [s for s in sites if "transpose" not in s.stack
+               and "model_energy" in s.stack]
+    adds = {stage_of(s.stack) for s in forward
+            if s.primitive in ("scatter-add", "scatter_add")}
+    assert "edge_aggregate" in adds
+    assert adds <= {"edge_aggregate", "bond_map", "halo"}
+    rows = lambda v: v.aval.shape[0]
+    table = [s for s in forward if s.primitive == "custom_vjp_call"]
+    slab_sums = [s for s in table
+                 if rows(s.eqn.outvars[0]) < rows(s.eqn.invars[0])]
+    assert len(slab_sums) == 2          # one a bond conv
+    assert {stage_of(s.stack) for s in slab_sums} == {"line_message"}
+    # of the lines' three addresses only the source is an index: one gather
+    # a reader of ``b[line_src]`` (the 4-wide geometry rows, the rows in two
+    # bond convs and one angle update), none by destination or centre:
+    # those are repeats of the bond rows over the table's slabs
+    n_lines = rows(slab_sums[0].eqn.invars[0])
+    by_line = [s for s in forward if s.primitive == "gather"
+               and rows(s.eqn.outvars[0]) == n_lines]
+    assert len(by_line) == 1 + 2 + 1, [s.stack for s in by_line]
+    repeats = [s for s in table if rows(s.eqn.outvars[0]) == n_lines]
+    assert len(repeats) == 1 + 2 * 2 + 2
+    assert {stage_of(s.stack) for s in repeats} == {
+        "line_geometry", "line_message", "angle_update"}
 
 
 def test_last_stats_carry_real_bonds_and_lines_per_partition():

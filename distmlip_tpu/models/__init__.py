@@ -5,6 +5,7 @@ from .mace import MACE, MACEConfig
 from .escn import ESCN, ESCNConfig
 from .escn_md import ESCNMD, ESCNMDConfig
 from .nequip import NequIP, NequIPConfig
+from .dimenet import DimeNetPP, DimeNetPPConfig
 
 __all__ = [
     "PairPotential", "PairConfig",
@@ -14,4 +15,5 @@ __all__ = [
     "ESCN", "ESCNConfig",
     "ESCNMD", "ESCNMDConfig",
     "NequIP", "NequIPConfig",
+    "DimeNetPP", "DimeNetPPConfig",
 ]

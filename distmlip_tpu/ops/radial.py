@@ -7,11 +7,15 @@ The bases used across the model zoo:
   - polynomial_cutoff     (MACE/CHGNet smooth envelope)
   - cosine_cutoff         (Behler-style envelope)
   - xplor_cutoff          (NequIP/SevenNet switching envelope)
+  - dimenet_envelope, spherical_bessel_table, spherical_bessel_jl,
+    legendre_rows         (DimeNet's radial and spherical bases)
 
 All functions are smooth at the cutoff so forces stay continuous.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax.numpy as jnp
 import numpy as np
@@ -111,3 +115,89 @@ def xplor_cutoff(d, cutoff: float, cutoff_on: float):
     switch = ((rc2 - d2) ** 2 * (rc2 + 2.0 * d2 - 3.0 * on2)
               / (rc2 - on2) ** 3)
     return jnp.where(d < cutoff_on, 1.0, jnp.where(d < cutoff, switch, 0.0))
+
+
+# ---- DimeNet (arXiv:2003.03123, section 4; DimeNet++ arXiv:2011.14115) ----
+
+def dimenet_envelope(x, exponent: int = 5):
+    """DimeNet's envelope of ``x = d / c``: ``1/x + a x^(p-1) + b x^p +
+    c x^(p+1)`` with ``p = exponent + 1`` (a = -(p+1)(p+2)/2, b = p(p+2),
+    c = -p(p+1)/2), zero from ``x = 1`` on, where its value and slope
+    vanish. ``x`` must be positive (the caller gives padded rows x = 1)."""
+    p = exponent + 1
+    a = -(p + 1) * (p + 2) / 2.0
+    b = p * (p + 2.0)
+    c = -p * (p + 1) / 2.0
+    xp = x ** (p - 1)
+    u = 1.0 / x + a * xp + b * xp * x + c * xp * x * x
+    return jnp.where(x < 1.0, u, 0.0)
+
+
+def _spherical_jn64(l: int, z):
+    """j_l in float64 numpy by the upward recurrence (host tables only)."""
+    j_prev, j = np.sin(z) / z, np.sin(z) / z ** 2 - np.cos(z) / z
+    if l == 0:
+        return j_prev
+    for k in range(1, l):
+        j_prev, j = j, (2 * k + 1) / z * j - j_prev
+    return j
+
+
+@functools.lru_cache(maxsize=None)
+def spherical_bessel_table(num_spherical: int, num_radial: int):
+    """Host float64 ``(roots, norms)``, each ``(num_spherical,
+    num_radial)``: ``z_ln`` the n-th positive root of ``j_l`` and ``N_ln =
+    sqrt(2) / |j_{l+1}(z_ln)|``, so that ``N_ln j_l(z_ln x)`` has unit
+    norm on [0, 1] with weight x^2. The roots of ``j_l`` interlace those of
+    ``j_{l-1}`` (the roots of j_0 are n pi): each is found by bisection
+    between two neighbouring roots of the order below."""
+    count = num_radial + num_spherical
+    roots = [np.pi * np.arange(1, count + 1, dtype=np.float64)]
+    for l in range(1, num_spherical):
+        below = roots[-1]
+        found = []
+        for lo, hi in zip(below[:-1], below[1:]):
+            f_lo = _spherical_jn64(l, lo)
+            for _ in range(200):
+                mid = 0.5 * (lo + hi)
+                f_mid = _spherical_jn64(l, mid)
+                if np.sign(f_mid) == np.sign(f_lo):
+                    lo, f_lo = mid, f_mid
+                else:
+                    hi = mid
+            found.append(0.5 * (lo + hi))
+        roots.append(np.array(found))
+    z = np.stack([r[:num_radial] for r in roots])
+    norms = np.sqrt(2.0) / np.abs(np.stack(
+        [_spherical_jn64(l + 1, z[l]) for l in range(num_spherical)]))
+    z.flags.writeable = norms.flags.writeable = False  # shared by the cache
+    return z, norms
+
+
+def spherical_bessel_jl(num_spherical: int, z):
+    """``[j_0(z), ..., j_{L-1}(z)]`` for ``z`` of shape ``(..., L, n)``
+    (row l of the second last axis gives j_l): j_0 and j_1 in closed form,
+    the rest by the upward recurrence ``j_{l+1} = (2l+1)/z j_l - j_{l-1}``.
+    In float32 it stays within 1e-6 of the largest value of each row over
+    arguments ``z_ln x``, x in [0.5, 1] (a bond at least half the cutoff
+    long); below x = 0.2 the recurrence loses digits, which no bond of a
+    solid reaches."""
+    s, c = jnp.sin(z), jnp.cos(z)
+    rows = [s / z, s / (z * z) - c / z]
+    out = [rows[0][..., 0, :]]
+    j_prev, j = rows
+    for l in range(1, num_spherical):
+        out.append(j[..., l, :])
+        j_prev, j = j, (2 * l + 1) / z * j - j_prev
+    return jnp.stack(out[:num_spherical], axis=-2)
+
+
+def legendre_rows(cos, num_spherical: int):
+    """``Y_l0 = sqrt((2l + 1) / 4 pi) P_l(cos)`` for l < ``num_spherical``,
+    ``(..., L)``, by Bonnet's recurrence on ``cos`` itself: no arccos, whose
+    slope diverges at the collinear triplets of a lattice."""
+    p = [jnp.ones_like(cos), cos]
+    for l in range(1, num_spherical - 1):
+        p.append(((2 * l + 1) * cos * p[l] - l * p[l - 1]) / (l + 1))
+    scale = np.sqrt((2 * np.arange(num_spherical) + 1) / (4 * np.pi))
+    return jnp.stack(p[:num_spherical], axis=-1) * scale.astype(np.float32)

@@ -110,6 +110,11 @@ class LocalGraph:
     ``bond_center`` (``(b_cap,)``, one atom a bond row) read through
     ``at_line_dst``. Only ``line_src`` is an index array. Pad slots are
     masked and in bounds; halo and padded bond rows have no live slot.
+    The bonds may be short bonds under a cutoff of their own (CHGNet's
+    3.0 A of a 6.0 A graph, 11 slabs) or every edge of the graph
+    (DimeNet++, ``bond_cutoff == cutoff``: K is then the largest in-degree
+    less one, 51 slabs in fcc at 5.5 A): :meth:`in_line_sum` scans such a
+    table one slab at a time.
     """
 
     axis_name: str | None
@@ -393,6 +398,31 @@ class LocalGraph:
         ``(b_cap, ...)``: a sum over the slabs, no scatter; ``mask`` zeroes
         rows first. Halo and padded bond rows read zero."""
         return slab_sum(line_rows, self.b_cap, mask)
+
+    def in_line_sum(self, line_fn, src_rows, dst_rows, width: int):
+        """Sum over every bond row's in-lines of ``line_fn(src, *dst)``,
+        ``(b_cap, width)`` float32: ``src`` the rows of ``src_rows``
+        (``(b_cap, F)``) at the lines' source bonds, ``dst`` the
+        ``dst_rows`` (each ``(b_cap, ...)``) of their destination bonds,
+        which within a slab are the rows themselves. One slab of the table
+        a step of a scan with a ``(b_cap, width)`` carry: the only index
+        traffic is the gather of ``src_rows`` (its transpose a scatter-add
+        of one slab's rows), the sum onto the destinations an add, and no
+        array over all slots but ``line_src`` / ``line_mask`` exists. The
+        body is checkpointed (``ops/chunk.remat_wrap``), so the backward
+        keeps a slab's rows for one slab only. Halo and padded bond rows
+        read zero."""
+        K, rows = self.line_slots, self.b_cap
+        xs = (self.line_src.reshape(K, rows), self.line_mask.reshape(K, rows))
+
+        def body(acc, slab):
+            src, live = slab
+            out = line_fn(src_rows[src], *dst_rows)
+            return acc + jnp.where(live[:, None], out, 0).astype(acc.dtype), \
+                None
+
+        acc0 = jnp.zeros((rows, width), jnp.float32)
+        return scan_accumulate(body, acc0, xs, remat=True)
 
     # ---- bond-graph index remaps (reference dist.py:635-702 analogue) ----
     def edge_to_bond(self, edge_feats, bond_feats):

@@ -78,6 +78,12 @@ def line_table_cap(policy, slots_needed: int, bond_rows: int,
     policy here rounds ``bonds`` to a multiple of 128 rows: a slab is then
     whole (8, 128) tiles and the ``(slabs, b_cap, C)`` view of a table's
     rows is a bitcast on the chip. Keep ``multiple`` a multiple of 128.
+
+    The bonds need not be short: with a bond cutoff equal to the cutoff
+    (DimeNet++) every built edge is a bond and ``slots_needed`` is the
+    largest in-degree less one (51 in the fcc lattice at 5.5 A, against 11
+    for CHGNet's 3.5 A bonds), so the table is a line graph over every
+    edge, some 18 times CHGNet's.
     """
     cap = policy.get("lines", slots_needed * bond_rows)
     if cap < slots_needed * b_cap:

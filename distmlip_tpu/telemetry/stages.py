@@ -36,6 +36,11 @@ STAGES = (
     "line_message",     # three gathers, gated MLP, sum onto the dst bond
     "angle_update",     # the angle feature of every line
     "bond_map",         # edge rows onto bond nodes and back
+    # directional message passing (DimeNet++): the hidden state lives on
+    # every bond, triplets k -> j -> i are the lines into bond j -> i
+    "triplet_basis",    # line geometry, cos theta, j_l, P_l, envelope, rows
+    "triplet_message",  # the slab scan: s[line_src] x basis, summed onto bonds
+    "edge_update",      # embedding block and every per-bond linear
 )
 # scopes parallel/halo.py has carried since PR 1; they ARE the halo stage,
 # and its two index remaps the bond_map stage

@@ -18,9 +18,10 @@ from distmlip_tpu.telemetry.stages import stage_of
 
 BOND_GRAPH = ("line_geometry", "line_message", "angle_update", "bond_map")
 # what CHGNet has no code for: a chunked scan, Wigner blocks, experts,
-# rank-2 node products, an equivariant gate, ZBL
+# rank-2 node products, an equivariant gate, ZBL, DimeNet++'s triplets
 NOT_CHGNET = {"edge_gather", "edge_rotation", "expert_mix", "node_tensor",
-              "node_gate", "pair_repulsion"}
+              "node_gate", "pair_repulsion", "triplet_basis",
+              "triplet_message", "edge_update"}
 
 
 def config(**kw):

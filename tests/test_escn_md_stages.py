@@ -90,11 +90,12 @@ def test_every_equation_of_the_model_carries_a_stage(nparts, kernels):
                    if stage_of(s.stack) is None})
     assert not bare, bare[:10]
     seen = {stage_of(s.stack) for s in model}
-    # ESCNMD has no pair repulsion, no bond graph and no NequIP gate; one
-    # partition has no halo
+    # ESCNMD has no pair repulsion, no bond graph, no NequIP gate and no
+    # DimeNet++ triplets; one partition has no halo
     expected = set(STAGES) - {
         "pair_repulsion", "line_geometry", "line_message", "angle_update",
-        "bond_map", "node_gate"} - ({"halo"} if nparts == 1 else set())
+        "bond_map", "node_gate", "triplet_basis", "triplet_message",
+        "edge_update"} - ({"halo"} if nparts == 1 else set())
     assert expected <= seen, expected - seen
     if kernels == "interpret":
         calls = [s for s in model if s.primitive == "pallas_call"]

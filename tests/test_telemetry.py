@@ -411,8 +411,10 @@ TOY = {
 ESCN_ONLY = {"edge_rotation", "expert_mix"}
 BOND_GRAPH = {"line_geometry", "line_message", "angle_update", "bond_map"}
 NEQUIP_ONLY = {"node_gate"}
-NOT_IN = {"mace": ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY,
-          "tensornet": ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY | {
+# DimeNet++'s three (tests/test_dimenet_stages.py)
+DIMENET_ONLY = {"triplet_basis", "triplet_message", "edge_update"}
+NOT_IN = {"mace": ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY | DIMENET_ONLY,
+          "tensornet": ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY | DIMENET_ONLY | {
               "edge_gather", "pair_repulsion"}}
 
 
@@ -635,7 +637,8 @@ def test_stage_table_of_a_compiled_step_outlives_the_potential(
     # one partition: the halo has no work
     assert all(by_stage[s] for s in STAGES
                if s != "halo"
-               and s not in ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY)
+               and s not in ESCN_ONLY | BOND_GRAPH | NEQUIP_ONLY
+               | DIMENET_ONLY)
     passes = {r["pass"] for r in rows}
     assert passes == {"forward", "backward", "recompute"}
     assert any(r["pass"] != "forward" for r in by_stage["edge_aggregate"])

@@ -342,6 +342,7 @@ def _kernel_cases(seed: int):
     import jax
     import jax.numpy as jnp
 
+    from distmlip_tpu.kernels.dispatch import repeat_edge_block
     from distmlip_tpu.kernels.segment import (pallas_edge_aggregate,
                                               pallas_segment_sum,
                                               pallas_segment_sum_into)
@@ -483,9 +484,27 @@ def _kernel_cases(seed: int):
                     indices_are_sorted=True).reshape(a.shape)),
                 (*accs, *rows))
 
+    def segment_repeat(dtype):
+        # the transpose of DimeNet++'s repeat over a slab of dimenet-pp-md-
+        # 1c's in-line scan: 394,368 bond rows 128 lanes wide (the 124-wide
+        # float32 source row on whole lane tiles) summed onto the two rows
+        # of their 8,448 centre atoms (46 to 49 bonds a centre, one in 47
+        # on the second row), in the dispatcher's edge blocks
+        e, n = 394368, 2 * 8448
+        deg = rng.integers(44, 50, 8192)
+        ids = np.repeat(np.arange(8192), deg)[:e]
+        ids = 2 * np.sort(np.concatenate([np.zeros(e - len(ids), np.int64),
+                                          ids])) + (rng.random(e) < 1 / 47)
+        ids = jnp.asarray(ids.astype(np.int32))
+        return (lambda d: pallas_segment_sum(d, ids, n,
+                                             edge_blk=repeat_edge_block(e)),
+                lambda d: masked_segment_sum(d, ids, n),
+                (normal((e, 128), dtype),))
+
     return (("segment_sum", segment_sum), ("edge_aggregate", edge_aggregate),
             ("so2_conv", so2_conv), ("wigner_rotate", wigner_rotate),
-            ("segment_sum_into", segment_sum_into))
+            ("segment_sum_into", segment_sum_into),
+            ("segment_repeat", segment_repeat))
 
 
 def phase_kernels(bands: dict, seed: int = 0) -> list:

@@ -16,7 +16,9 @@ never through :mod:`segment`/:mod:`so3` directly. The dispatcher owns
 - **autodiff**: ``pallas_call`` has no transpose rule, so each fused op
   carries a custom VJP. ``fused_segment_sum``'s backward is the sorted
   gather ``g[segment_ids] * mask`` (``fused_segment_sum_into``'s too, the
-  carry's cotangent passing through); ``fused_edge_aggregate``'s backward
+  carry's cotangent passing through), and ``fused_segment_repeat``, the
+  sorted gather, has the kernel's sum for its backward;
+  ``fused_edge_aggregate``'s backward
   re-runs the per-edge compute in bounded chunks (a ``lax.scan``) so the
   backward pass ALSO never materializes the ``(E, width)`` message
   cotangent; ``fused_so2_conv``'s backward is the VJP of the XLA
@@ -34,7 +36,7 @@ from __future__ import annotations
 import math
 import os
 import threading
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any
@@ -101,6 +103,12 @@ TPU_DEFAULT_MODE = {
     # 1152); max rel err 4.1e-7 (float32), 2.8e-3 (bfloat16) — chip run,
     # PR 33; timings: PERF.md section 6
     "segment_sum_into": "pallas",
+    # the transpose of DimeNet++'s repeat over a slab: compiled on a TPU v5
+    # lite at (394368, 128) float32 rows into 8448 sorted centres, 1.82 ms
+    # a call in edge blocks of 1664 against 4.16 for XLA's sorted scatter
+    # (2.04 / 4.06 into two rows a centre); max rel err 6.5e-6 (float32)
+    # — chip run on a TPU v5e; PERF.md section 6
+    "segment_repeat": "pallas",
 }
 
 
@@ -280,32 +288,107 @@ def fused_segment_sum(data, segment_ids, num_segments: int, mask=None,
                                 jnp.result_type(data))(data, segment_ids, mask)
 
 
-def _segment_sum_vjp(num_segments: int, interpret: bool, dtype):
+def _segment_sum_vjp(num_segments: int, interpret: bool, dtype,
+                     stage: str | None = "edge_aggregate",
+                     edge_blk: int | None = None):
     # shape/dtype are trace-time statics: they ride the factory closure,
     # NOT the custom_vjp residuals (residuals must be valid JAX types —
     # they become scan carries when the call sits inside a scanned body)
     @jax.custom_vjp
     def f(d, ids, m):
         return pallas_segment_sum(d, ids, num_segments, mask=m,
-                                  interpret=interpret)
+                                  edge_blk=edge_blk, interpret=interpret)
 
     def fwd(d, ids, m):
         return f(d, ids, m), (ids, m)
 
     def bwd(res, g):
-        return _segment_sum_bwd(*res, g, dtype)
+        return _segment_sum_bwd(*res, g, dtype, stage)
 
     f.defvjp(fwd, bwd)
     return f
 
 
-def _segment_sum_bwd(ids, m, g, dtype):
+def _segment_sum_bwd(ids, m, g, dtype, stage: str | None = "edge_aggregate"):
     """Cotangents ``(rows, ids, mask)`` of a masked segment sum: its
-    transpose is the sorted per-edge gather; ids and mask get float0."""
-    with scope("edge_aggregate"):
+    transpose is the sorted per-edge gather; ids and mask get float0.
+    ``stage`` is the scope the gather opens (None: the caller's)."""
+    with _scoped(stage):
         gd = jnp.take(g, ids, axis=0)
         m_ct = None if m is None else _int_zero(m)
         return (_mask_mul(gd, m).astype(dtype), _int_zero(ids), m_ct)
+
+
+def _scoped(stage: str | None):
+    return scope(stage) if stage else nullcontext()
+
+
+def lane_width(width: int, op: str, kernels=None) -> int:
+    """The row width ``op``'s kernel takes for rows ``width`` wide: the
+    next multiple of the 128 lanes where the kernel path engages (Mosaic
+    refuses blocks off the lane grid), ``width`` itself on the XLA path. A
+    caller that pads its rows once, before it gathers them, makes no padded
+    copy a call."""
+    if resolve_kernel_mode(kernels, op=op) == "xla":
+        return width
+    return -(-width // 128) * 128
+
+
+def fused_segment_repeat(rows, segment_ids, kernels=None):
+    """``rows[segment_ids]`` where the rows come in pairs and the members
+    of a list read a row of their pair in pair order (``rows``
+    ``(num_segments, W)``, ``segment_ids // 2`` nondecreasing: the kernel's
+    dst tiles, whole multiples of 8 rows, cut no pair). The transpose is
+    the segment sum of the members' cotangents onto their rows: on the
+    Pallas path the dst-tiled :func:`pallas_segment_sum` through a
+    ``custom_vjp`` (``W`` its :func:`lane_width`), elsewhere XLA's
+    scatter-add; half-precision rows add up in float32 and round once
+    either way. No scope of its own: the gather, the sum and, under a
+    second derivative, the sum's own transpose read under the caller's
+    stage."""
+    mode = resolve_kernel_mode(kernels, op="segment_repeat")
+    use = mode != "xla" and segment_ids.shape[0] > 0 and rows.shape[0] > 0
+    _count("segment_repeat", use)
+    half = str(rows.dtype) in ("bfloat16", "float16")
+    if not use:
+        f32 = rows.astype(jnp.float32) if half else rows
+        return f32[segment_ids].astype(rows.dtype)
+    # every traced operand explicit, statics in the closure: as in
+    # fused_segment_sum
+    return _segment_repeat_vjp(rows.shape[0], mode == "interpret",
+                               rows.dtype)(rows, segment_ids)
+
+
+def repeat_edge_block(n: int) -> int:
+    """The edge block of :func:`fused_segment_repeat`'s kernel over ``n``
+    member rows: the largest multiple of 128 that divides ``n``, up to
+    2048, so that no padded copy of the rows is made and the kernel takes
+    few, long steps; where that is under 384, 1024 rows (or ``n`` rounded
+    up to 128 where fewer) and a padded copy. On a v5e, ``(n, 128)``
+    float32 rows summed onto two rows of each of 8,448 centres: at 394,368
+    rows 1.97 ms a call in blocks of 1,664, 2.12 in 384, 2.54 in 1,024
+    padded, 2.96 in the kernel's default 256 padded, 3.05 in 128; at
+    394,624 rows (128 times a prime) 2.60 in 1,024 padded, 3.01 in 256,
+    3.07 in 128; XLA's sorted scatter-add 4.05-4.07 (PERF.md section 6)."""
+    best = next((b for b in range(2048, 0, -128) if n % b == 0), 0)
+    return best if best >= 384 else min(1024, -(-n // 128) * 128)
+
+
+def _segment_repeat_vjp(num_segments: int, interpret: bool, dtype):
+    @jax.custom_vjp
+    def f(rows, ids):
+        return jnp.take(rows, ids, axis=0)
+
+    def fwd(rows, ids):
+        return f(rows, ids), ids
+
+    def bwd(ids, g):
+        total = _segment_sum_vjp(num_segments, interpret, g.dtype, None,
+                                 repeat_edge_block(ids.shape[0]))(g, ids, None)
+        return total.astype(dtype), _int_zero(ids)
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
 def segment_sum_carry(num_segments: int, out_shape, dtype, kernels=None):

@@ -27,9 +27,11 @@ Where the work runs: the bonds are the graph's bond nodes with
 ``bond_cutoff == cutoff``, so every built edge is a bond and the in-line
 table (``LocalGraph``) is the triplet set, k != i by atom id as the
 partitioner joins it. The triplet sum is :meth:`LocalGraph.in_line_sum`, a
-scan over the table's slabs: per slab one gather of a 124-wide float32 row
-of the source bond ``[s_kj | R_kj | vector | length]``; the destination's
-rows are the slab's own. ``W_sbf1`` is applied per bond, not per triplet:
+scan over the table's slabs: per slab the 124-wide float32 row of the
+source bond ``[s_kj | R_kj | vector | length]``, read as two rows a centre
+atom repeated over the centre's bonds (its transpose a sum onto the
+centres, sorted); the destination's rows are the slab's own. ``W_sbf1`` is
+applied per bond, not per triplet:
 ``a_ln = rad_ln(d_kj) Y_l(theta)``, so ``W_sbf1 a = sum_l Y_l R_l`` with
 ``R_l = sum_n rad_ln W_sbf1[l, n]`` (``(b_cap, 7, 8)`` a block), which is
 the same sum in another order and leaves no basis over the slots. The sum

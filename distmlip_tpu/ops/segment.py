@@ -15,6 +15,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _HALF_DTYPES = ("bfloat16", "float16")
 
@@ -144,3 +145,31 @@ def slab_sum(data, num_segments: int, mask=None):
     if data.shape[0] == 0:
         return jnp.zeros((num_segments,) + data.shape[1:], data.dtype)
     return _slab_add(data, data.shape[0] // num_segments)
+
+
+# ---- permutations ----------------------------------------------------------
+
+@jax.custom_vjp
+def _permute(rows, order, inverse):
+    return rows[order]
+
+
+def _permute_fwd(rows, order, inverse):
+    return _permute(rows, order, inverse), (order, inverse)
+
+
+def _permute_bwd(res, g):
+    order, inverse = res
+    zero = lambda x: np.zeros(x.shape, jax.dtypes.float0)
+    return _permute(g, inverse, order), zero(order), zero(inverse)
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def permute_rows(rows, order, inverse):
+    """``rows[order]`` for a permutation ``order`` whose inverse is
+    ``inverse``: the transpose is the gather ``g[inverse]``, not the
+    scatter-add XLA makes of a gather's cotangent (``custom_vjp``, so a
+    derivative of any order stays a gather)."""
+    return _permute(rows, order, inverse)

@@ -29,10 +29,11 @@ from jax import lax
 
 from ..geometry import COORD_PRECISION
 from ..kernels.dispatch import (Gather, fused_edge_aggregate,
-                                fused_segment_sum, fused_segment_sum_into,
+                                fused_segment_repeat, fused_segment_sum,
+                                fused_segment_sum_into, lane_width,
                                 segment_sum_carry, segment_sum_result)
 from ..ops.chunk import chunk_layout, chunked, scan_accumulate, take_rows
-from ..ops.segment import slab_repeat, slab_sum
+from ..ops.segment import permute_rows, slab_repeat, slab_sum
 from ..telemetry import scope
 
 
@@ -103,7 +104,8 @@ class LocalGraph:
     ``b_cap`` bond rows (``partition/graph.line_table``): entry
     ``k * b_cap + b`` of ``line_src`` / ``line_mask`` (and of every per-line
     array a model makes) is the k-th line INTO bond row ``b``, with
-    ``line_slots`` slabs. A line's destination bond and its centre atom are
+    ``line_slots`` slabs; the live slots of a row are those below its
+    ``line_count``. A line's destination bond and its centre atom are
     therefore its position: :meth:`at_line_dst` (a repeat) and
     :meth:`sum_to_line_dst` (a sum over the slabs) are the only code that
     knows the order, each the other's transpose; the centre is
@@ -114,7 +116,9 @@ class LocalGraph:
     3.0 A of a 6.0 A graph, 11 slabs) or every edge of the graph
     (DimeNet++, ``bond_cutoff == cutoff``: K is then the largest in-degree
     less one, 51 slabs in fcc at 5.5 A): :meth:`in_line_sum` scans such a
-    table one slab at a time.
+    table one slab at a time, reading the same lines by centre atom
+    (``center_in``, ``bond_order`` / ``bond_rank``, ``redirect_bits``:
+    ``partition/graph.center_table``) and not ``line_src``.
     """
 
     axis_name: str | None
@@ -137,7 +141,13 @@ class LocalGraph:
     has_bond_graph: bool = False
     line_src: Any = None      # (line_slots * b_cap,) slot-major table
     line_mask: Any = None
+    line_count: Any = None    # (b_cap,)
     bond_center: Any = None   # (b_cap,)
+    # the lines by centre atom (see the class docstring)
+    center_in: Any = None     # (line_slots, n_cap, 2)
+    bond_order: Any = None    # (b_cap,)
+    bond_rank: Any = None     # (b_cap,)
+    redirect_bits: Any = None  # (ceil(line_slots / 32), b_cap)
     bond_map_edge: Any = None
     bond_map_bond: Any = None
     bond_map_mask: Any = None
@@ -403,26 +413,51 @@ class LocalGraph:
         """Sum over every bond row's in-lines of ``line_fn(src, *dst)``,
         ``(b_cap, width)`` float32: ``src`` the rows of ``src_rows``
         (``(b_cap, F)``) at the lines' source bonds, ``dst`` the
-        ``dst_rows`` (each ``(b_cap, ...)``) of their destination bonds,
-        which within a slab are the rows themselves. One slab of the table
-        a step of a scan with a ``(b_cap, width)`` carry: the only index
-        traffic is the gather of ``src_rows`` (its transpose a scatter-add
-        of one slab's rows), the sum onto the destinations an add, and no
-        array over all slots but ``line_src`` / ``line_mask`` exists. The
-        body is checkpointed (``ops/chunk.remat_wrap``), so the backward
-        keeps a slab's rows for one slab only. Halo and padded bond rows
-        read zero."""
-        K, rows = self.line_slots, self.b_cap
-        xs = (self.line_src.reshape(K, rows), self.line_mask.reshape(K, rows))
+        ``dst_rows`` (each ``(b_cap, ...)``) of their destination bonds.
+
+        One slab of the table a step of a scan with a ``(b_cap, width)``
+        carry, in centre order (``bond_order``: the bond rows sorted by
+        their centre atom, ``partition/graph.center_table``). Slot ``k`` of
+        a row reads the ``k``-th in-bond of its centre or, where the row
+        skips that one, the row its centre's redirected bonds read there:
+        two rows an atom, ``src_rows[center_in[k]]``, gathered for every
+        slab once a call (the scan's input: their cotangents stack, and
+        one scatter-add after the scan puts them on the bond rows). A
+        slab's source rows are its two rows an atom repeated over each
+        centre's bond rows, the bond picking one by its bit of slot ``k`` in
+        ``redirect_bits``; the repeat's transpose is the sum onto the two
+        rows of each centre (``kernels/dispatch.fused_segment_repeat``: the
+        Pallas kernel on the chip, with ``F`` padded to the kernel's
+        ``lane_width``). ``dst_rows`` go into centre
+        order and the result out of it once a call, permutations whose
+        transposes are gathers. Live are the slots below a row's
+        ``line_count``. The body is checkpointed (``ops/chunk.remat_wrap``),
+        so the backward keeps a slab's repeat for one slab only; no float
+        array over all slots exists. Halo and padded bond rows read zero.
+        No scope of its own: everything reads under the caller's stage."""
+        K, F = self.line_slots, src_rows.shape[1]
+        order, rank = self.bond_order, self.bond_rank
+        src_rows = jnp.pad(src_rows, (
+            (0, 0), (0, lane_width(F, "segment_repeat", self.kernels) - F)))
+        center = 2 * self.bond_center[order]
+        count = self.line_count[order]
+        bits = self.redirect_bits[:, order]
+        dst_rows = [permute_rows(x, order, rank) for x in dst_rows]
+        xs = (src_rows[self.center_in].reshape(K, -1, src_rows.shape[1]),
+              jnp.arange(K, dtype=jnp.int32))
 
         def body(acc, slab):
-            src, live = slab
-            out = line_fn(src_rows[src], *dst_rows)
-            return acc + jnp.where(live[:, None], out, 0).astype(acc.dtype), \
-                None
+            rows, k = slab
+            word = lax.dynamic_index_in_dim(bits, k // 32, keepdims=False)
+            ids = center + ((word >> (k % 32)) & 1)
+            src = fused_segment_repeat(rows, ids, kernels=self.kernels)
+            out = line_fn(src[:, :F], *dst_rows)
+            return acc + jnp.where((k < count)[:, None], out, 0).astype(
+                acc.dtype), None
 
-        acc0 = jnp.zeros((rows, width), jnp.float32)
-        return scan_accumulate(body, acc0, xs, remat=True)
+        acc0 = jnp.zeros((self.b_cap, width), jnp.float32)
+        return permute_rows(scan_accumulate(body, acc0, xs, remat=True),
+                            rank, order)
 
     # ---- bond-graph index remaps (reference dist.py:635-702 analogue) ----
     def edge_to_bond(self, edge_feats, bond_feats):
@@ -528,7 +563,12 @@ def local_graph_from_stacked(
         has_bond_graph=g.has_bond_graph,
         line_src=sq(g.line_src),
         line_mask=sq(g.line_mask),
+        line_count=sq(g.line_count),
         bond_center=sq(g.bond_center),
+        center_in=sq(g.center_in),
+        bond_order=sq(g.bond_order),
+        bond_rank=sq(g.bond_rank),
+        redirect_bits=sq(g.redirect_bits),
         bond_map_edge=sq(g.bond_map_edge),
         bond_map_bond=sq(g.bond_map_bond),
         bond_map_mask=sq(g.bond_map_mask),

@@ -67,7 +67,8 @@ def graph_in_specs(graph: PartitionedGraph, axes=None) -> PartitionedGraph:
         halo_send_idx=table, halo_send_mask=table, halo_recv_idx=table,
         lattice=rep, n_total_nodes=rep,
         system=None if graph.system is None else {k: rep for k in graph.system},
-        line_src=row, line_mask=row, bond_center=row,
+        line_src=row, line_mask=row, line_count=row, bond_center=row,
+        center_in=row, bond_order=row, bond_rank=row, redirect_bits=row,
         bond_map_edge=row, bond_map_bond=row, bond_map_mask=row,
         bond_halo_send_idx=table, bond_halo_send_mask=table,
         bond_halo_recv_idx=table,

@@ -26,7 +26,8 @@ unchanged on the packed ``LocalGraph``:
   (``partition/graph.line_table``): slot ``k * b_cap + b`` is the k-th line
   into bond row ``b``, ``K`` the largest in-degree in the batch, so a line's
   destination and centre are its position and only ``line_src`` is an index
-  array; ``bond_center`` holds each bond row's centre atom;
+  array; ``bond_center`` holds each bond row's centre atom, and the centre
+  tables (``partition/graph.center_table``) the same lines by centre atom;
 - padded ``dst`` rows repeat the last real value (in-bounds, nondecreasing);
   padded rows are masked so they contribute 0;
 - ``e_split == e_cap``: the packed layout is unsplit (single partition has
@@ -53,9 +54,10 @@ import numpy as np
 from ..neighbors import neighbor_list
 from .capacity import (LINE_SLOTS, BucketPolicy, FixedCaps, freeze_caps,
                        line_table_cap)
-from .graph import (PartitionedGraph, build_partitioned_graph,
-                    expand_shift_tables, line_slots_needed, line_table,
-                    line_table_stats)
+from .graph import (PartitionedGraph, bond_orders, build_partitioned_graph,
+                    center_table, empty_center_tables, expand_shift_tables,
+                    line_slots_needed, line_table, line_table_stats,
+                    live_mask)
 from .partitioner import build_plan
 from .plan import PartitionPlan
 
@@ -311,18 +313,22 @@ def pack_structures(
             ei += len(blk["src"])
             mi += nm
         # one table over the packed bond rows (block offsets on the ids)
-        line_src, line_mask, bond_center = (x[None] for x in line_table(
-            np.concatenate([b["line_src"] + o
-                            for b, o in zip(blocks, bond_off)]),
-            np.concatenate([b["line_dst"] + o
-                            for b, o in zip(blocks, bond_off)]),
-            np.concatenate([b["line_center"] + o
-                            for b, o in zip(blocks, node_off)]),
-            b_cap, slabs))
+        lines = (np.concatenate([b["line_src"] + o
+                                 for b, o in zip(blocks, bond_off)]),
+                 np.concatenate([b["line_dst"] + o
+                                 for b, o in zip(blocks, bond_off)]),
+                 np.concatenate([b["line_center"] + o
+                                 for b, o in zip(blocks, node_off)]))
+        line_src, line_count, bond_center = (
+            x[None] for x in line_table(*lines, b_cap, slabs))
+        center_tables = dict(zip(
+            ("center_in", "redirect_bits"),
+            (x[None] for x in center_table(*lines, b_cap, n_cap, slabs))))
     else:
-        b_cap = 0
-        line_src = bond_center = np.zeros((1, 0), dtype=np.int32)
-        line_mask = np.zeros((1, 0), dtype=bool)
+        b_cap = slabs = 0
+        line_src = line_count = bond_center = np.zeros((1, 0),
+                                                       dtype=np.int32)
+        center_tables = empty_center_tables(1, n_cap)
         bm_edge = bm_bond = np.zeros((1, 0), dtype=np.int32)
         bm_mask = np.zeros((1, 0), dtype=bool)
 
@@ -352,8 +358,11 @@ def pack_structures(
         lattice=np.eye(3, dtype=dtype),
         n_total_nodes=np.int32(n_tot),
         line_src=line_src,
-        line_mask=line_mask,
+        line_mask=live_mask(line_count, slabs),
+        line_count=line_count,
         bond_center=bond_center,
+        **center_tables,
+        **bond_orders(bond_center),
         bond_map_edge=bm_edge,
         bond_map_bond=bm_bond,
         bond_map_mask=bm_mask,
@@ -831,7 +840,10 @@ def pack_structures_mesh(
         n_total_nodes=np.int32(sum(it["n"] for it in items)),
         line_src=cat0("line_src"),
         line_mask=cat0("line_mask"),
+        line_count=cat0("line_count"),
         bond_center=cat0("bond_center"),
+        **{name: cat0(name) for name in (
+            "center_in", "bond_order", "bond_rank", "redirect_bits")},
         bond_map_edge=cat0("bond_map_edge"),
         bond_map_bond=cat0("bond_map_bond"),
         bond_map_mask=cat0("bond_map_mask"),
@@ -931,7 +943,7 @@ def graph_live_slots(graph: PartitionedGraph) -> tuple:
     slots = P * (graph.n_cap + graph.e_cap)
     if graph.has_bond_graph:
         slots += P * int(graph.line_src.shape[-1])
-        live += int(np.asarray(graph.line_mask).sum())
+        live += int(np.asarray(graph.line_count).sum())
     return live, slots
 
 
@@ -979,6 +991,6 @@ def packed_stats(graph: PartitionedGraph, n_real_structures: int) -> dict:
         send = np.asarray(graph.halo_send_mask).sum(axis=(0, 2))
         stats["halo_send_per_part"] = [int(x) for x in send]
     if graph.has_bond_graph:
-        stats["n_lines"] = int(np.asarray(graph.line_mask).sum())
+        stats["n_lines"] = int(np.asarray(graph.line_count).sum())
         stats.update(line_table_stats(graph))
     return stats

@@ -40,7 +40,12 @@ _default_caps = CapacityPolicy()
         "lattice",
         "line_src",
         "line_mask",
+        "line_count",
         "bond_center",
+        "center_in",
+        "bond_order",
+        "bond_rank",
+        "redirect_bits",
         "bond_map_edge",
         "bond_map_bond",
         "bond_map_mask",
@@ -96,8 +101,19 @@ class PartitionedGraph:
     # is an index array. ``K = line_src.shape[-1] // b_cap`` slabs.
     line_src: Any           # (P, K * b_cap) int32 — bond-node local ids
     line_mask: Any          # (P, K * b_cap) bool — pad slots False
+    line_count: Any         # (P, b_cap) int32 — lines into each bond row:
+    #                         its slots below the count are live
     bond_center: Any        # (P, b_cap) int32 — atom local id of each bond
     #                         row's source atom, the centre of its in-lines
+    # The same lines read by centre atom (:func:`center_table`,
+    # ``LocalGraph.in_line_sum``): slot k of row b is the k-th in-bond of
+    # its centre, but for the slots a row redirects.
+    center_in: Any          # (P, K, N_cap, 2) int32 — k-th in-bond of each
+    #                         atom, and the row its redirected bonds read
+    bond_order: Any         # (P, b_cap) int32 — bond rows by centre atom
+    bond_rank: Any          # (P, b_cap) int32 — its inverse permutation
+    redirect_bits: Any      # (P, ceil(K / 32), b_cap) int32 — bit k % 32 of
+    #                         word k // 32: slot k of the row is redirected
     bond_map_edge: Any      # (P, M_cap) int32 — local edge id per owned bond node
     bond_map_bond: Any      # (P, M_cap) int32
     bond_map_mask: Any
@@ -252,11 +268,11 @@ def line_table(line_src, line_dst, line_center, b_cap: int, slabs: int):
     (bond-node local ids, and the centre atom's local id): the lines into
     bond row ``b`` take slots ``0 .. n_b - 1`` of that row in their stable
     dst-sorted order, and slot ``k`` of row ``b`` is entry ``k * b_cap + b``.
-    Returns ``(line_src, line_mask, bond_center)``: ``(slabs * b_cap,)``
-    source ids and live-slot mask (a pad slot is masked and points at its
-    own row, which is in bounds), and the ``(b_cap,)`` centre atom of each
-    row's in-lines (a function of the destination bond alone: its source
-    atom; 0 for a row with none).
+    Returns ``(line_src, line_count, bond_center)``: ``(slabs * b_cap,)``
+    source ids (a pad slot points at its own row, which is in bounds), the
+    ``(b_cap,)`` count ``n_b`` of each row's lines (its slots ``k < n_b``
+    are live) and the centre atom of each row's in-lines (a function of the
+    destination bond alone: its source atom; 0 for a row with none).
     """
     line_dst = np.asarray(line_dst, np.int64)
     order = np.argsort(line_dst, kind="stable")
@@ -269,14 +285,142 @@ def line_table(line_src, line_dst, line_center, b_cap: int, slabs: int):
             f"{int(dst[-1])}")
     slot = rank * b_cap + dst
     src = np.tile(np.arange(b_cap, dtype=np.int32), slabs)
-    mask = np.zeros(slabs * b_cap, dtype=bool)
     src[slot] = np.asarray(line_src)[order]
-    mask[slot] = True
+    count = np.bincount(dst, minlength=b_cap).astype(np.int32)
     center = np.zeros(b_cap, dtype=np.int32)
     center[dst] = np.asarray(line_center)[order]
     assert np.array_equal(center[dst], np.asarray(line_center)[order]), \
         "a bond's in-lines must share their centre atom"
-    return src, mask, center
+    return src, count, center
+
+
+def center_table(line_src, line_dst, line_center, b_cap: int, n_cap: int,
+                 slabs: int):
+    """The same lines read by centre atom (``LocalGraph.in_line_sum``).
+
+    A line ``k -> j -> i`` reads its source bond from the bonds into its
+    centre ``j``, a set that depends on ``j`` alone but for the bonds that
+    the destination ``j -> i`` skips (``k != i``: the in-bonds from atom
+    ``i``, one in a box wider than twice the bond cutoff, several images
+    in a smaller one). So slot ``k`` of bond row ``b`` reads the ``k``-th
+    in-bond of its centre, except where that one is skipped: such a slot
+    below the row's line count ``n_b`` is *redirected* to a position at or
+    past ``n_b`` that is not skipped, one for one, in ascending order. Each
+    slot ``k < n_b`` then holds exactly one line of the list, and no slot
+    more is needed than the list's own largest ``n_b``. The rows a centre's
+    bonds skip at slot ``k`` are those of the one atom whose in-bond sits at
+    position ``k``, so every bond of the centre redirected there reads the
+    same row: one more row a centre and slot.
+
+    From the line list as :func:`line_table` takes it. A centre's in-bonds
+    are the distinct sources of its lines, in ascending bond row; a row's
+    skipped positions are the ones none of its lines reads. Returns
+    ``(center_in, redirect_bits)``: ``(slabs, n_cap, 2)`` bond rows by slot
+    and centre, the slot's in-bond and the row the centre's redirected
+    bonds read there (row 0 where there is none, in bounds and never read
+    live), and the redirected slots of each row as bits, ``(ceil(slabs /
+    32), b_cap)`` int32 words: slot ``k`` is bit ``k % 32`` of word
+    ``k // 32``. Its shape is the table's own, so every graph of one
+    capacity has it, however many slots a row redirects (one where a box
+    is wider than twice the bond cutoff, more in tiny periodic boxes:
+    :func:`line_table_stats`).
+    """
+    src = np.asarray(line_src, np.int64)
+    dst = np.asarray(line_dst, np.int64)
+    cen = np.asarray(line_center, np.int64)
+    count = np.bincount(dst, minlength=b_cap)
+    if len(count) > b_cap or (len(dst) and int(count.max()) > slabs):
+        raise ValueError(f"{slabs} slabs x {b_cap} rows cannot hold the "
+                         f"lines into bond row {int(dst.max())}")
+    # a source bond enters its lines' centre: its in-bonds are those rows
+    center_of = np.full(b_cap, -1, np.int64)
+    center_of[src] = cen
+    assert np.array_equal(center_of[src], cen), \
+        "a bond's out-lines must share their centre atom"
+    ins = np.nonzero(center_of >= 0)[0]
+    ins = ins[np.argsort(center_of[ins], kind="stable")]
+    owner = center_of[ins]
+    degree = np.bincount(owner, minlength=n_cap)
+    pos = np.zeros(b_cap, np.int64)
+    pos[ins] = np.arange(len(ins)) - np.searchsorted(owner, owner)
+    table = np.zeros((max(slabs, int(degree.max(initial=0))), n_cap),
+                     np.int32)
+    table[pos[ins], owner] = ins
+    # a row's skipped positions: one (the reverse bond) in a wide box, whose
+    # position is what the line positions' sum leaves out of 0 + .. + d - 1
+    bond_center = np.zeros(b_cap, np.int64)
+    bond_center[dst] = cen
+    deg_b = degree[bond_center]
+    skipped = np.where(count > 0, deg_b - count, 0)
+    psum = np.bincount(dst, weights=pos[src], minlength=b_cap)
+    one = np.nonzero(skipped == 1)[0]
+    gap = deg_b[one] * (deg_b[one] - 1) // 2 - psum[one].astype(np.int64)
+    live = gap < count[one]
+    rows, slots, targets = [one[live]], [gap[live]], [count[one[live]]]
+    many = np.nonzero(skipped > 1)[0]
+    if len(many):
+        # several images of one neighbour: mark what each such row reads
+        at = np.full(b_cap, -1, np.int64)
+        at[many] = np.arange(len(many))
+        sel = at[dst] >= 0
+        read = np.zeros((len(many), table.shape[0]), bool)
+        read[at[dst[sel]], pos[src[sel]]] = True
+        p = np.arange(table.shape[0])
+        inside = p < deg_b[many][:, None]
+        below = p < count[many][:, None]
+        r_rows, r_slots = np.nonzero(inside & below & ~read)
+        t_rows, t_pos = np.nonzero(inside & ~below & read)
+        assert np.array_equal(r_rows, t_rows)
+        rows.append(many[r_rows])
+        slots.append(r_slots)
+        targets.append(t_pos)
+    rows, slots, targets = (np.concatenate(x) for x in (rows, slots, targets))
+    # one bit a redirected slot; a row's bits in one word are distinct
+    # powers of two, so their float64 sum is exact
+    words = -(-slabs // 32)
+    bits = np.bincount((slots // 32) * b_cap + rows,
+                       weights=np.exp2(slots % 32), minlength=words * b_cap)
+    redirect_bits = bits.astype(np.uint32).view(np.int32).reshape(words,
+                                                                  b_cap)
+    # the row a centre's redirected bonds read at a slot: one a centre
+    at_slot = np.zeros((slabs, n_cap), np.int32)
+    reads = table[targets, bond_center[rows]]
+    at_slot[slots, bond_center[rows]] = reads
+    assert np.array_equal(at_slot[slots, bond_center[rows]], reads), \
+        "a centre's bonds redirected at one slot must read one row"
+    return np.stack([table[:slabs], at_slot], axis=-1), redirect_bits
+
+
+def live_mask(line_count, slabs: int):
+    """``(..., slabs * b_cap)`` bool of ``(..., b_cap)`` line counts, slot-major
+    as :func:`line_table`: slot ``k`` of row ``b`` holds a line, ``k < n_b``."""
+    count = np.asarray(line_count)
+    live = np.arange(slabs)[:, None] < count[..., None, :]
+    return live.reshape(*count.shape[:-1], slabs * count.shape[-1])
+
+
+def redirects_per_row(redirect_bits):
+    """The redirected slots of each bond row: the bits set in its column of
+    ``(..., words, b_cap)`` ``redirect_bits``."""
+    bits = np.asarray(redirect_bits).view(np.uint32)
+    return np.bitwise_count(bits).sum(axis=-2)
+
+
+def bond_orders(bond_center) -> dict:
+    """``bond_order`` and ``bond_rank`` of ``(P, b_cap)`` bond centres: each
+    partition's bond rows by centre atom (stable), and each row's place in
+    that order, its inverse."""
+    order = np.argsort(bond_center, axis=-1, kind="stable").astype(np.int32)
+    rank = np.empty_like(order)
+    for p in range(order.shape[0]):
+        rank[p, order[p]] = np.arange(order.shape[1], dtype=np.int32)
+    return {"bond_order": order, "bond_rank": rank}
+
+
+def empty_center_tables(P: int, n_cap: int) -> dict:
+    """The centre tables of a graph without bonds: zero-size arrays."""
+    return dict(center_in=np.zeros((P, 0, n_cap, 2), np.int32),
+                redirect_bits=np.zeros((P, 0, 0), np.int32))
 
 
 def build_partitioned_graph(
@@ -399,15 +543,20 @@ def build_partitioned_graph(
             max(len(x) for x in plan.bond_mapping_edge), b_cap
         ) // max(b_cap, 1)
         line_src = np.zeros((P, slabs * b_cap), dtype=np.int32)
-        line_mask = np.zeros((P, slabs * b_cap), dtype=bool)
+        line_count = np.zeros((P, b_cap), dtype=np.int32)
         bond_center = np.zeros((P, b_cap), dtype=np.int32)
+        center_in = np.zeros((P, slabs, n_cap, 2), dtype=np.int32)
+        redirect_bits = np.zeros((P, -(-slabs // 32), b_cap), dtype=np.int32)
         bm_edge = np.zeros((P, m_cap), dtype=np.int32)
         bm_bond = np.zeros((P, m_cap), dtype=np.int32)
         bm_mask = np.zeros((P, m_cap), dtype=bool)
         for p in range(P):
-            line_src[p], line_mask[p], bond_center[p] = line_table(
-                plan.line_src[p], plan.line_dst[p],
-                plan.line_center_local[p], b_cap, slabs)
+            lines = (plan.line_src[p], plan.line_dst[p],
+                     plan.line_center_local[p])
+            line_src[p], line_count[p], bond_center[p] = line_table(
+                *lines, b_cap, slabs)
+            center_in[p], redirect_bits[p] = center_table(
+                *lines, b_cap, n_cap, slabs)
             nm = len(plan.bond_mapping_edge[p])
             bm_edge[p, :nm] = edge_perm_inv[p][plan.bond_mapping_edge[p]]
             bm_bond[p, :nm] = plan.bond_mapping_bond[p]
@@ -418,10 +567,13 @@ def build_partitioned_graph(
         )
         # the node and bond exchanges must ride the same ring shifts
         all_shifts = tuple(sorted(set(shifts) | set(b_shifts)))
+        center_tables = dict(center_in=center_in, redirect_bits=redirect_bits)
     else:
         b_cap = 0
-        line_src = bond_center = np.zeros((P, 0), dtype=np.int32)
-        line_mask = np.zeros((P, 0), dtype=bool)
+        slabs = 0
+        line_src = line_count = bond_center = np.zeros((P, 0),
+                                                       dtype=np.int32)
+        center_tables = empty_center_tables(P, n_cap)
         bm_edge = bm_bond = np.zeros((P, 0), dtype=np.int32)
         bm_mask = np.zeros((P, 0), dtype=bool)
         b_send = np.zeros((1, P, 0), dtype=np.int32)
@@ -459,8 +611,11 @@ def build_partitioned_graph(
         lattice=np.asarray(lattice, dtype=dtype),
         n_total_nodes=np.int32(len(plan.node_part)),
         line_src=line_src,
-        line_mask=line_mask,
+        line_mask=live_mask(line_count, slabs),
+        line_count=line_count,
         bond_center=bond_center,
+        **center_tables,
+        **bond_orders(bond_center),
         bond_map_edge=bm_edge,
         bond_map_bond=bm_bond,
         bond_map_mask=bm_mask,
@@ -565,15 +720,19 @@ def device_refresh_graph(static, arrays, graph, positions):
 
 
 def line_table_stats(graph: PartitionedGraph) -> dict:
-    """How far the in-line table engages: ``line_slots`` (its slabs, K) and
+    """How far the in-line table engages: ``line_slots`` (its slabs, K),
     ``line_table_fill``, live lines over K x the bond rows computed (1.0
     where every bond has K in-lines; the rest is dense line work on pad
-    slots)."""
+    slots), and ``line_redirects``, the most slots one bond row redirects in
+    the centre tables (``m``: 1 where a box is wider than twice the bond
+    cutoff, more in a smaller one)."""
     slabs = graph.line_src.shape[-1] // graph.b_cap if graph.b_cap else 0
     rows = int(np.asarray(graph.bond_map_mask).sum())
-    live = int(np.asarray(graph.line_mask).sum())
+    live = int(np.asarray(graph.line_count).sum())
     return {"line_slots": slabs,
-            "line_table_fill": live / (slabs * rows) if slabs * rows else 0.0}
+            "line_table_fill": live / (slabs * rows) if slabs * rows else 0.0,
+            "line_redirects": int(redirects_per_row(
+                graph.redirect_bits).max(initial=0))}
 
 
 def graph_build_stats(graph: PartitionedGraph) -> dict:
@@ -617,7 +776,7 @@ def graph_build_stats(graph: PartitionedGraph) -> dict:
         # real rows of the bond graph beside n_edges_per_part: the bond
         # nodes a partition computes (those mapped from one of its edges;
         # halo bond rows arrive by exchange) and its live lines
-        lines = np.asarray(graph.line_mask).sum(axis=1)
+        lines = np.asarray(graph.line_count).sum(axis=1)
         stats["n_bonds_per_part"] = [
             int(x) for x in np.asarray(graph.bond_map_mask).sum(axis=1)]
         stats["n_lines_per_part"] = [int(x) for x in lines]

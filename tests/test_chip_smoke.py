@@ -145,6 +145,11 @@ def test_kernels_phase_has_a_row_for_every_entry_of_the_default_table():
     got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
     assert got.shape == want.shape == (29568 * 5120 + 9856 * 1152,)
     assert got.dtype == want.dtype == jnp.bfloat16
+    # a slab of dimenet-pp-md-1c's bond rows onto two rows a centre atom
+    kernel, xla, args = cases["segment_repeat"](jnp.float32)
+    assert [a.shape for a in args] == [(394368, 128)]
+    got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
+    assert got.shape == want.shape == (2 * 8448, 128)
 
 
 def test_chip_bands_carry_their_measurement():

@@ -51,8 +51,8 @@ def potential(model=None, nparts=1, **kw):
                          num_partitions=nparts, skin=0.3, **kw)
 
 
-def step_sites(model, nparts=1):
-    pot = potential(model, nparts)
+def step_sites(model, nparts=1, **kw):
+    pot = potential(model, nparts, **kw)
     graph, _, positions = pot._prepare(atoms_of(nparts))
     jaxpr = jax.make_jaxpr(pot._potential)(pot.params, graph, positions)
     return [s for s in iter_sites(jaxpr) if "model_energy" in s.stack]
@@ -94,6 +94,33 @@ def test_every_equation_of_the_model_carries_a_stage(nparts, dtype):
         assert len(sends) == (1 + config().num_blocks) * 2
     else:
         assert not sends
+
+
+@pytest.mark.parametrize("kernels", [False, "interpret"])
+def test_the_reads_by_centre_keep_the_triplet_stage(kernels):
+    """The scan reads a slab's source rows by centre atom: the repeat over
+    each centre's bond rows, its transposed sum (the Pallas kernel, or
+    XLA's scatter-add) and the permutations into and out of centre order
+    all read ``triplet_message`` (its basis ``triplet_basis``), none
+    ``edge_aggregate``, whose scope the kernel dispatcher's segment sums
+    open."""
+    model = step_sites(DimeNetPP(config()), 1, kernels=kernels)
+    in_scan = {stage_of(s.stack) for s in model if "scan" in s.path}
+    assert in_scan == {"triplet_message", "triplet_basis"}
+    sums = [s for s in model if "scan" in s.path and s.primitive == (
+        "pallas_call" if kernels else "scatter-add")]
+    assert sums and {stage_of(s.stack) for s in sums} == {"triplet_message"}
+    # the permutations and their transposes: gathers, outside the scan
+    perms = [s for s in model if s.primitive == "gather"
+             and "custom_vjp_call" in s.path and "scan" not in s.path]
+    assert len(perms) >= 4
+    assert {stage_of(s.stack) for s in perms} == {"triplet_message"}
+    # outside the scan the triplet message scatters once a block: every
+    # slab's rows by centre, gathered before the scan, onto the bond rows
+    onto_bonds = [s for s in model if s.primitive == "scatter-add"
+                  and "scan" not in s.path
+                  and stage_of(s.stack) == "triplet_message"]
+    assert len(onto_bonds) == config().num_blocks
 
 
 OTHERS = {

@@ -1,7 +1,10 @@
 """CHGNet's lines as a slot-major in-line table (``partition/graph.line_table``).
 
 The host helper on a hand-built ragged list and on real plans (one and four
-partitions, both packers of ``partition/batch.py``); ``LocalGraph``'s two
+partitions, both packers of ``partition/batch.py``); the same lines by
+centre atom (``partition/graph.center_table``, what DimeNet++'s scan reads)
+on the same plans, a box narrower than twice the cutoff and a cut of
+``dimenet-pp-md-1c``'s mix; ``LocalGraph``'s two
 methods that know the order against ``x[line_dst]`` and ``masked_segment_sum``
 of the dst-sorted list the table replaced, values and gradients, float32 and
 bfloat16; and CHGNet's energy and forces on a two-species toy with vacancies
@@ -27,8 +30,11 @@ from distmlip_tpu.partition import (BucketPolicy, CapacityPolicy, FixedCaps,
                                     build_partitioned_graph, build_plan,
                                     pack_structures)
 from distmlip_tpu.partition.capacity import line_table_cap
-from distmlip_tpu.partition.graph import (line_slots_needed, line_table,
-                                          line_table_stats)
+from distmlip_tpu.partition import bucket_key, fixed_caps_for_batches
+from distmlip_tpu.partition.graph import (center_table, line_slots_needed,
+                                          line_table, line_table_stats,
+                                          live_mask, redirects_per_row)
+from distmlip_tpu.train.data import structure_needs
 from distmlip_tpu.telemetry import scope
 from tests.utils import make_crystal
 
@@ -55,13 +61,18 @@ def build(cart, lattice, species, nparts, caps=None):
     return plan, graph, host
 
 
+def live(count, slabs):
+    """``(slabs, b_cap)``: slot ``k`` of row ``b`` is live, ``k < n_b``."""
+    return np.arange(slabs)[:, None] < np.asarray(count)[None, :]
+
+
 def table_lines(graph, p):
     """The live lines of partition ``p`` read back from its table, as
     ``(src, dst, centre)`` rows in dst-sorted, slot-ascending order (the
     list the table replaced) with each row's table entry."""
     b_cap = graph.b_cap
     slabs = graph.line_src.shape[-1] // b_cap
-    mask = np.asarray(graph.line_mask[p]).reshape(slabs, b_cap)
+    mask = live(graph.line_count[p], slabs)
     k, dst = np.nonzero(mask.T)[::-1]      # dst-major, slot ascending
     entry = k * b_cap + dst
     src = np.asarray(graph.line_src[p])[entry]
@@ -70,7 +81,7 @@ def table_lines(graph, p):
 
 def check_table(graph, p):
     """What every table holds: entries in bounds, a bond's live slots a
-    prefix of its slots, pad slots masked."""
+    prefix of its slots below its line count, pad slots masked."""
     b_cap = graph.b_cap
     slabs = graph.line_src.shape[-1] // b_cap
     src = np.asarray(graph.line_src[p])
@@ -79,8 +90,12 @@ def check_table(graph, p):
     center = np.asarray(graph.bond_center[p])
     assert center.shape == (b_cap,) and 0 <= center.min()
     assert center.max() < graph.n_cap
+    count = np.asarray(graph.line_count[p])
+    assert count.shape == (b_cap,) and 0 <= count.min()
+    assert count.max() <= slabs
     mask = np.asarray(graph.line_mask[p]).reshape(slabs, b_cap)
     assert np.all(mask[1:] <= mask[:-1])
+    np.testing.assert_array_equal(mask, live(count, slabs))
 
 
 # ---- the helper ------------------------------------------------------------
@@ -92,23 +107,26 @@ def test_hand_built_ragged_table():
     line_dst = np.array([6, 2, 2, 5, 6, 6, 2])
     center = np.array([9, 4, 4, 8, 9, 9, 4])
     assert line_slots_needed([line_dst]) == 3
-    src, mask, bond_center = line_table(line_src, line_dst, center, 8, 3)
-    assert mask.reshape(3, 8).sum(axis=0).tolist() == [0, 0, 3, 0, 0, 1, 3, 0]
+    src, count, bond_center = line_table(line_src, line_dst, center, 8, 3)
+    assert count.tolist() == [0, 0, 3, 0, 0, 1, 3, 0]
+    np.testing.assert_array_equal(live_mask(count, 3),
+                                  live(count, 3).reshape(-1))
+    assert live_mask(np.zeros((2, 0), int), 0).shape == (2, 0)
     # a bond's lines keep their order in the list, slot by slot
     assert src.reshape(3, 8)[:, 2].tolist() == [1, 0, 1]
     assert src.reshape(3, 8)[:, 6].tolist() == [7, 4, 5]
     assert src.reshape(3, 8)[:, 5].tolist() == [3, 5, 5]   # pads: own row
-    # pad slots are masked and point at their own (valid) row
-    pads = ~mask
+    # pad slots are not live and point at their own (valid) row
+    pads = ~live(count, 3).reshape(-1)
     assert np.array_equal(src[pads], np.tile(np.arange(8), 3)[pads])
     assert bond_center.tolist() == [0, 0, 4, 0, 0, 8, 9, 0]
     # more slabs than needed: whole pad slabs
-    src4, mask4, _ = line_table(line_src, line_dst, center, 8, 4)
-    assert not mask4[24:].any() and np.array_equal(src4[:24], src)
+    src4, count4, _ = line_table(line_src, line_dst, center, 8, 4)
+    assert not live(count4, 4)[3:].any() and np.array_equal(src4[:24], src)
     # an empty list, and no slab at all
-    src0, mask0, c0 = line_table(np.zeros(0, int), np.zeros(0, int),
-                                 np.zeros(0, int), 8, 2)
-    assert not mask0.any() and src0.tolist() == list(range(8)) * 2
+    src0, count0, c0 = line_table(np.zeros(0, int), np.zeros(0, int),
+                                  np.zeros(0, int), 8, 2)
+    assert not count0.any() and src0.tolist() == list(range(8)) * 2
     assert line_table(np.zeros(0, int), np.zeros(0, int), np.zeros(0, int),
                       8, 0)[0].shape == (0,)
     assert line_slots_needed([np.zeros(0, int)]) == 0
@@ -165,7 +183,9 @@ def test_table_holds_the_plan_lines(rng, nparts):
     assert stats["line_table_fill"] == pytest.approx(live / (slabs * rows))
     assert 0.5 < stats["line_table_fill"] < 1.0
     assert line_table_stats(graph) == {
-        "line_slots": slabs, "line_table_fill": stats["line_table_fill"]}
+        "line_slots": slabs, "line_table_fill": stats["line_table_fill"],
+        "line_redirects": 1}
+    assert stats["line_redirects"] == 1
 
 
 def _atoms(cart, lattice, species):
@@ -210,6 +230,133 @@ def test_packers_build_one_table_over_the_batch(rng, spatial, batch):
     assert live == want_lines == host.stats["n_lines"]
     assert host.stats["line_slots"] == slabs
     assert 0.4 < host.stats["line_table_fill"] < 1.0
+    # the centre tables hold the lines of the slot-major table, as
+    # build_partitioned_graph's do
+    assert host.stats["line_redirects"] == 1
+    for p in range(graph.num_partitions):
+        src, dst, _, _ = table_lines(graph, p)
+        same_lines(center_lines(graph, p), (src, dst))
+
+
+# ---- the same lines by centre atom (partition/graph.center_table) ----------
+
+def center_lines(graph, p):
+    """Every live slot ``k < n_b`` the centre tables describe, as ``(src,
+    dst)`` rows: the ``k``-th in-bond of the row's centre, or, where the
+    slot is redirected, the row the centre's redirected bonds read at
+    ``k``. Checks what every such table holds on the way."""
+    b_cap, n_cap = graph.b_cap, graph.n_cap
+    center = np.asarray(graph.bond_center[p])
+    center_in = np.asarray(graph.center_in[p])
+    count = np.asarray(graph.line_count[p])
+    bits = np.asarray(graph.redirect_bits[p]).view(np.uint32)
+    K = graph.line_src.shape[-1] // b_cap
+    assert center_in.shape == (K, n_cap, 2) and count.max() <= K
+    assert 0 <= center_in.min() and center_in.max() < b_cap
+    assert bits.shape == (-(-K // 32), b_cap)
+    order = np.asarray(graph.bond_order[p])
+    rank = np.asarray(graph.bond_rank[p])
+    assert np.all(np.diff(center[order]) >= 0)
+    np.testing.assert_array_equal(rank[order], np.arange(b_cap))
+    # slot k of a row is redirected where bit k % 32 of word k // 32 is
+    # set; a redirected slot is a live slot
+    k = np.arange(K)
+    redirected = (bits[k // 32] >> (k % 32)[:, None].astype(np.uint32)) & 1
+    assert not (redirected.astype(bool) & ~live(count, K)).any()
+    dst = np.repeat(np.arange(b_cap), count)
+    k = np.arange(len(dst)) - np.repeat(np.cumsum(count) - count, count)
+    return center_in[k, center[dst], redirected[k, dst]], dst
+
+
+def same_lines(got, want):
+    key = lambda src, dst: np.sort(np.asarray(src, np.int64) * (1 << 32)
+                                   + np.asarray(dst, np.int64))
+    a, b = key(*got), key(*want)
+    assert len(np.unique(b)) == len(b)      # the list holds no line twice
+    np.testing.assert_array_equal(a, b)
+
+
+def tiny_bond_graph():
+    """One fcc cell 3.5 A wide with every edge under 3.2 A a bond (as
+    DimeNet++ builds it): a centre's neighbour is there in several images,
+    and a bond skips every in-bond from its destination atom."""
+    rng = np.random.default_rng(5)
+    cart, lattice, species = make_crystal(rng, reps=(1, 1, 1), a=3.5,
+                                          noise=0.08)
+    nl = neighbor_list_numpy(cart, lattice, [1, 1, 1], 3.2, bond_r=3.2)
+    plan = build_plan(nl, lattice, [1, 1, 1], 1, 3.2, 3.2, True)
+    graph, host = build_partitioned_graph(plan, nl, species, lattice,
+                                          caps=CapacityPolicy())
+    return plan, graph, host
+
+
+@pytest.mark.parametrize("nparts", [1, 4, "tiny"])
+def test_center_tables_hold_the_plan_lines(rng, nparts):
+    """One slot per line of the list, no line twice, every one below its
+    row's count; the same K as the slot-major table; ``m`` 1 where the box
+    is wide and more in a box narrower than twice the cutoff."""
+    if nparts == "tiny":
+        plan, graph, host = tiny_bond_graph()
+    else:
+        plan, graph, host = build(*ragged_crystal(rng, (8, 3, 3)), nparts)
+    slabs = graph.line_src.shape[-1] // graph.b_cap
+    assert graph.center_in.shape[1] == slabs
+    m = host.stats["line_redirects"]
+    assert m >= 2 if nparts == "tiny" else m == 1
+    for p in range(graph.num_partitions):
+        same_lines(center_lines(graph, p),
+                   (plan.line_src[p], plan.line_dst[p]))
+        np.testing.assert_array_equal(
+            graph.line_count[p],
+            np.bincount(plan.line_dst[p], minlength=graph.b_cap))
+
+
+def test_center_table_keeps_k_and_wants_one_redirect_at_the_cells_mix():
+    """``dimenet-pp-md-1c``'s mix (perturbed fcc, a = 3.9 A, sigma 0.04 A,
+    bonds = edges under 5.0 + 0.5 A) on a 3 x 3 x 3 cut, 11.7 A wide:
+    K is the list's largest in-degree, as the slot-major table's, and one
+    redirected slot a row is room enough."""
+    rng = np.random.default_rng(0)
+    cart, lattice, species = make_crystal(rng, reps=(3, 3, 3), a=3.9,
+                                          noise=0.04, n_species=1)
+    nl = neighbor_list_numpy(cart, lattice, [1, 1, 1], 5.5, bond_r=5.5)
+    plan = build_plan(nl, lattice, [1, 1, 1], 1, 5.5, 5.5, True)
+    b_cap, n_cap = 128 * (-(-len(nl.src) // 128)), 128
+    need = line_slots_needed(plan.line_dst)
+    center_in, bits = center_table(
+        plan.line_src[0], plan.line_dst[0], plan.line_center_local[0],
+        b_cap, n_cap, need)
+    assert center_in.shape == (need, n_cap, 2)
+    assert np.bincount(plan.line_dst[0]).max() == need
+    assert bits.shape == (-(-need // 32), b_cap)
+    # nearly every bond skips one in-bond of its centre below its count
+    per_row = redirects_per_row(bits)
+    assert per_row.max() == 1
+    assert 0.9 < per_row.sum() / len(nl.src) < 1.0
+    with pytest.raises(ValueError, match="cannot hold"):
+        center_table(plan.line_src[0], plan.line_dst[0],
+                     plan.line_center_local[0], b_cap, n_cap, need - 1)
+
+
+def test_frozen_caps_give_a_tiny_and_a_wide_cell_one_shape(rng):
+    """A cell narrower than twice the bond cutoff redirects several slots
+    of a bond row, a wide one a slot at most: under one set of frozen
+    capacities (``fixed_caps_for_batches`` over both) their packs have the
+    same shapes and bucket, so a training run over both compiles one
+    step."""
+    tiny = make_crystal(np.random.default_rng(5), reps=(1, 1, 1), a=A_LAT,
+                        noise=0.08)
+    atoms = [_atoms(*s) for s in (tiny, ragged_crystal(rng, (4, 3, 3)))]
+    caps = fixed_caps_for_batches(structure_needs(
+        atoms, CFG.cutoff, bond_cutoff=CFG.bond_cutoff, use_bond_graph=True),
+        1)
+    packs = [pack_structures([a], CFG.cutoff, bond_cutoff=CFG.bond_cutoff,
+                             use_bond_graph=True, caps=caps) for a in atoms]
+    tiny_m, wide_m = (h.stats["line_redirects"] for _, h in packs)
+    assert tiny_m >= 2 and wide_m == 1
+    shapes = [[np.shape(x) for x in jax.tree.leaves(g)] for g, _ in packs]
+    assert shapes[0] == shapes[1]
+    assert bucket_key(packs[0][0]) == bucket_key(packs[1][0])
 
 
 # ---- the two methods that know the order -----------------------------------

@@ -24,7 +24,7 @@ def kernel(op: str, file: str) -> str:
 
 
 def hlo(n=7, clone=34, line=120, op="add", lhs="p", shape="f32[8,3]",
-        value="1.5", kernel_op="add", file="halo.py"):
+        value="1.5", kernel_op="add", file="halo.py", sep=":"):
     operands = [f"param_0.{n + 1}", f"param_1.{n + 2}"]
     if lhs == "q":
         operands.reverse()
@@ -53,7 +53,7 @@ fused_computation.{n}.clone.{clone} (param_0.{n + 1}: {shape}, param_1.{n + 2}: 
 ENTRY main.{n + 9} (p: {shape}, q: {shape}) -> {shape} {{
   p = {shape}{{1,0}} parameter(0), metadata={{op_name="positions"}}
   q = {shape}{{1,0}} parameter(1)
-  custom-call.{n + 5} = {shape}{{1,0}} custom-call(p), custom_call_target="tpu_custom_call", backend_config={{"custom_call_config": {{"body":"{kernel(kernel_op, file)}", "serialization_format":1}}}}
+  custom-call.{n + 5} = {shape}{{1,0}} custom-call(p), custom_call_target="tpu_custom_call", backend_config={{"custom_call_config": {{"body"{sep}"{kernel(kernel_op, file)}", "serialization_format":1}}}}
   ROOT fusion.{n + 6} = {shape}{{1,0}} fusion(custom-call.{n + 5}, q), kind=kLoop, calls=fused_computation.{n}.clone.{clone}, metadata={{op_name="jit(potential)/edge_gather" source_line={line + 1}}}
 }}
 """
@@ -65,11 +65,13 @@ ENTRY main.{n + 9} (p: {shape}, q: {shape}) -> {shape} {{
     (dict(line=517), True),               # metadata and the file tables
     (dict(file="chunk.py"), True),        # source path, a kernel's locations
     (dict(n=40, clone=2, line=9, file="x.py"), True),
+    (dict(file="chunk.py", sep=": "), True),  # the body after a space
     (dict(op="multiply"), False),         # another instruction
     (dict(lhs="q"), False),               # the same instructions, wired anew
     (dict(shape="f32[16,3]"), False),
     (dict(value="2.5"), False),           # a number that is no name
     (dict(kernel_op="mul"), False),       # another kernel body
+    (dict(kernel_op="mul", sep=": "), False),
 ])
 def test_normalize_erases_names_and_places_only(change, same):
     base, other = hlo(), hlo(**change)

@@ -36,7 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 METADATA = re.compile(r",?\s*metadata=\{[^{}]*\}")
-KERNEL_BODY = re.compile(r'"body":"([^"]+)"')
+KERNEL_BODY = re.compile(r'"body":\s*"([^"]+)"')
 NAME = re.compile(
     r"(?<![\w.\-])%?([A-Za-z_][A-Za-z0-9_\-]*(?:\.[A-Za-z0-9_\-]+)+)(?![\w.])")
 

@@ -346,12 +346,15 @@ def _kernel_cases(seed: int):
     from distmlip_tpu.kernels.segment import (pallas_edge_aggregate,
                                               pallas_segment_sum,
                                               pallas_segment_sum_into)
-    from distmlip_tpu.kernels.so3 import (packed_m_layout, so2_conv_pallas,
+    from distmlip_tpu.kernels.so3 import (packed_m_layout,
+                                          s2_activation_pallas, s2_tables,
+                                          so2_conv_pallas,
                                           so2_conv_reference, wigner_cols,
                                           wigner_dcols_pallas,
                                           wigner_rotate_pallas,
                                           wigner_rotate_reference)
-    from distmlip_tpu.models import ESCN, ESCNConfig
+    from distmlip_tpu.models import (ESCN, ESCNConfig, EquiformerV2,
+                                     EquiformerV2Config)
     from distmlip_tpu.ops.nn import gated_mlp
     from distmlip_tpu.ops.segment import masked_segment_sum
     from distmlip_tpu.ops.so3_e3nn import (CoeffLayout,
@@ -501,10 +504,35 @@ def _kernel_cases(seed: int):
                 lambda d: masked_segment_sum(d, ids, n),
                 (normal((e, 128), dtype),))
 
+    def s2_activation(dtype):
+        # EquiformerV2's edge S2 activation on one scan chunk of
+        # eqv2-31m-md-1c: the 19 coefficients of lmax 4 / mmax 2 for 64
+        # channels and 16,384 edges, as the kernel takes them; the forward
+        # and the backward side by side
+        to_grid, from_grid = EquiformerV2(EquiformerV2Config()).edge_grid
+        tables = s2_tables(to_grid, from_grid, dtype)
+        x, dy = (normal((to_grid.shape[1], 64, 16384), dtype, 0.5)
+                 for _ in range(2))
+
+        def kernel(x_, dy_):
+            return jnp.concatenate([
+                s2_activation_pallas(x_, tables),
+                s2_activation_pallas(dy_, tables, backward_of=x_)])
+
+        def xla(x_, dy_):
+            g, f = (jnp.asarray(t, x_.dtype) for t in (to_grid, from_grid))
+            y, vjp = jax.vjp(lambda c: jnp.einsum(
+                "kg,ghe->khe", f, jax.nn.silu(jnp.einsum(
+                    "gk,khe->ghe", g, c))), x_)
+            return jnp.concatenate([y, vjp(dy_)[0]])
+
+        return kernel, xla, (x, dy)
+
     return (("segment_sum", segment_sum), ("edge_aggregate", edge_aggregate),
             ("so2_conv", so2_conv), ("wigner_rotate", wigner_rotate),
             ("segment_sum_into", segment_sum_into),
-            ("segment_repeat", segment_repeat))
+            ("segment_repeat", segment_repeat),
+            ("s2_activation", s2_activation))
 
 
 def phase_kernels(bands: dict, seed: int = 0) -> list:

@@ -53,6 +53,9 @@ from .segment import (
 )
 from .so3 import (
     packed_m_layout,
+    s2_activation_pallas,
+    s2_lanes,
+    s2_tables,
     so2_conv_pallas,
     so2_conv_reference,
     wigner_dcols_pallas,
@@ -109,6 +112,12 @@ TPU_DEFAULT_MODE = {
     # (2.04 / 4.06 into two rows a centre); max rel err 6.5e-6 (float32)
     # — chip run on a TPU v5e; PERF.md section 6
     "segment_repeat": "pallas",
+    # EquiformerV2's edge S2 activation: compiled on a TPU v5 lite at
+    # eqv2-31m-md-1c's (19, 64, 16384) chunk, the forward and a
+    # forward-and-backward 4.51 ms against 8.88 for XLA's products
+    # (bfloat16); max rel err of both against XLA at highest 2.9e-6
+    # (float32), 3.9e-3 (bfloat16) — chip run, PR 41; PERF.md section 6
+    "s2_activation": "pallas",
 }
 
 
@@ -1027,3 +1036,50 @@ def _wigner_vjps(interpret: bool, *, n_ops: int, **statics):
     dcols.defvjp(lambda labs, pieces: (dcols(labs, pieces), (labs, pieces)),
                  dcols_bwd)
     return to_edge, to_lab, dcols
+
+
+# ---------------------------------------------------------------------------
+# S2 activation: SiLU on the edge grid (EquiformerV2)
+# ---------------------------------------------------------------------------
+
+def fused_s2_activation(x, to_grid, from_grid, kernels=None):
+    """``from_grid @ silu(to_grid @ x)`` over the coefficient axis of ``x``
+    ``(E, K, H)``, dispatched; ``to_grid`` ``(P, K)`` and ``from_grid``
+    ``(K, P)`` are host tables, used in ``x``'s dtype.
+
+    On the Pallas path :func:`so3.s2_activation_pallas` takes the
+    coefficients as ``(K, H, E)``, the order in which XLA lays out ``x``
+    when the SO(2) convolution's products make it (no copy: the edges run
+    along the lanes, padded only where their count is no whole block), and
+    runs each pass as one kernel: the forward, and through a
+    ``custom_vjp`` whose residual is ``x`` alone, the backward, which
+    computes the grid again. The grid values exist only in VMEM. Elsewhere
+    the two products of XLA with SiLU between them. No scope of its own:
+    both read under the caller's stage."""
+    mode = resolve_kernel_mode(kernels, op="s2_activation")
+    use = mode != "xla" and x.shape[0] > 0
+    _count("s2_activation", use)
+    if not use:
+        g, f = (jnp.asarray(t, x.dtype) for t in (to_grid, from_grid))
+        return jnp.einsum("kg,egh->ekh", f,
+                          jax.nn.silu(jnp.einsum("gk,ekh->egh", g, x)))
+    e = x.shape[0]
+    rows = jnp.pad(x.transpose(1, 2, 0),
+                   ((0, 0), (0, 0), (0, -e % s2_lanes(e, x.dtype))))
+    tables = s2_tables(to_grid, from_grid, x.dtype)
+    out = _s2_vjp(tables, mode == "interpret")(rows)
+    return out[:, :, :e].transpose(2, 0, 1)
+
+
+def _s2_vjp(tables, interpret: bool):
+    run = partial(s2_activation_pallas, tables=tables, interpret=interpret)
+
+    @jax.custom_vjp
+    def f(x):
+        return run(x)
+
+    def bwd(x, g):
+        return (run(g, backward_of=x),)
+
+    f.defvjp(lambda x: (f(x), x), bwd)
+    return f

@@ -415,3 +415,129 @@ def _wigner_dcols_kernel(*refs, l_max, m_max, ms, c, n_ops):
         return carry
 
     jax.lax.fori_loop(0, out_ref.shape[0] // ROT_SUB, body, None)
+
+
+# ---------------------------------------------------------------------------
+# S2 activation: SiLU on the sphere's grid, between two table products
+# ---------------------------------------------------------------------------
+#
+# EquiformerV2 (models/equiformer_v2.py) takes each edge's narrowed
+# coefficients to the 324 points of its grid, applies SiLU there and
+# integrates back: ``y = F silu(G x)`` per (edge, channel) column. As two
+# XLA products the grid values (18 times the coefficients) cross HBM in
+# every pass. Here the coefficients come as ``(K, H, E)``: coefficient,
+# channel, edge, the order in which XLA lays out the convolution's output
+# pieces, so the operand is a view and no copy. A grid step takes a block
+# of channels and of edges; the kernel reads a channel's ``(K, L)`` rows
+# (a sublane of each coefficient's tile), which are the right operand of
+# both products, plain 2-D matrix products, and the grid of a block
+# exists only in VMEM. The grid axis is zero-padded to whole tiles
+# (``s2_tables``): SiLU(0) = 0, so a padded grid point adds nothing.
+
+# a grid step: 32 channels by 1,024 edges at 2 bytes an element (float32:
+# 512; at 1,024 its backward outgrows VMEM). One chunk of eqv2-31m-md-1c,
+# (19, 64, 16384) bfloat16, forward plus forward-and-backward on a v5e:
+# 4.51 ms, against 4.82 at 8 x 2,048, 4.86 at 16 x 2,048, 4.86 at 64 x 512
+# and 8.88 for XLA's products (PERF.md section 6, PR 41)
+S2_LANES = 1024
+S2_CHANNELS = 32    # a block holds them all where they are no multiple
+
+
+def s2_tables(to_grid, from_grid, dtype):
+    """``(G, Gt, F, Ft)``: ``to_grid`` ``(P, K)`` and ``from_grid``
+    ``(K, P)`` with the grid axis zero-padded to whole 16-row tiles, and
+    their transposes, as ``dtype`` host arrays."""
+    P, K = to_grid.shape
+    pp = -(-P // 16) * 16
+    G = np.zeros((pp, K), np.float32)
+    G[:P] = to_grid
+    F = np.zeros((K, pp), np.float32)
+    F[:, :P] = from_grid
+    return tuple(np.asarray(t, dtype) for t in (G, G.T.copy(), F, F.T.copy()))
+
+
+def s2_lanes(n: int, dtype) -> int:
+    """The edge block of :func:`s2_activation_pallas` over ``n`` edges."""
+    lanes = S2_LANES * 2 // max(2, jnp.dtype(dtype).itemsize)
+    return min(lanes, -(-n // 128) * 128)
+
+
+def _s2_dot(a, b):
+    # float32 rows ask for a true float32 product, as in _so2_kernel
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=(jax.lax.Precision.HIGHEST
+                              if b.dtype == jnp.float32 else None))
+
+
+def _s2_grid(G, x):
+    """The grid values of a channel's rows, float32 sums rounded once to
+    the rows' dtype (where the XLA products round them), then widened for
+    SiLU."""
+    return _s2_dot(G, x).astype(x.dtype).astype(jnp.float32)
+
+
+# SiLU and its slope through tanh: with h = g / 2 and t = tanh(h), silu(g)
+# = h (1 + t) and silu'(g) = (1 + t) / 2 + h (1 - t^2) / 2. One
+# transcendental a point and no division: the logistic's form took 6.37 ms
+# where this takes 4.82 (the block at 8 x 2,048, PERF.md section 6, PR 41).
+
+def _s2_fwd_kernel(g_ref, f_ref, x_ref, o_ref):
+    def channel(j, carry):
+        h = 0.5 * _s2_grid(g_ref[...], x_ref[:, j, :])
+        s = (h + h * jnp.tanh(h)).astype(o_ref.dtype)
+        o_ref[:, j, :] = _s2_dot(f_ref[...], s).astype(o_ref.dtype)
+        return carry
+
+    _over_channels(x_ref, channel)
+
+
+def _s2_bwd_kernel(g_ref, gt_ref, ft_ref, x_ref, dy_ref, dx_ref):
+    dtype = dx_ref.dtype
+
+    def channel(j, carry):
+        h = 0.5 * _s2_grid(g_ref[...], x_ref[:, j, :])
+        t = jnp.tanh(h)
+        ds = _s2_dot(ft_ref[...], dy_ref[:, j, :]).astype(dtype).astype(
+            jnp.float32)
+        dg = ds * (0.5 + 0.5 * t + h * (0.5 - 0.5 * t * t))
+        dx_ref[:, j, :] = _s2_dot(gt_ref[...], dg.astype(dtype)).astype(dtype)
+        return carry
+
+    _over_channels(x_ref, channel)
+
+
+def _over_channels(x_ref, channel):
+    """``channel(j, None)`` for each channel row of the block, unrolled:
+    Mosaic reads a bfloat16 row of a tile only at a static index. One
+    traced body, so a step program's trace does not grow with the block."""
+    jax.lax.fori_loop(0, x_ref.shape[1], channel, None, unroll=True)
+
+
+def s2_activation_pallas(x, tables, *, backward_of=None,
+                         interpret: bool = False):
+    """``F silu(G x)`` over the coefficient axis of ``x`` ``(K, H, E)``
+    (``tables`` from :func:`s2_tables`, ``E`` a multiple of
+    :func:`s2_lanes`), in one pass over blocks of channels and edges;
+    products accumulate in float32 and the grid values, SiLU's and the
+    result are rounded to the rows' dtype. With ``backward_of=x0`` it is
+    the cotangent of ``x0`` for the output's cotangent ``x`` instead: the
+    grid of ``x0`` computed again, ``G^T (silu'(G x0) * F^T x)``."""
+    G, Gt, F, Ft = (jnp.asarray(t) for t in tables)
+    k, h, e = x.shape
+    hb = S2_CHANNELS if h % S2_CHANNELS == 0 else h
+    lanes = s2_lanes(e, x.dtype)
+    assert e % lanes == 0, (x.shape, lanes)
+    block = pl.BlockSpec((k, hb, lanes), lambda c, i: (0, c, i))
+    whole = lambda t: pl.BlockSpec(t.shape, lambda c, i: (0, 0))
+    if backward_of is None:
+        kernel, consts, ins = _s2_fwd_kernel, (G, F), (x,)
+    else:
+        kernel, consts, ins = _s2_bwd_kernel, (G, Gt, Ft), (backward_of, x)
+    return pl.pallas_call(
+        kernel,
+        grid=(h // hb, e // lanes),
+        in_specs=[whole(t) for t in consts] + [block] * len(ins),
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=interpret,
+    )(*consts, *ins)

@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..geometry import COORD_PRECISION
-from ..kernels.dispatch import fused_wigner_rotate
+from ..kernels.dispatch import fused_s2_activation, fused_wigner_rotate
 from ..kernels.so3 import wigner_cols
 from ..ops.s2_grid import grid_tables
 from ..ops.segment import masked_segment_softmax
@@ -274,16 +274,13 @@ class EquiformerV2:
         return self._per_degree(p["lin2"], h.at[:, 0].set(gate))
 
     # ---- edge pieces -----------------------------------------------------
-    def _s2_activation(self, y, gate):
+    def _s2_activation(self, y, gate, kernels=None):
         """SiLU on the edge grid of the message pieces (H lanes a degree),
         then the l = 0 coefficient set to SiLU of the gate scalars."""
         lay, H = self.lay, self.cfg.attn_hidden_channels
-        dtype = y[0].dtype
-        to_grid, from_grid = (jnp.asarray(t, dtype) for t in self.edge_grid)
         flat = jnp.concatenate([y[m] for m in lay.signed_ms], axis=-1)
-        flat = flat.reshape(flat.shape[0], -1, H)
-        g = jnp.einsum("gk,ekh->egh", to_grid, flat)
-        flat = jnp.einsum("kg,egh->ekh", from_grid, jax.nn.silu(g))
+        flat = fused_s2_activation(flat.reshape(flat.shape[0], -1, H),
+                                   *self.edge_grid, kernels=kernels)
         flat = flat.at[:, 0].set(jax.nn.silu(gate))
         out, o = {}, 0
         for m in lay.signed_ms:
@@ -427,7 +424,7 @@ class EquiformerV2:
                     with scope("edge_message"):
                         y, gate = so2_conv(lay, conv1_b, fr, rad, H)
                     with scope("s2_activation"):
-                        y = self._s2_activation(y, gate)
+                        y = self._s2_activation(y, gate, lg.kernels)
                     with scope("edge_message"):
                         v, _ = so2_conv(lay, p["conv2"], y, None, heads * V)
                     with scope("edge_attention"):

@@ -150,6 +150,12 @@ def test_kernels_phase_has_a_row_for_every_entry_of_the_default_table():
     assert [a.shape for a in args] == [(394368, 128)]
     got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
     assert got.shape == want.shape == (2 * 8448, 128)
+    # a scan chunk of eqv2-31m-md-1c's coefficients, forward and backward
+    kernel, xla, args = cases["s2_activation"](jnp.bfloat16)
+    assert [a.shape for a in args] == [(19, 64, 16384)] * 2
+    got, want = jax.eval_shape(kernel, *args), jax.eval_shape(xla, *args)
+    assert got.shape == want.shape == (2 * 19, 64, 16384)
+    assert got.dtype == want.dtype == jnp.bfloat16
 
 
 def test_chip_bands_carry_their_measurement():

@@ -72,7 +72,7 @@ def test_the_new_stages_are_declared():
 
 @pytest.mark.parametrize("nparts, dtype, kernels", [
     (1, "float32", False), (1, "bfloat16", "interpret"),
-    (4, "bfloat16", False)])
+    (4, "bfloat16", False), (4, "bfloat16", "interpret")])
 def test_every_equation_of_the_model_carries_a_stage(nparts, dtype, kernels):
     model = step_sites(EquiformerV2(config(dtype=dtype)), nparts,
                        kernels=kernels)
@@ -96,9 +96,25 @@ def test_every_equation_of_the_model_carries_a_stage(nparts, dtype, kernels):
              and "pallas_call" not in s.path]
     assert len(scans) == 1 + 2 * config().num_layers
     if kernels:
-        # the in-place sum of each scan (the rotation kernel takes 128 lanes)
-        assert {stage_of(s.stack) for s in model
-                if s.primitive == "pallas_call"} == {"edge_aggregate"}
+        # the in-place sum of each scan (the rotation kernel takes 128
+        # lanes) and the edge grid's kernel a block: the forward, its
+        # recompute in the checkpointed backward and the backward, with
+        # its products and SiLU inside it
+        calls = [s for s in model if s.primitive == "pallas_call"]
+        assert {stage_of(s.stack) for s in calls} == {
+            "edge_aggregate", "s2_activation"}
+        assert sum(stage_of(s.stack) == "s2_activation" for s in calls) == (
+            3 * config().num_layers)
+        inside = {s.primitive for s in model if "pallas_call" in s.path
+                  and stage_of(s.stack) == "s2_activation"}
+        assert {"dot_general", "tanh"} <= inside
+        # the grid itself never leaves the kernel: no value outside it
+        # holds the 324 (padded: 336) points of a column
+        outside = [v.aval.shape for s in model if "pallas_call" not in s.path
+                   and stage_of(s.stack) == "s2_activation"
+                   for v in s.eqn.outvars if hasattr(v.aval, "shape")]
+        assert outside and not any({324, 336} & set(shape)
+                                   for shape in outside)
 
 
 # ---- the model on its own --------------------------------------------------
